@@ -35,6 +35,10 @@ class TrackTable:
     artist_name: np.ndarray | None = None
     artist_uri: np.ndarray | None = None
     album_name: np.ndarray | None = None
+    # which parser produced the table ("native" | "pandas"); None for
+    # tables built in memory. The job prints it, so a log shows whether
+    # the C++ loader was built and used or the pandas path carried the run
+    loader: str | None = None
 
     def __len__(self) -> int:
         return len(self.pid)
@@ -106,6 +110,7 @@ def read_tracks(path: str, sample_ratio: float = 1.0) -> TrackTable:
         artist_name=col("artist_name"),
         artist_uri=col("artist_uri"),
         album_name=col("album_name"),
+        loader="pandas",
     )
 
 
@@ -130,6 +135,7 @@ def _table_from_native(nt, sample_ratio: float) -> TrackTable:
         artist_name=col("artist_name"),
         artist_uri=col("artist_uri"),
         album_name=col("album_name"),
+        loader="native",
     )
 
 

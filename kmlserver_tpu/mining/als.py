@@ -264,7 +264,6 @@ def _sharded_sweep_fn(mesh):
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import AXIS_TP
-    from ..utils.jaxcompat import shard_map
 
     def local(x_loc, user_f, item_f_loc, reg):
         # x_loc (P, V_loc) f32; user_f (P, R) replicated; item_f_loc
@@ -281,7 +280,7 @@ def _sharded_sweep_fn(mesh):
         return user_f, item_f_loc
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local, mesh=mesh,
             in_specs=(
                 P(None, AXIS_TP), P(None, None), P(AXIS_TP, None), P()
@@ -302,7 +301,6 @@ def _sharded_loss_fn(mesh):
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import AXIS_TP
-    from ..utils.jaxcompat import shard_map
 
     def local(x_loc, user_f, item_f_loc, reg):
         resid = x_loc - user_f @ item_f_loc.T
@@ -312,7 +310,7 @@ def _sharded_loss_fn(mesh):
         )
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local, mesh=mesh,
             in_specs=(
                 P(None, AXIS_TP), P(None, None), P(AXIS_TP, None), P()

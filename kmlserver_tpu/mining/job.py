@@ -89,14 +89,16 @@ def main() -> int:
     try:
         distributed = maybe_initialize()
         cfg = MiningConfig.from_env()
-        # persistent XLA compilation cache (PVC-backed via KMLS_JAX_CACHE_DIR):
-        # the pseudo-CronJob re-runs this container every ~20 min and would
-        # otherwise re-pay every jit compile each run. AFTER from_env so the
-        # knob honors .env like every other KMLS_ variable; before any jit.
+        # persistent XLA compilation cache (utils/jaxcache.py): the
+        # pseudo-CronJob re-runs this container every ~20 min and would
+        # otherwise re-pay every jit compile each run. Before any jit.
         from ..utils.jaxcache import enable_compilation_cache
 
         enable_compilation_cache()
         from ..parallel.distributed import resolve_mesh
+        from ..parallel.mesh import describe_devices
+
+        print(f"Devices: {describe_devices()}", flush=True)
 
         if distributed and cfg.rank_timeout_s > 0:
             from .checkpoint import heartbeat_dir

@@ -97,7 +97,7 @@ def _run_encode_phase(cfg: MiningConfig, selected: str) -> dict:
     table = read_tracks(selected, cfg.sample_ratio)
     print(
         f"Loaded {len(table)} rows, {table.n_playlists} playlists, "
-        f"{table.n_tracks} unique tracks"
+        f"{table.n_tracks} unique tracks (CSV loader: {table.loader})"
     )
     artists = vocab_mod.validate_and_map_artists(table)
     repeated = vocab_mod.extract_repeated_track_names(table)

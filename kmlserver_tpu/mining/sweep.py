@@ -307,7 +307,7 @@ def main() -> int:
     # the sweep honors the same KMLS_MESH_SHAPE contract as the mining job,
     # including multi-host bootstrap: under a distributed runtime
     # KMLS_MESH_SHAPE=auto must build the hybrid DCN×ICI mesh, not a flat
-    # local-device one (ADVICE r4 #2)
+    # local-device one
     from ..parallel.distributed import maybe_initialize, resolve_mesh
 
     distributed = maybe_initialize()

@@ -88,9 +88,11 @@ PEAK_TABLE: tuple[tuple[str, float, float], ...] = (
 
 def resolve_peaks(device=None) -> tuple[float, float, str]:
     """→ ``(peak_flops, peak_bytes_per_s, source)``. Env knobs win
-    (``KMLS_PEAK_FLOPS`` / ``KMLS_PEAK_BYTES_PER_S`` — the TPU window
-    pins the exact chip); otherwise the table is keyed by the device
-    kind of ``device`` (default: the first local device)."""
+    (``KMLS_PEAK_FLOPS`` / ``KMLS_PEAK_BYTES_PER_S``); otherwise the
+    table is keyed by the device kind of ``device`` (default: the first
+    local device). A device kind the table does not know raises
+    ``ValueError`` unless BOTH knobs supply its peaks — an accelerator
+    judged against the CPU row would report a utilization of nothing."""
     env_flops = os.getenv(PEAK_FLOPS_ENV)
     env_bytes = os.getenv(PEAK_BYTES_ENV)
     kind = ""
@@ -108,8 +110,12 @@ def resolve_peaks(device=None) -> tuple[float, float, str]:
             flops, bw = table_flops, table_bw
             break
     else:
-        flops, bw = PEAK_TABLE[-1][1], PEAK_TABLE[-1][2]
-        auto_source = f"auto-default:{kind.strip()}"
+        if not (env_flops and env_bytes):
+            raise ValueError(
+                f"device kind {kind.strip()!r} is not in PEAK_TABLE; add "
+                f"its published peaks there, or set {PEAK_FLOPS_ENV} and "
+                f"{PEAK_BYTES_ENV}"
+            )
     if env_flops:
         flops = float(env_flops)
     if env_bytes:

@@ -22,10 +22,7 @@ Two implementations share the bit-packed operand (``impl=`` /
 ``KMLS_BITPACK_IMPL``): ``"mxu"`` (default) is a pure-XLA blocked
 unpack-matmul (:func:`mxu_pair_counts_padded`) that puts the contraction on
 the MXU; ``"vpu"`` is the Pallas AND+popcount kernel below. The VPU kernel
-itself has two variants (``variant=``), identical results, different
-lowering risk/perf profiles — selectable so the on-hardware bench can pick
-whichever actually lowers fastest (this environment has no local TPU to
-pre-verify Mosaic lowering):
+itself has two variants (``variant=``) with identical results:
 
 - ``"bcast"`` (default): fully vectorized — slices the word chunk into
   SUB-wide pieces and broadcasts ``(TI, 1, SUB) & (1, TJ, SUB)``; only
@@ -34,15 +31,26 @@ pre-verify Mosaic lowering):
   (``a_ref[i, :]``) — smaller intermediates, more loop overhead.
 
 ``swar=True`` replaces ``jax.lax.population_count`` with an adds-and-shifts
-SWAR popcount (Hacker's Delight fig. 5-2, public-domain identity) in case
-the popcount primitive doesn't lower in Mosaic.
+SWAR popcount (Hacker's Delight fig. 5-2, public-domain identity).
+
+What the chip said (TPU v5e, jax 0.9.0 / libtpu 0.0.34, PR 21): all four
+variant × popcount combinations compile with ``interpret=False`` at the
+default tiles and match :func:`mxu_pair_counts_padded` exactly on a
+68 × 17 × 4 grid (65,536 playlists × 2,171 tracks — every axis multi-step,
+so the accumulate-across-chunks branch runs). The Mosaic popcount primitive
+lowers; neither the dynamic sublane index of ``"row"`` nor the minor-axis
+reduce of ``"bcast"`` is refused. ``chip_smoke.py`` keeps checking the
+default combination; which one is fastest is not measured.
 
 On non-TPU backends the kernel runs in interpreter mode (tests); the public
-entry point falls back gracefully.
+entry point selects it there by itself.
 
 Tile sizes are env-tunable (``KMLS_POPCOUNT_TILE_I/TILE_J/WORD_CHUNK``) for
 on-hardware tuning without a code change; defaults keep every operand on
-the (8, 128) 32-bit tile grid and the per-step VMEM footprint ≈ 0.3 MB.
+the (8, 128) 32-bit tile grid. Per-step VMEM at the defaults: the operand
+and output blocks are ≈ 0.33 MB (the pipeline double-buffers them), and
+the ``"bcast"`` variant adds a ``(TI, TJ, SUB)`` uint32 intermediate of
+2 MiB per live copy — which the compiler fits in its default scoped VMEM.
 Like ``KMLS_POPCOUNT_VARIANT``, the tile knobs are read LAZILY at
 kernel-build time (:func:`resolve_tiles`) — an env change after import
 takes effect on the next call, and because the resolved sizes ride the
@@ -123,10 +131,11 @@ def resolve_counts_impl(impl: str | None = None) -> str:
     - ``"mxu"`` (default): blocked unpack-matmul — scan over word-chunk
       slabs, unpack each uint32 slab to int8 bits in registers, one native
       int8×int8→int32 MXU contraction per slab (:func:`mxu_pair_counts_padded`).
-      Pure XLA (no Mosaic lowering risk), runs natively on every backend,
-      and puts the FLOPs where the chip has them: at config-4 scale the MXU
-      peak is ~3.4 s where the VPU popcount kernel's measured rate gives
-      minutes. It is fast off-TPU too — measured 1.1 s vs 43 s for the
+      Pure XLA (no Mosaic lowering involved), runs natively on every
+      backend, and puts the operations on the MXU, where the chip has its
+      integer peak (config 4 is ≈1.3·10¹⁵ int8 ops). The two impls have not
+      been timed against each other on the chip (ROADMAP queue 3 item 4).
+      It is fast off-TPU too — measured 1.1 s vs 43 s for the
       dense int8 matmul on XLA:CPU at 100k×2k (the compressed operand
       streams through cache where the dense one thrashes it), so it is
       also the right fallback when the native CPU counter can't build.
@@ -201,9 +210,10 @@ def _kernel_bcast(a_ref, b_ref, out_ref, *, swar: bool):
     tj = b.shape[0]
     sub = min(_SUB, wk)
 
-    # static Python unroll (wk/sub is a compile-time constant, default 4):
-    # Mosaic's TC lowering has no dynamic_slice, so a fori_loop with traced
-    # slice starts fails to compile on real hardware — verified on v5e
+    # static Python unroll (wk/sub is a compile-time constant, default 4)
+    # rather than a fori_loop over traced slice starts: static slices are
+    # what this body was compiled and checked with on a v5e (module
+    # docstring)
     acc = jnp.zeros((ti, tj), jnp.int32)
     for c in range(wk // sub):
         a_c = a[:, c * sub:(c + 1) * sub]  # (TI, SUB)

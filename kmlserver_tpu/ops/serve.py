@@ -84,16 +84,10 @@ recommend_batch = partial(jax.jit, static_argnames=("k_best",))(
     _recommend_batch_impl
 )
 
-# Donating twin: the padded seed buffer is consumed by the call, letting XLA
-# reuse its device memory for the outputs — steady-state batches then do no
-# fresh HBM allocation on the seed path. Each dispatch stages a new seed
-# array anyway (the host staging buffer is what gets reused), so donation
-# costs nothing. Kept separate from `recommend_batch` because donation on
-# the CPU backend is unimplemented and warns per call; the engine picks the
-# donating variant only on accelerator backends.
-recommend_batch_donated = partial(
-    jax.jit, static_argnames=("k_best",), donate_argnums=(2,)
-)(_recommend_batch_impl)
+# (There is no seed-buffer-donating twin: the int32 (B, L) seed batch has the
+# shape and dtype of neither output, so XLA can alias it to nothing — on
+# the v5e every one of the 24 warm-up compiles answered "Some donated
+# buffers were not usable", PR 21. Every backend runs this one function.)
 
 
 # ---------------------------------------------------------------------------
@@ -230,15 +224,13 @@ def sharded_recommend_fn(mesh, k_best: int, axis: str = "shard"):
     (unpadded) tensors."""
     from jax.sharding import PartitionSpec as P
 
-    from ..utils.jaxcompat import shard_map
-
     n_shards = mesh.shape[axis]
     local = partial(
         _sharded_recommend_local,
         k_best=k_best, axis=axis, n_shards=n_shards,
     )
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(axis, None), P(axis, None), P(None, None)),
             out_specs=(P(None, None), P(None, None)),

@@ -53,5 +53,16 @@ def make_mesh(
     return Mesh(grid, (AXIS_DP, AXIS_TP))
 
 
+def describe_devices() -> str:
+    """``platform=… device_kind=… count=…`` as JAX reports them — the
+    start-up line of both entry points, so a log (or ``chip_smoke.py``)
+    can tell which device a process actually ran on."""
+    devices = jax.devices()
+    return (
+        f"platform={devices[0].platform} "
+        f"device_kind={devices[0].device_kind} count={len(devices)}"
+    )
+
+
 def round_up(n: int, multiple: int) -> int:
     return ((n + multiple - 1) // multiple) * multiple

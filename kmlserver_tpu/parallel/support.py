@@ -36,7 +36,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..mining.vocab import Baskets
 from ..ops import encode
-from ..utils.jaxcompat import pcast_varying, shard_map
 from .mesh import AXIS_DP, AXIS_TP, round_up
 
 
@@ -79,7 +78,7 @@ def _allgather_counts(mesh: Mesh):
         return jax.lax.psum(c_local, AXIS_DP)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local, mesh=mesh, in_specs=P(AXIS_DP, AXIS_TP),
             out_specs=P(None, AXIS_TP),
         )
@@ -105,15 +104,15 @@ def _ring_counts(mesh: Mesh):
 
         # mark the accumulator device-varying so the fori_loop carry type
         # matches after blocks of `c` (which varies per shard) land in it
-        out0 = pcast_varying(
+        out0 = jax.lax.pcast(
             jnp.zeros((v_loc * tp, v_loc), dtype=jnp.int32),
-            (AXIS_DP, AXIS_TP),
+            (AXIS_DP, AXIS_TP), to="varying",
         )
         _, out = jax.lax.fori_loop(0, tp, step, (x_local, out0))
         return jax.lax.psum(out, AXIS_DP)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local, mesh=mesh, in_specs=P(AXIS_DP, AXIS_TP),
             out_specs=P(None, AXIS_TP),
         )
@@ -230,7 +229,7 @@ def _sharded_counts_fn(mesh, impl, interpret, variant, swar):
         return jax.lax.psum(c, AXIS_DP)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local, mesh=mesh, in_specs=P(None, AXIS_DP),
             out_specs=P(None, None),
             # the pallas_call's out_shape carries no vma annotation; the
@@ -309,7 +308,7 @@ def _sharded_emit_fn(mesh: Mesh, k_max: int):
         return rule_ids, rule_counts, row_valid, item_counts
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(None, AXIS_TP), P()),
             out_specs=(

@@ -24,8 +24,10 @@ response is written.
 SIGTERM drain parity with the threaded transport (k8s rollout semantics):
 on ``drain()`` the listener closes immediately (racing connects are
 refused, not parked), every subsequent response carries ``Connection:
-close`` so keep-alive clients migrate off the pod, and shutdown settles
-until in-flight requests hit zero (bounded by ``KMLS_DRAIN_SETTLE_S``).
+close`` so keep-alive clients migrate off the pod, shutdown settles
+until in-flight requests hit zero (bounded by ``KMLS_DRAIN_SETTLE_S``),
+and then the keep-alive connections still idling are closed so the
+process exits — and gives up its device — whatever its clients do.
 """
 
 from __future__ import annotations
@@ -60,15 +62,18 @@ class _ServerState:
         self.inflight = 0
         self.idle = asyncio.Event()
         self.idle.set()
+        # live connections, so shutdown can close the idle keep-alive
+        # ones instead of waiting for their clients to hang up
+        self.conns: set[_Conn] = set()
         self._engine_pool = None
 
     @property
     def engine_pool(self):
         """Small thread pool for the BATCHERLESS recommend path
-        (KMLS_BATCH_WINDOW_MS=0): engine.recommend blocks on the device —
-        through a remote-TPU tunnel for hundreds of ms — and running it
-        on the loop would freeze every connection, health probes
-        included. Lazy: the batched default never needs it."""
+        (KMLS_BATCH_WINDOW_MS=0): engine.recommend blocks until the
+        device answers, and running it on the loop would freeze every
+        connection for that long, health probes included. Lazy: the
+        batched default never needs it."""
         if self._engine_pool is None:
             from concurrent.futures import ThreadPoolExecutor
 
@@ -126,9 +131,11 @@ class _Conn(asyncio.Protocol):
                 pass
         peer = transport.get_extra_info("peername")
         self.peer_host = peer[0] if peer else None
+        self.state.conns.add(self)
 
     def connection_lost(self, exc) -> None:
         self.closed = True
+        self.state.conns.discard(self)
 
     def data_received(self, data: bytes) -> None:
         self.buf += data
@@ -473,23 +480,31 @@ async def run_async(app: RecommendApp, port: int, ready=None) -> int:
         except (NotImplementedError, RuntimeError):
             pass  # non-main thread / exotic platform
 
-    async with server:
+    try:
         await stop.wait()
-        # listener closes NOW: racing connects get an instant refusal
+    finally:
+        # listener closes NOW: racing connects get an instant refusal.
+        # Not followed by wait_closed(): since Python 3.12 that blocks
+        # until every accepted connection has closed, so one idle
+        # keep-alive client would hold the process — and the device it
+        # owns — forever.
         server.close()
-        await server.wait_closed()
-        settle_s = float(os.getenv("KMLS_DRAIN_SETTLE_S") or 2.0)
-        # floor before the zero-exit (threaded-transport parity): a
-        # keep-alive client that raced the signal may still be writing its
-        # request — give it a beat to land and be answered with
-        # Connection: close before the idle check can end the settle
-        await asyncio.sleep(min(0.5, settle_s))
-        try:
-            await asyncio.wait_for(state.idle.wait(), timeout=settle_s)
-        except asyncio.TimeoutError:
-            logger.warning(
-                "drain settle expired after %.1fs with %d requests still "
-                "in flight (raise KMLS_DRAIN_SETTLE_S to match "
-                "terminationGracePeriodSeconds)", settle_s, state.inflight,
-            )
+    settle_s = float(os.getenv("KMLS_DRAIN_SETTLE_S") or 2.0)
+    # floor before the zero-exit (threaded-transport parity): a
+    # keep-alive client that raced the signal may still be writing its
+    # request — give it a beat to land and be answered with
+    # Connection: close before the idle check can end the settle
+    await asyncio.sleep(min(0.5, settle_s))
+    try:
+        await asyncio.wait_for(state.idle.wait(), timeout=settle_s)
+    except asyncio.TimeoutError:
+        logger.warning(
+            "drain settle expired after %.1fs with %d requests still "
+            "in flight (raise KMLS_DRAIN_SETTLE_S to match "
+            "terminationGracePeriodSeconds)", settle_s, state.inflight,
+        )
+    # whatever is still connected is an idle keep-alive client (or a
+    # request past the settle): close it rather than wait on it
+    for conn in list(state.conns):
+        conn.transport.close()
     return 0

@@ -79,7 +79,7 @@ def is_loopback_host(client_host: str | None) -> bool:
     ``/debug/profile``). ``None`` is a direct in-process call (tests,
     embedding harnesses) — inherently local. A dual-stack server reports
     IPv4 loopback in IPv6-mapped form (``::ffff:127.0.0.1``): normalize
-    before the check (ADVICE r5 #3)."""
+    before the check."""
     if client_host is None:
         return True
     host = client_host.removeprefix("::ffff:")
@@ -330,7 +330,7 @@ class RecommendApp:
             )
         if method == "POST" and path == "/metrics/reset":
             # measurement-harness hook: windows the latency percentiles
-            # to one replay run (VERDICT r4 #7). Loopback-only via the
+            # to one replay run. Loopback-only via the
             # shared guard (is_loopback_host — one copy for all four
             # guarded endpoints).
             if not is_loopback_host(client_host):
@@ -687,7 +687,7 @@ class RecommendApp:
         ``/static`` mount (rest_api/app/main.py:138). Paths are confined to
         the root after symlink resolution, so neither ``..`` traversal nor
         a symlink planted inside an operator-supplied static dir can reach
-        outside it (ADVICE r4 #4)."""
+        outside it."""
         full = os.path.realpath(os.path.join(self.static_dir, rel))
         root = os.path.realpath(self.static_dir)
         if not full.startswith(root + os.sep):

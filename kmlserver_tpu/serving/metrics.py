@@ -457,7 +457,7 @@ class ServingMetrics:
         observations discarded).
 
         Lets a measurement harness window the percentiles to one replay
-        run (VERDICT r4 #7). The Prometheus counters stay cumulative —
+        run. The Prometheus counters stay cumulative —
         resetting counters would break scrape-delta semantics — and the
         attribution HISTOGRAMS stay with the counters: their buckets ARE
         counters (fleet aggregation depends on scrape deltas), so only

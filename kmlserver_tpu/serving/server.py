@@ -33,14 +33,15 @@ def main() -> int:
     )
     logging.getLogger("kmlserver_tpu").setLevel(logging.DEBUG)
     cfg = ServingConfig.from_env()
-    # persistent XLA compilation cache (PVC-backed via KMLS_JAX_CACHE_DIR):
-    # per-shape warmup on every rollout/reload hits the cache instead of
-    # recompiling the same serving-bucket kernels. AFTER from_env so the
-    # knob honors .env like every other KMLS_ variable; before any jit.
+    # persistent XLA compilation cache (utils/jaxcache.py): per-shape
+    # warmup on every rollout/reload hits the cache instead of
+    # recompiling the same serving-bucket kernels. Before any jit.
+    from ..parallel.mesh import describe_devices
     from ..utils.jaxcache import enable_compilation_cache
 
     enable_compilation_cache()
     log = logging.getLogger("kmlserver_tpu.serving")
+    log.info("devices: %s", describe_devices())
     # transport selection: the asyncio front end is the default (thread-
     # per-connection collapses under concurrency on small pods — see
     # serving/aioserver.py); the stdlib ThreadingHTTPServer stays as the

@@ -18,10 +18,13 @@ choosing its pair-count implementation).
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
 from typing import Callable
+
+logger = logging.getLogger("kmlserver_tpu.nativelib")
 
 NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
@@ -34,8 +37,9 @@ _make_ran = False
 
 def run_make_once(quiet: bool = True) -> None:
     """Invoke ``make -C native`` at most once per process (all targets
-    build together). Failures are swallowed — per-.so existence decides
-    availability afterwards."""
+    build together). A failed build is logged, not raised — per-.so
+    existence decides availability afterwards, and callers name the path
+    they took instead (``CSV loader:``, ``Pair-count path:``)."""
     global _make_ran
     with _make_lock:
         if _make_ran:
@@ -45,8 +49,13 @@ def run_make_once(quiet: bool = True) -> None:
             subprocess.run(
                 ["make", "-C", NATIVE_DIR], check=True, capture_output=quiet
             )
-        except (subprocess.CalledProcessError, FileNotFoundError):
-            pass
+        except FileNotFoundError:
+            logger.warning("native build skipped: no `make` on PATH")
+        except subprocess.CalledProcessError as exc:
+            tail = (exc.stderr or b"").decode("utf-8", "replace")[-400:]
+            logger.warning(
+                "native build failed (exit %d): %s", exc.returncode, tail
+            )
 
 
 class NativeLib:

@@ -545,7 +545,7 @@ class TestLoopbackNormalization:
             "POST", "/metrics/reset", b"", client_host="::ffff:10.2.3.4"
         )[0] == 403
         # 'localhost' never appears as a client_address value — dropped
-        # from the allowlist (ADVICE r5 #3)
+        # from the allowlist
         assert app.handle(
             "POST", "/metrics/reset", b"", client_host="localhost"
         )[0] == 403

@@ -71,41 +71,97 @@ class TestConfig:
         serving = ServingConfig.from_env(dotenv_path=None)
         assert serving.batch_max_inflight == 2
 
-    def test_compilation_cache_env(self, monkeypatch, tmp_path):
+    @pytest.fixture
+    def jax_config_writes(self, monkeypatch):
+        """Record every ``jax.config.update`` instead of applying it, so
+        the helper's writes are observable and the pytest process keeps
+        its own compile-cache settings."""
         import jax
 
-        from kmlserver_tpu.utils.jaxcache import enable_compilation_cache
+        writes: list[tuple[str, object]] = []
+        monkeypatch.setattr(
+            jax.config, "update", lambda name, value: writes.append((name, value))
+        )
+        return writes
 
-        monkeypatch.delenv("KMLS_JAX_CACHE_DIR", raising=False)
-        assert enable_compilation_cache() is None
-        cache = tmp_path / "jax-cache"
-        monkeypatch.setenv("KMLS_JAX_CACHE_DIR", str(cache))
+    def test_cache_dir_placed_from_outside_is_left_to_jax(
+        self, monkeypatch, tmp_path, jax_config_writes
+    ):
+        """JAX_COMPILATION_CACHE_DIR set: JAX reads it itself — the helper
+        writes no directory of its own, only the one storage threshold."""
+        from kmlserver_tpu.utils import jaxcache
+
+        cache = tmp_path / "placed"
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache))
+        assert jaxcache.enable_compilation_cache() == str(cache)
+        assert cache.is_dir()
+        assert jax_config_writes == [(
+            "jax_persistent_cache_min_compile_time_secs",
+            jaxcache.MIN_COMPILE_TIME_S,
+        )]
+        assert jaxcache.child_env()["JAX_COMPILATION_CACHE_DIR"] == str(cache)
+
+    def test_unset_cache_dir_is_one_fixed_in_checkout_path(
+        self, monkeypatch, tmp_path, jax_config_writes
+    ):
+        from kmlserver_tpu.utils import jaxcache
+
+        monkeypatch.setattr(jaxcache, "DEFAULT_CACHE_DIR", str(tmp_path / "c"))
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         try:
-            assert enable_compilation_cache() == str(cache)
-            assert cache.is_dir()
-            assert jax.config.jax_compilation_cache_dir == str(cache)
+            assert jaxcache.enable_compilation_cache() == str(tmp_path / "c")
+            # exported, so children inherit the same directory
+            assert os.environ["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path / "c")
+            assert (
+                "jax_compilation_cache_dir", str(tmp_path / "c")
+            ) in jax_config_writes
         finally:
-            jax.config.update("jax_compilation_cache_dir", None)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 1.0
-            )
+            os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 
-    def test_compilation_cache_failure_is_soft(self, monkeypatch, tmp_path):
-        # a mis-mounted cache path must never take down the job/API
-        import jax
+    def test_default_cache_path_is_identical_across_processes(self, monkeypatch):
+        """No temp name, pid or time in the path: the directory is part
+        of where a cached executable is looked up, so one that moves
+        never hits."""
+        import subprocess
+        import sys
 
-        from kmlserver_tpu.utils.jaxcache import enable_compilation_cache
+        from kmlserver_tpu.utils import jaxcache
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert jaxcache.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+        env = {
+            k: v for k, v in os.environ.items()
+            if k != "JAX_COMPILATION_CACHE_DIR"
+        }
+        env["PYTHONPATH"] = repo
+        code = (
+            "from kmlserver_tpu.utils import jaxcache; "
+            "print(jaxcache.cache_dir()); "
+            "print(jaxcache.child_env()['JAX_COMPILATION_CACHE_DIR'])"
+        )
+        seen = {
+            subprocess.run(
+                [sys.executable, "-c", code], env=env, cwd=cwd, check=True,
+                capture_output=True, text=True,
+            ).stdout
+            for cwd in (repo, os.path.join(repo, "tests"))
+        }
+        assert seen == {f"{jaxcache.DEFAULT_CACHE_DIR}\n" * 2}
+
+    def test_compilation_cache_failure_is_soft(
+        self, monkeypatch, tmp_path, jax_config_writes
+    ):
+        # a mis-mounted cache path must never take down the job/API…
+        from kmlserver_tpu.utils import jaxcache
 
         blocker = tmp_path / "not-a-dir"
         blocker.write_text("file, not a directory")
-        monkeypatch.setenv("KMLS_JAX_CACHE_DIR", str(blocker / "cache"))
-        try:
-            assert enable_compilation_cache() is None  # logged, not raised
-        finally:
-            jax.config.update("jax_compilation_cache_dir", None)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 1.0
-            )
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(blocker / "cache"))
+        assert jaxcache.enable_compilation_cache() is None  # logged, not raised
+        assert jax_config_writes == []
+        # …while a driver of children (bench.py, chip_smoke.py) gets the error
+        with pytest.raises(OSError):
+            jaxcache.child_env()
 
     def test_bitpack_threshold_env_forms(self, monkeypatch):
         # default and "auto" -> HBM-fit dispatch; "none" disables bitpack;

@@ -1192,6 +1192,24 @@ class TestCostModelUnit:
         assert cm.peak_source.startswith("explicit+"), cm.peak_source
         assert cm.peak_bytes_s > 0
 
+    def test_unknown_accelerator_kind_raises(self, monkeypatch):
+        """A device the table does not know must not be judged against
+        the CPU row; both env knobs together are the only way through."""
+        import types
+
+        monkeypatch.delenv("KMLS_PEAK_FLOPS", raising=False)
+        monkeypatch.delenv("KMLS_PEAK_BYTES_PER_S", raising=False)
+        chip = types.SimpleNamespace(platform="npu", device_kind="Mystery 9")
+        with pytest.raises(ValueError, match="Mystery 9"):
+            costmodel_mod.resolve_peaks(chip)
+        v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+        assert costmodel_mod.resolve_peaks(v5e)[:2] == (197e12, 819e9)
+        monkeypatch.setenv("KMLS_PEAK_FLOPS", "5e13")
+        with pytest.raises(ValueError):
+            costmodel_mod.resolve_peaks(chip)  # one knob is not a peak pair
+        monkeypatch.setenv("KMLS_PEAK_BYTES_PER_S", "2e12")
+        assert costmodel_mod.resolve_peaks(chip) == (5e13, 2e12, "env")
+
 
 class TestCostAttributionLive:
     """The tentpole, end to end on the real serving stack: jitted serve
