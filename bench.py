@@ -2823,7 +2823,7 @@ with tempfile.TemporaryDirectory(prefix="kmls_traceov_") as base:
     # disabled. Both are driven through app.handle (the full HTTP path
     # minus the socket) with pre-encoded bodies — the json cost is paid
     # identically on both sides, so the RATIO isolates the trace cost
-    # (begin + queue/device/compose spans + tail retention). The cache
+    # (begin + the request and batch spans + tail retention). The cache
     # is OFF: a Zipf replay warmed through the cache would answer ~all
     # hits and never reach the batcher's per-pending span recording —
     # the dominant trace cost this bracket exists to bound.

@@ -34,15 +34,45 @@ The trace id travels in the ``X-KMLS-Trace`` header (request:
 ``<trace_id>`` or ``<trace_id>:<parent_id>``; response echoes the trace
 id), so a replay/bench client can join its client-side timing to the
 server-side span breakdown for the same request.
+
+Spans form a TREE (ISSUE 26): every span has a small integer id local
+to its trace and a parent id; the root (id 0) is the trace itself,
+``request`` or ``batch``. A dispatched batch has a trace of its own —
+``batch -> {stage, dispatch, fetch_rules, fetch_embed, compose,
+resolve}`` — and each member request's ``batch`` span names it by
+``batch_id``, so a request reads ``request -> {parse, cache, admit,
+queue, batch, respond, write}`` and the batch's inside is looked up
+once, not copied into every member.
+
+CAPTURE MODE puts the spans on the device trace's clock. While a
+``/debug/profile`` capture is open (``utils/profiling.start_capture``
+switches it; there is no knob) every request and every batch is traced,
+whatever the sample rate, and kept in a list of its own; the capture
+thread emits clock anchors into the profiler session
+(:func:`emit_clock_anchor`: a ``TraceAnnotation`` whose name carries
+``perf_counter_ns``), and when the capture closes the spans are written
+beside the ``.xplane.pb`` as ``kmls_spans.jsonl`` with the anchors in a
+header line. A reader maps ``perf_counter`` onto the capture's time
+base by the anchors alone. Every ``TraceAnnotation`` of the serving
+path lives in this module.
 """
 
 from __future__ import annotations
 
 import collections
 import heapq
+import itertools
 import random
 import threading
 import time
+
+# a trace's root span: the request or the batch itself
+ROOT = 0
+# written beside the capture's .xplane.pb when a /debug/profile capture
+# closes; benchmark/spans.py reads it
+SPANS_FILENAME = "kmls_spans.jsonl"
+# a clock anchor is a TraceAnnotation named <prefix><perf_counter_ns>
+CLOCK_ANCHOR_PREFIX = "kmls/clock:"
 
 # ids are [-A-Za-z0-9_.]{1,64}: anything else in the header is treated
 # as absent (a hostile or corrupted header must not flow into JSON
@@ -57,78 +87,142 @@ def _valid_id(s: str) -> bool:
     return 0 < len(s) <= _MAX_ID_LEN and all(c in _ID_OK for c in s)
 
 
+def emit_clock_anchor() -> tuple[int, int]:
+    """Write one clock anchor into the open profiler session → (the
+    ``perf_counter_ns`` its name carries, ``perf_counter_ns`` read just
+    after the annotation opened). The annotation's start on the
+    capture's host plane lies between the two, so their distance bounds
+    what the mapping can be off by."""
+    import jax
+
+    named = time.perf_counter_ns()
+    with jax.profiler.TraceAnnotation(f"{CLOCK_ANCHOR_PREFIX}{named}"):
+        opened = time.perf_counter_ns()
+    return named, opened
+
+
 class TraceContext:
-    """One request's spans. Append-only; list.append is GIL-atomic, so
-    the batcher's completion thread and the HTTP thread can both record
-    without a lock (the same benign-race budget the batcher's in-flight
-    counters run on — on the normal path spans are recorded before the
-    future resolves, so the finishing thread observes a complete list).
-    When the app thread finishes a trace EARLY (deadline expiry, shed),
-    the completer may still be running — ``finished`` makes its late
-    span() a no-op (best-effort; the check is unsynchronized). The hard
-    immutability guarantee lives in :class:`SpanRecorder`, which retains
-    a trace as its rendered dict frozen at finish time."""
+    """One request's — or one batch's — spans. Append-only; list.append
+    is GIL-atomic, so the batcher's completion thread and the HTTP
+    thread can both record without a lock (the same benign-race budget
+    the batcher's in-flight counters run on — on the normal path spans
+    are recorded before the future resolves, so the finishing thread
+    observes a complete list). When the app thread finishes a trace
+    EARLY (deadline expiry, shed), the completer may still be running —
+    ``finished`` makes its late span() a no-op (best-effort; the check
+    is unsynchronized). The hard immutability guarantee lives in
+    :class:`SpanRecorder`, which retains a trace as its rendered dict
+    frozen at finish time.
+
+    A span is ``(span_id, parent_id, name, t_start, t_end, attrs)``;
+    the root (``ROOT``, named by ``kind``) spans ``t0`` to the finish
+    and is rendered first. ``cursor`` serves :meth:`lap`: back-to-back
+    spans, each starting where the last one ended, which is how a batch
+    trace is recorded across the dispatching thread, the executor thread
+    and the resolving one (they hand the trace on, never share it)."""
 
     __slots__ = (
-        "trace_id", "parent_id", "t0", "wall_start",
-        "spans", "attrs", "status", "duration_s", "finished",
+        "kind", "trace_id", "parent_id", "t0", "wall_start", "spans",
+        "attrs", "status", "duration_s", "finished", "deferred",
+        "cursor", "_ids",
     )
 
-    def __init__(self, trace_id: str, parent_id: str | None, t0: float):
+    def __init__(
+        self, trace_id: str, parent_id: str | None, t0: float,
+        kind: str = "request",
+    ):
+        self.kind = kind
         self.trace_id = trace_id
         self.parent_id = parent_id
         self.t0 = t0  # perf_counter at begin
         self.wall_start = time.time()
-        self.spans: list[tuple[str, float, float, dict | None]] = []
+        self.spans: list[
+            tuple[int, int, str, float, float, dict | None]
+        ] = []
         self.attrs: dict[str, object] = {}
         self.status = "open"
         self.duration_s = 0.0
         self.finished = False
+        # True: a response builder only stamps the status, and the
+        # transport finishes the trace once its write has returned
+        self.deferred = False
+        self.cursor = t0
+        self._ids = itertools.count(ROOT + 1)  # next() is GIL-atomic
 
     def span(
         self, name: str, t_start: float, t_end: float,
-        attrs: dict | None = None,
-    ) -> None:
-        """Record a named span (perf_counter endpoints). No-op once the
-        trace is finished: a deadline-expired request is retained at
-        resolve time, and the kernel's eventual completion must not
-        rewrite what ``/debug/traces`` already served."""
+        attrs: dict | None = None, parent: int = ROOT,
+    ) -> int:
+        """Record a named span (perf_counter endpoints) under ``parent``
+        → its id. No-op (→ -1) once the trace is finished: a
+        deadline-expired request is retained at resolve time, and the
+        kernel's eventual completion must not rewrite what
+        ``/debug/traces`` already served."""
         if self.finished:
-            return
-        self.spans.append((name, t_start, t_end, attrs))
+            return -1
+        span_id = next(self._ids)
+        self.spans.append((span_id, parent, name, t_start, t_end, attrs))
+        return span_id
+
+    def lap(self, name: str, attrs: dict | None = None) -> int:
+        """Close a span that began where the last lap ended (or at the
+        last :meth:`skip`) and ends now."""
+        now = time.perf_counter()
+        span_id = self.span(name, self.cursor, now, attrs)
+        self.cursor = now
+        return span_id
+
+    def skip(self) -> None:
+        """Move the lap cursor to now: what lies between the last lap
+        and here belongs to no span."""
+        self.cursor = time.perf_counter()
 
     def annotate(self, key: str, value) -> None:
         self.attrs[key] = value
 
-    def to_dict(self) -> dict:
+    def to_dict(self, absolute: bool = False) -> dict:
+        """JSON-ready. With ``absolute`` every span also carries its
+        ``perf_counter`` endpoints in whole nanoseconds (the capture
+        file's form: the clock the anchors are on)."""
+        t0 = self.t0
+        rows = [(ROOT, None, self.kind, t0, t0 + self.duration_s, None)]
+        rows += list(self.spans)
+        spans = []
+        for span_id, parent, name, t_start, t_end, attrs in rows:
+            span = {
+                "id": span_id,
+                "parent": parent,
+                "name": name,
+                "start_ms": round((t_start - t0) * 1e3, 4),
+                "duration_ms": round((t_end - t_start) * 1e3, 4),
+            }
+            if absolute:
+                span["t_start_ns"] = int(t_start * 1e9)
+                span["t_end_ns"] = int(t_end * 1e9)
+            if attrs:
+                span["attrs"] = attrs
+            spans.append(span)
         return {
+            "kind": self.kind,
             "trace_id": self.trace_id,
             "parent_id": self.parent_id,
             "status": self.status,
             "start_unix": round(self.wall_start, 6),
             "duration_ms": round(self.duration_s * 1e3, 4),
             "attrs": dict(self.attrs),
-            "spans": [
-                {
-                    "name": name,
-                    "start_ms": round((t_start - self.t0) * 1e3, 4),
-                    "duration_ms": round((t_end - t_start) * 1e3, 4),
-                    **({"attrs": attrs} if attrs else {}),
-                }
-                for name, t_start, t_end, attrs in list(self.spans)
-            ],
+            "spans": spans,
         }
 
 
 class SpanRecorder:
     """Bounded ring of finished traces with tail-based retention.
 
-    ``sample <= 0`` disables the recorder entirely (``enabled`` False);
-    call sites must check ``enabled`` before :meth:`begin` so the
-    disabled hot path does literally nothing. The retention lock is
-    taken at most twice per FINISHED request (never per span) and guards
-    only ring + heap mutation — no I/O, no rendering, no blocking calls
-    ever run under it."""
+    ``sample <= 0`` disables the recorder (``enabled`` False); call
+    sites check ``active`` — ``enabled``, or a capture open — before
+    :meth:`begin` so the disabled hot path does literally nothing. The
+    retention lock is taken at most twice per FINISHED trace (never per
+    span) and guards only ring + heap + capture-list mutation — no I/O,
+    no rendering, no blocking calls ever run under it."""
 
     def __init__(
         self,
@@ -141,9 +235,13 @@ class SpanRecorder:
         self.capacity = max(1, capacity)
         self.slow_n = max(0, slow_n)
         self.enabled = self.sample > 0.0
-        # contexts created — the zero-cost proof counter (compile-counter
-        # discipline: must stay 0 while tracing is disabled)
+        # the ONE attribute every call site checks: tracing on by the
+        # knob, or a profile capture open (capture mode)
+        self.active = self.enabled
+        # contexts created — the zero-cost proof counters (compile-
+        # counter discipline: both must stay 0 while nothing is active)
         self.began = 0
+        self.batches_began = 0
         self.retained_total = 0
         # retained traces are stored PRE-RENDERED (to_dict at finish
         # time): the live TraceContext stays reachable from the batcher
@@ -154,21 +252,33 @@ class SpanRecorder:
         self._buf: "collections.deque[dict]" = collections.deque(
             maxlen=self.capacity
         )
+        # batch traces while the knob is on: the newest `capacity`, so a
+        # retained request's `batch_id` can be looked up
+        self._batches: "collections.deque[dict]" = collections.deque(
+            maxlen=self.capacity
+        )
+        # capture mode: every finished trace, rendered with absolute
+        # endpoints, until the capture closes (None = no capture open)
+        self._capture: list[dict] | None = None
         # min-heap of the N largest OK durations retained so far: the
         # root is the admission bar a new trace must clear to count as
         # "slowest-N"
         self._slow: list[float] = []
         self._lock = threading.Lock()
         self._rng = rng or random.Random()
+        self._batch_ids = itertools.count(1)
 
     # ---------- lifecycle ----------
 
-    def begin(self, header: str | None = None) -> TraceContext | None:
+    def begin(
+        self, header: str | None = None, t0: float | None = None,
+    ) -> TraceContext | None:
         """Open a trace for one request; ``header`` is the raw
-        ``X-KMLS-Trace`` request value (``id`` or ``id:parent``). Only
-        called when :attr:`enabled` — returns None defensively so a
-        miswired call site degrades to untraced rather than crashing."""
-        if not self.enabled:
+        ``X-KMLS-Trace`` request value (``id`` or ``id:parent``), ``t0``
+        when the request began (default: now). Only called when
+        :attr:`active` — returns None defensively so a miswired call
+        site degrades to untraced rather than crashing."""
+        if not self.active:
             return None
         self.began += 1  # benign race: diagnostic counter, GIL-coalesced
         trace_id = ""
@@ -183,17 +293,40 @@ class SpanRecorder:
                 parent_id = tail
         if not trace_id:
             trace_id = f"{self._rng.getrandbits(64):016x}"
-        return TraceContext(trace_id, parent_id, time.perf_counter())
+        return TraceContext(
+            trace_id, parent_id, time.perf_counter() if t0 is None else t0
+        )
+
+    def begin_batch(self, t0: float, **attrs) -> TraceContext | None:
+        """Open the trace of one dispatched batch, formed at ``t0``;
+        its ``batch_id`` attribute is what the members' ``batch`` spans
+        name. Same contract as :meth:`begin`."""
+        if not self.active:
+            return None
+        self.batches_began += 1
+        batch_id = next(self._batch_ids)
+        trace = TraceContext(f"batch-{batch_id}", None, t0, kind="batch")
+        trace.attrs["batch_id"] = batch_id
+        trace.attrs.update(attrs)
+        return trace
 
     def finish(
         self, trace: TraceContext, status: str, duration_s: float
     ) -> bool:
-        """Close the trace and decide retention → whether it was kept.
-        ``status``: ``"ok"`` | ``"shed"`` | ``"degraded"`` | ``"error"``
-        (degraded traces carry the reason in ``attrs["reason"]``)."""
+        """Close the trace and decide retention → whether the ring kept
+        it. ``status``: ``"ok"`` | ``"shed"`` | ``"degraded"`` |
+        ``"error"`` (degraded traces carry the reason in
+        ``attrs["reason"]``). A capture keeps every trace besides."""
         trace.status = status
         trace.duration_s = duration_s
         trace.finished = True  # best-effort: stops further span() appends
+        if self._capture is not None:
+            frozen = trace.to_dict(absolute=True)
+            with self._lock:
+                if self._capture is not None:
+                    self._capture.append(frozen)
+        if not self.enabled:
+            return False
         with self._lock:
             keep = status != "ok"
             if not keep and self.slow_n > 0:
@@ -216,6 +349,37 @@ class SpanRecorder:
                 self.retained_total += 1
         return keep
 
+    def finish_batch(self, trace: TraceContext, status: str = "ok") -> None:
+        """Close a batch trace (its root ends now). Kept for the open
+        capture, and among the newest batches while the knob is on."""
+        trace.status = status
+        trace.duration_s = time.perf_counter() - trace.t0
+        trace.finished = True
+        captured = trace.to_dict(absolute=True) if self._capture is not None else None
+        kept = trace.to_dict() if self.enabled else None
+        with self._lock:
+            if captured is not None and self._capture is not None:
+                self._capture.append(captured)
+            if kept is not None:
+                self._batches.append(kept)
+
+    # ---------- capture mode ----------
+
+    def capture_begin(self) -> None:
+        """A profile capture opens: trace everything until
+        :meth:`capture_end`."""
+        with self._lock:
+            self._capture = []
+            self.active = True
+
+    def capture_end(self) -> list[dict]:
+        """The capture closed → every trace finished while it was open
+        (rendered with absolute endpoints), oldest first."""
+        with self._lock:
+            traces, self._capture = self._capture or [], None
+            self.active = self.enabled
+        return traces
+
     # ---------- exposition ----------
 
     def retained(self) -> int:
@@ -230,13 +394,16 @@ class SpanRecorder:
 
     def debug_payload(self) -> dict:
         """The ``GET /debug/traces`` response body."""
-        traces = self.snapshot() if self.enabled else []
+        with self._lock:
+            batches = list(self._batches) if self.enabled else []
         return {
             "enabled": self.enabled,
             "sample": self.sample,
             "capacity": self.capacity,
             "slow_n": self.slow_n,
             "began": self.began,
+            "batches_began": self.batches_began,
             "retained_total": self.retained_total,
-            "traces": traces,
+            "traces": self.snapshot() if self.enabled else [],
+            "batches": batches,
         }

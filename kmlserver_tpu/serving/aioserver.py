@@ -35,6 +35,7 @@ from __future__ import annotations
 import asyncio
 import logging
 import socket
+import time
 
 from .. import faults
 from .app import RecommendApp
@@ -115,7 +116,7 @@ class _Conn(asyncio.Protocol):
         self.closed = False
         self._next_seq = 0    # next request sequence number to assign
         self._next_write = 0  # next sequence number to write out
-        self._staged: dict[int, tuple[tuple, bool]] = {}
+        self._staged: dict[int, tuple[tuple, bool, object]] = {}
         self._reading_paused = False
 
     # ---------- transport events ----------
@@ -168,10 +169,14 @@ class _Conn(asyncio.Protocol):
     # ---------- request framing ----------
 
     def _process_buffer(self) -> None:
+        recorder = self.state.app.recorder
         while (
             not self.closed
             and self._next_seq - self._next_write < _MAX_PIPELINE
         ):
+            # a traced request's root span starts where its parse does
+            # (one attribute check while tracing is off)
+            t_received = time.perf_counter() if recorder.active else None
             end = self.buf.find(b"\r\n\r\n")
             if end < 0:
                 if len(self.buf) > _MAX_HEAD:
@@ -218,7 +223,8 @@ class _Conn(asyncio.Protocol):
             body = self.buf[end + 4: total] or None
             self.buf = self.buf[total:]
             self._dispatch(
-                method, path, body, close_after, trace_header, budget_header
+                method, path, body, close_after, trace_header, budget_header,
+                t_received,
             )
 
     def _bad_request(self, detail: str) -> None:
@@ -237,6 +243,7 @@ class _Conn(asyncio.Protocol):
     def _dispatch(
         self, method: str, path: str, body: bytes | None, close_after: bool,
         trace_header: str | None = None, budget_header: str | None = None,
+        t_received: float | None = None,
     ) -> None:
         state = self.state
         app = state.app
@@ -266,11 +273,12 @@ class _Conn(asyncio.Protocol):
             if delay > 0:
                 self.loop.call_later(
                     delay, self._recommend, seq, path, body, close_after,
-                    trace_header, budget_header,
+                    trace_header, budget_header, t_received,
                 )
                 return
             self._recommend(
-                seq, path, body, close_after, trace_header, budget_header
+                seq, path, body, close_after, trace_header, budget_header,
+                t_received,
             )
             return
         try:
@@ -290,6 +298,7 @@ class _Conn(asyncio.Protocol):
     def _recommend(
         self, seq: int, path: str, body: bytes | None, close_after: bool,
         trace_header: str | None = None, budget_header: str | None = None,
+        t_received: float | None = None,
     ) -> None:
         """The recommend-POST tail of :meth:`_dispatch`, split out so an
         armed fault stall can re-enter it from a loop timer with its
@@ -300,6 +309,7 @@ class _Conn(asyncio.Protocol):
         if self.closed:  # connection dropped during a fault stall
             state.leave()
             return
+        trace = None
         try:
             if app.batcher is None:
                 # batching disabled: the blocking engine call must
@@ -317,7 +327,7 @@ class _Conn(asyncio.Protocol):
                 )
                 return
             response, future, t0, trace = app.submit_recommend(
-                body, trace_header, budget_header
+                body, trace_header, budget_header, t_received
             )
             if response is None:
                 if isinstance(future, asyncio.Future):
@@ -345,7 +355,7 @@ class _Conn(asyncio.Protocol):
                 500, {"Content-Type": "application/json"},
                 b'{"detail": "Internal Server Error"}',
             )
-        self._stage(seq, response, close_after)
+        self._stage(seq, response, close_after, trace)
         state.leave()
 
     def _finish_recommend(
@@ -353,7 +363,9 @@ class _Conn(asyncio.Protocol):
     ) -> None:
         if not self.closed:
             response = self.state.app.finish_recommend(future, t0, trace=trace)
-            self._stage(seq, response, close_after)
+            self._stage(seq, response, close_after, trace)
+        elif trace is not None:
+            self.state.app.trace_written(trace)  # nobody left to write to
         self.state.leave()
         if not self.closed:
             self._process_buffer()  # pipeline slots freed — keep parsing
@@ -382,24 +394,42 @@ class _Conn(asyncio.Protocol):
 
     # ---------- response writing ----------
 
-    def _stage(self, seq: int, response, close_after: bool) -> None:
+    def _stage(
+        self, seq: int, response, close_after: bool, trace=None,
+    ) -> None:
         """Stage response ``seq``; flush the contiguous ready prefix as a
-        single write (responses must leave in request order)."""
+        single write (responses must leave in request order). ``trace``
+        is the request's deferred trace (``app.submit_recommend`` with
+        ``t_received``): the flush that carries its response records its
+        ``write`` span — encode, join, ``transport.write`` returned — and
+        closes it."""
+        app = self.state.app
         if self.closed or self.transport is None:
+            if trace is not None and trace.deferred:
+                app.trace_written(trace)
             return
-        self._staged[seq] = (response, close_after)
+        self._staged[seq] = (response, close_after, trace)
         if seq != self._next_write:
             return
         chunks: list[bytes] = []
+        traces = None  # the deferred traces this flush closes, if any
         closing = False
+        t_write = time.perf_counter() if app.recorder.active else 0.0
         while self._next_write in self._staged:
-            response, close_after = self._staged.pop(self._next_write)
+            response, close_after, trace = self._staged.pop(self._next_write)
             self._next_write += 1
+            if trace is not None and trace.deferred:
+                traces = (traces or []) + [trace]
             closing = close_after or self.state.draining
             chunks.append(self._encode(response, closing))
             if closing:
                 break
         self.transport.write(b"".join(chunks))
+        if traces:
+            t_written = time.perf_counter()
+            for trace in traces:
+                # t_write is 0.0 only where tracing went on mid-flush
+                app.trace_written(trace, t_write or t_written, t_written)
         if closing:
             self.transport.close()
             self.closed = True
@@ -449,6 +479,7 @@ async def run_async(app: RecommendApp, port: int, ready=None) -> int:
             metrics=app.metrics,
             lag_monitor=app.loop_lag,
             forecaster=getattr(app, "forecaster", None),
+            recorder=app.recorder,
         )
     if app.loop_lag is not None:
         # arm the drift tick on THIS loop: timer-due minus timer-ran is

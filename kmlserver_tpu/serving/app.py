@@ -292,6 +292,7 @@ class RecommendApp:
                 metrics=self.metrics,
                 lag_monitor=self.loop_lag,
                 forecaster=self.forecaster,
+                recorder=self.recorder,
             )
         # template/static roots honor APP_PATH_FROM_ROOT like the reference
         # (rest_api/app/main.py:44-48 resolves its template/static dirs from
@@ -422,6 +423,10 @@ class RecommendApp:
                     robustness=self._robustness_state(),
                     shard_counts=getattr(
                         self.engine, "shard_dispatch_counts", None
+                    ),
+                    seed_slots=(
+                        getattr(self.engine, "seed_slots_real", 0),
+                        getattr(self.engine, "seed_slots_padded", 0),
                     ),
                     cost=getattr(self.engine, "cost_model", None),
                     slo=self.slo,
@@ -576,6 +581,11 @@ class RecommendApp:
         state["mesh_expired_on_arrival_total"] = getattr(
             worker, "expired_on_arrival", 0
         )
+        # dispatches that paid a compile on the serving path (all four
+        # sites in engine.py bump the one attribute) — must stay 0
+        state["unwarmed_dispatches_total"] = getattr(
+            self.engine, "unwarmed_dispatches", 0
+        )
         # span-tracing bookkeeping: began is the zero-cost proof counter
         # (must stay 0 while KMLS_TRACE_SAMPLE=0)
         state["traces_began_total"] = self.recorder.began
@@ -661,7 +671,10 @@ class RecommendApp:
                 return _json_response(
                     409, {"detail": "a profile capture is already running"}
                 )
-            self._profile_thread = profiling.start_capture(label, seconds)
+            # the capture switches the recorder's capture mode itself
+            self._profile_thread = profiling.start_capture(
+                label, seconds, recorder=getattr(self, "recorder", None)
+            )
         return _json_response(
             202,
             {
@@ -732,22 +745,56 @@ class RecommendApp:
 
     # ---------- span tracing (ISSUE 9) ----------
 
-    def _trace_begin(self, header: str | None):
+    def _trace_begin(
+        self, header: str | None, t_start: float, defer: bool = False,
+    ):
         """→ a TraceContext for this request, or None. The one
-        ``enabled`` check is the ENTIRE per-request cost with tracing
-        disabled (KMLS_TRACE_SAMPLE=0): no context, no id generation,
-        no allocation — the recorder's ``began`` counter proves it."""
+        ``active`` check is the ENTIRE per-request cost with tracing
+        disabled (KMLS_TRACE_SAMPLE=0, no capture open): no context, no
+        id generation, no allocation — the recorder's ``began`` counter
+        proves it. The trace starts at ``t_start`` (where the request
+        began) and its ``parse`` span ends here: body read, JSON decode
+        and validation are behind us. ``defer``: the transport, not the
+        response builder, finishes the trace (:meth:`trace_written`)."""
         rec = self.recorder
-        return rec.begin(header) if rec.enabled else None
+        if not rec.active:
+            return None
+        trace = rec.begin(header, t_start)
+        if trace is not None:
+            trace.deferred = defer
+            trace.span("parse", t_start, time.perf_counter())
+        return trace
 
     def _trace_finish(self, trace, status: str, headers: dict) -> None:
-        """Close the trace (tail-based retention decides whether it is
-        kept) and echo ``X-KMLS-Trace`` so a replay/bench client can join
-        its client-side timing to the server-side span breakdown."""
+        """The response is built: echo ``X-KMLS-Trace`` so a replay/bench
+        client can join its client-side timing to the server-side span
+        breakdown, and close the trace (tail-based retention decides
+        whether it is kept) — unless the transport closes it after its
+        write, in which case only the status is stamped."""
         if trace is None:
             return
-        self.recorder.finish(trace, status, time.perf_counter() - trace.t0)
         headers["X-KMLS-Trace"] = trace.trace_id
+        if trace.deferred:
+            trace.status = status
+        else:
+            self.recorder.finish(
+                trace, status, time.perf_counter() - trace.t0
+            )
+
+    def trace_written(
+        self, trace, t_start: float = 0.0, t_end: float = 0.0,
+    ) -> None:
+        """The async transport's half of a deferred trace: the response
+        has been passed to ``transport.write`` (``t_start``→``t_end``,
+        the ``write`` span; none where the connection was gone), so the
+        root ``request`` span ends and the trace closes."""
+        if t_end:
+            trace.span("write", t_start, t_end)
+        else:
+            t_end = time.perf_counter()
+        # "open": no response was ever built (the client hung up first)
+        status = "disconnected" if trace.status == "open" else trace.status
+        self.recorder.finish(trace, status, t_end - trace.t0)
 
     # ---------- degradation (the fault-tolerance contract) ----------
 
@@ -1024,9 +1071,10 @@ class RecommendApp:
         self, t0: float, recs: list[str], source: str, cached: bool = False,
         trace=None, songs: list[str] | None = None,
     ) -> Response:
-        # compose span: answer-available (the future just resolved — the
+        # respond span: answer-available (the future just resolved — the
         # caller invokes this immediately after) → response bytes built
-        t_compose = time.perf_counter() if trace is not None else 0.0
+        # (the engine's own blend/id→name is the batch trace's `compose`)
+        t_respond = time.perf_counter() if trace is not None else 0.0
         self.metrics.record(source, time.perf_counter() - t0)
         status, headers, payload = _json_response(
             200,
@@ -1054,7 +1102,7 @@ class RecommendApp:
             self.metrics.record_degraded(reason)
         if trace is not None:
             trace.span(
-                "compose", t_compose, time.perf_counter(),
+                "respond", t_respond, time.perf_counter(),
                 {"source": source},
             )
             if cached:
@@ -1204,7 +1252,7 @@ class RecommendApp:
         future, joined = self.cache.join_or_lead(key, lead)
         if joined and trace is not None:
             # a joiner shares the leader's batch slot: it gets no
-            # queue/device spans of its own (it never dispatched)
+            # queue/batch spans of its own (it never dispatched)
             trace.annotate("singleflight", "joined")
         if not joined:
             cache = self.cache
@@ -1279,7 +1327,7 @@ class RecommendApp:
         if err is not None:
             return err
         # trace begins AFTER validation: malformed bodies never allocate
-        trace = self._trace_begin(trace_header)
+        trace = self._trace_begin(trace_header, t0)
         deadline, budget_ms, expired = self._effective_deadline(
             t0, budget_header
         )
@@ -1325,7 +1373,7 @@ class RecommendApp:
 
     def submit_recommend(
         self, body: bytes | None, trace_header: str | None = None,
-        budget_header: str | None = None,
+        budget_header: str | None = None, t_received: float | None = None,
     ):
         """Non-blocking twin of :meth:`_post_recommend` for the asyncio
         transport: → ``(response, None, t0, trace)`` when the answer is
@@ -1335,6 +1383,13 @@ class RecommendApp:
         ``trace`` rides the TUPLE, not the future: singleflight shares
         one future across joined connections, and each connection's trace
         is its own.
+
+        ``t_received`` is when the transport began parsing this request.
+        A transport that passes it owns the trace's end too: the trace
+        starts there, no response builder finishes it, and the transport
+        calls :meth:`trace_written` once its write has returned. Without
+        it (in-process callers) the trace starts here and ends where the
+        response is built, as in :meth:`_post_recommend`.
 
         Cache semantics mirror :meth:`recommend_direct`: hit → immediate
         response; miss → singleflight through the batcher, so concurrent
@@ -1352,7 +1407,10 @@ class RecommendApp:
         err, songs = self._validate_recommend(body)
         if err is not None:
             return err, None, t0, None
-        trace = self._trace_begin(trace_header)
+        trace = self._trace_begin(
+            trace_header, t0 if t_received is None else t_received,
+            defer=t_received is not None,
+        )
         deadline, budget_ms, expired = self._effective_deadline(
             t0, budget_header
         )
@@ -1364,7 +1422,7 @@ class RecommendApp:
                 self._degraded_response(
                     t0, songs, "deadline-expired", trace=trace
                 ),
-                None, t0, None,
+                None, t0, trace,
             )
         # serve mesh (ISSUE 16): same pre-check as _post_recommend —
         # never cache/merge an answer a dark slab can't contribute to
@@ -1372,7 +1430,7 @@ class RecommendApp:
         if missing:
             return (
                 self._mesh_shard_response(t0, songs, missing[0], trace=trace),
-                None, t0, None,
+                None, t0, trace,
             )
         if self.batcher is None:
             try:
@@ -1385,23 +1443,23 @@ class RecommendApp:
                         self._mesh_shard_response(
                             t0, songs, exc.rank, trace=trace
                         ),
-                        None, t0, None,
+                        None, t0, trace,
                     )
                 reason = self._degrade_reason(exc)
                 if reason is not None:
                     return (
                         self._degraded_response(t0, songs, reason, trace=trace),
-                        None, t0, None,
+                        None, t0, trace,
                     )
                 return (
                     self._recommend_error_response(exc, trace=trace),
-                    None, t0, None,
+                    None, t0, trace,
                 )
             return (
                 self._recommend_result_response(
                     t0, recs, source, cached=cached, trace=trace, songs=songs
                 ),
-                None, t0, None,
+                None, t0, trace,
             )
         try:
             state, payload = self._cache_lookup_or_lead(songs, deadline, trace)
@@ -1420,17 +1478,17 @@ class RecommendApp:
             if isinstance(exc, MeshShardUnavailable):
                 return (
                     self._mesh_shard_response(t0, songs, exc.rank, trace=trace),
-                    None, t0, None,
+                    None, t0, trace,
                 )
             reason = self._degrade_reason(exc)
             if reason is not None:
                 return (
                     self._degraded_response(t0, songs, reason, trace=trace),
-                    None, t0, None,
+                    None, t0, trace,
                 )
             return (
                 self._recommend_error_response(exc, trace=trace),
-                None, t0, None,
+                None, t0, trace,
             )
         if state == "hit":
             return (
@@ -1438,7 +1496,7 @@ class RecommendApp:
                     t0, payload[0], payload[1], cached=True, trace=trace,
                     songs=songs,
                 ),
-                None, t0, None,
+                None, t0, trace,
             )
         return None, payload, t0, trace
 

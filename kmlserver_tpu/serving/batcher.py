@@ -305,20 +305,69 @@ class _Pending:
     deadline: float | None = None
     retries: int = 0
     # per-request TraceContext (observability.trace) riding the pipeline
-    # so completion can record queue/device spans; None = untraced — the
+    # so completion can record queue/batch spans; None = untraced — the
     # default, costing nothing (tracing-off requests never construct one)
     trace: object | None = None
+    # traced requests only: when the admission decision was made, which
+    # is where the queue span starts (0.0 = untraced; t_enqueue then)
+    t_admitted: float = 0.0
 
 
-def _takes_deadline(engine) -> bool:
-    """True when the engine's ``recommend_many_async`` accepts a
-    ``deadline`` kwarg (the real engine does; test fakes with the bare
-    legacy signature must keep working)."""
+def _engine_takes(engine, kwarg: str) -> bool:
+    """True when the engine's ``recommend_many_async`` accepts ``kwarg``
+    (the real engine takes ``deadline`` and ``trace``; test fakes with
+    the bare legacy signature must keep working)."""
     try:
         sig = inspect.signature(engine.recommend_many_async)
     except (TypeError, ValueError, AttributeError):
         return False
-    return "deadline" in sig.parameters
+    return kwarg in sig.parameters
+
+
+def _begin_batch_trace(recorder, t_dispatch: float, n_requests: int, idx: int):
+    """The batch is formed: it gets a trace of its own while the app's
+    recorder is active (→ None otherwise: one attribute check)."""
+    if recorder is None or not recorder.active:
+        return None
+    return recorder.begin_batch(t_dispatch, requests=n_requests, replica=idx)
+
+
+def _record_request_spans(
+    batch: list[_Pending], btrace, t_dispatch: float, t_slot: float,
+    idx: int, finish,
+) -> None:
+    """The ONE place both batchers record a batch's spans on its member
+    requests, BEFORE their futures resolve (the finishing thread must
+    observe a complete span list when the result lands): ``queue``
+    (admitted → batch formed; ``slot_wait_ms`` is the part of it spent
+    with the pipeline full, from ``t_slot``, the rest being the batch
+    window) and ``batch`` (batch formed → here), which names the
+    batch's own trace by ``batch_id``. An untraced member costs one
+    is-None check."""
+    now = time.perf_counter()
+    batch_id = btrace.attrs["batch_id"] if btrace is not None else None
+    # hedge outcome (ISSUE 18): the mesh finish() stamps its won/lost/
+    # cancelled decision on itself; ride it onto every traced request
+    hedged = getattr(finish, "_kmls_hedge", None)
+    for pending in batch:
+        trace = pending.trace
+        if trace is None:
+            continue
+        t_queued = pending.t_admitted or pending.t_enqueue
+        slot_s = t_dispatch - max(t_slot, t_queued) if t_slot else 0.0
+        trace.span(
+            "queue", t_queued, t_dispatch,
+            {
+                "batch": len(batch),
+                "slot_wait_ms": round(max(slot_s, 0.0) * 1e3, 4),
+            },
+        )
+        trace.span(
+            "batch", t_dispatch, now,
+            {"batch_id": batch_id, "replica": idx},
+        )
+        if hedged is not None:
+            trace.annotate("hedged", hedged)
 
 
 def _batch_deadline(batch: list[_Pending]) -> float | None:
@@ -349,10 +398,14 @@ class MicroBatcher:
         metrics=None,
         lag_monitor=None,
         forecaster=None,
+        recorder=None,
     ):
         self.engine = engine
         self.max_size = max_size
         self.window_s = window_ms / 1e3
+        # the app's SpanRecorder (None = batches are never traced): a
+        # dispatched batch gets a trace of its own while it is active
+        self.recorder = recorder
         # predictive serving (ISSUE 17): a serving.forecast
         # .TrafficForecaster, or None (the default — every forecast
         # touchpoint below is one is-None check, the zero-cost contract)
@@ -389,7 +442,8 @@ class MicroBatcher:
         # (the mesh stamps it on peer frames as remaining budget).
         # Detected once here so fakes with the bare legacy signature
         # keep working untouched.
-        self._engine_takes_deadline = _takes_deadline(engine)
+        self._engine_takes_deadline = _engine_takes(engine, "deadline")
+        self._engine_takes_trace = _engine_takes(engine, "trace")
         self._consec_failures: dict[int, int] = {}
         self._ejected: dict[int, float] = {}  # idx -> perf_counter at eject
         self._probing: set[int] = set()  # half-open: one trial batch out
@@ -409,11 +463,12 @@ class MicroBatcher:
             queue.PriorityQueue()
         )
         self._seq = itertools.count()
-        # one completion lane PER REPLICA: (batch, finish_fn, t_dispatch)
-        # triples awaiting their device results, FIFO within a lane — jax
-        # executes dispatches in order per device, so completion order
-        # matches per lane (but NOT across lanes; a single global lane
-        # would head-of-line-block fast devices behind a slow one).
+        # one completion lane PER REPLICA: (batch, finish_fn, t_dispatch,
+        # t_slot, batch trace) tuples awaiting their device results, FIFO
+        # within a lane — jax executes dispatches in order per device, so
+        # completion order matches per lane (but NOT across lanes; a
+        # single global lane would head-of-line-block fast devices behind
+        # a slow one).
         # Lanes + their completer threads are created on first dispatch
         # to a replica index, by the collector thread only.
         self._completions: dict[int, "queue.Queue"] = {}
@@ -641,7 +696,7 @@ class MicroBatcher:
         ``deadline`` (perf_counter seconds) rides the pending entry
         through collection and dispatch; ``trace`` (a TraceContext, None
         when tracing is off) rides it so completion can record the
-        queue/device spans."""
+        queue/batch spans."""
         now = time.perf_counter()
         with self._rate_lock:
             self._arrivals.append(now)
@@ -670,33 +725,39 @@ class MicroBatcher:
                         "all serving replicas ejected; next probe in "
                         f"<= {self.probe_interval_s:.1f}s"
                     )
+        decision, pressure = "admit", 0.0
         if self.shed_budget_s > 0:
             decision, pressure = self._admission.decide(
                 self.projected_queue_wait_s()
             )
-            if decision == "shed":
-                with self._rate_lock:  # += from concurrent request threads
-                    self.shed_total += 1
-                if self.metrics is not None:
-                    self.metrics.record_shed()
-                # report the EFFECTIVE wait the decision was made on, not
-                # the bare projection — an EWMA-driven shed right after a
-                # burst would otherwise claim a sub-budget wait exceeded
-                # the budget
-                raise Overloaded(
-                    self._admission.retry_after_jittered_s(),
-                    pressure * self.shed_budget_s * 1e3,
-                )
-            if decision == "degrade":
-                with self._rate_lock:
-                    self.degrade_total += 1
-                # the app layer answers from the popularity fallback
-                # (record_degraded("overload") happens there, next to the
-                # deadline/replica-loss reasons)
-                raise OverloadDegraded(pressure)
+        t_admitted = 0.0
+        if trace is not None:
+            # the ladder's decision (and the breaker's pre-check above)
+            t_admitted = time.perf_counter()
+            trace.span("admit", now, t_admitted, {"decision": decision})
+        if decision == "shed":
+            with self._rate_lock:  # += from concurrent request threads
+                self.shed_total += 1
+            if self.metrics is not None:
+                self.metrics.record_shed()
+            # report the EFFECTIVE wait the decision was made on, not
+            # the bare projection — an EWMA-driven shed right after a
+            # burst would otherwise claim a sub-budget wait exceeded
+            # the budget
+            raise Overloaded(
+                self._admission.retry_after_jittered_s(),
+                pressure * self.shed_budget_s * 1e3,
+            )
+        if decision == "degrade":
+            with self._rate_lock:
+                self.degrade_total += 1
+            # the app layer answers from the popularity fallback
+            # (record_degraded("overload") happens there, next to the
+            # deadline/replica-loss reasons)
+            raise OverloadDegraded(pressure)
         pending = _Pending(
             seeds=seeds, future=Future(), t_enqueue=now, deadline=deadline,
-            trace=trace,
+            trace=trace, t_admitted=t_admitted,
         )
         self._queue.put((1, next(self._seq), pending))
         return pending.future
@@ -833,12 +894,15 @@ class MicroBatcher:
             # undispatched-but-queued device calls PER replica, block here
             # (requests keep queueing upstream and land in bigger batches
             # — backpressure, not failure).
+            t_slot = 0.0  # when the wait for a pipeline slot began, if any
             with self._pipe_cond:
                 while (
                     self._total_inflight_locked()
                     >= self.max_inflight
                     * max(1, self._n_healthy_locked(self._n_replicas()))
                 ):
+                    if not t_slot:
+                        t_slot = time.perf_counter()
                     self._pipe_cond.wait(timeout=1.0)
             # deadline check AFTER the capacity wait (which can block for
             # seconds under overload — exactly when deadlines matter): a
@@ -875,17 +939,22 @@ class MicroBatcher:
                     if not pending.future.done():
                         pending.future.set_exception(err)
                 continue
+            btrace = _begin_batch_trace(
+                self.recorder, t_dispatch, len(batch), idx
+            )
             try:
                 # the replica kwarg is passed only when there's a choice:
                 # single-replica engines (fakes, the native host kernel)
                 # keep the bare signature they always had; the deadline
-                # kwarg only when the engine declared it (deadline
-                # propagation across the mesh)
+                # and trace kwargs only when the engine declared them
+                # (deadline propagation across the mesh; batch spans)
                 kwargs = {}
                 if n > 1:
                     kwargs["replica"] = idx
                 if self._engine_takes_deadline:
                     kwargs["deadline"] = _batch_deadline(batch)
+                if btrace is not None and self._engine_takes_trace:
+                    kwargs["trace"] = btrace
                 finish = self.engine.recommend_many_async(
                     [p.seeds for p in batch], **kwargs
                 )
@@ -896,14 +965,20 @@ class MicroBatcher:
                     if lane:
                         lane.pop()
                     self._pipe_cond.notify_all()
+                if btrace is not None:
+                    self.recorder.finish_batch(btrace, "error")
                 self._on_replica_failure(idx, batch, exc)
                 continue
-            self._completion_lane(idx).put((batch, finish, t_dispatch))
+            if self.metrics is not None:
+                self.metrics.record_batch_size(len(batch))
+            self._completion_lane(idx).put(
+                (batch, finish, t_dispatch, t_slot, btrace)
+            )
 
     def _complete_loop(self, idx: int) -> None:
         lane = self._completions[idx]
         while True:
-            batch, finish, t_dispatch = lane.get()
+            batch, finish, t_dispatch, t_slot, btrace = lane.get()
             try:
                 results = finish()
                 err = None
@@ -932,6 +1007,8 @@ class MicroBatcher:
                     self._note_replica_ok_locked(idx)
                 self._pipe_cond.notify_all()
             if err is not None:
+                if btrace is not None:
+                    self.recorder.finish_batch(btrace, "error")
                 self._on_replica_failure(idx, batch, err)
                 continue
             # the batch LEADER's measured queue wait grounds the admission
@@ -940,27 +1017,16 @@ class MicroBatcher:
             self._admission.note_queue_wait(
                 t_dispatch - batch[0].t_enqueue, now=t_complete
             )
-            # span recording BEFORE the futures resolve: the finishing
-            # thread (app layer) must observe a complete span list when
-            # the result lands (TraceContext's documented ordering)
-            # hedge outcome (ISSUE 18): the mesh finish() stamps its
-            # won/lost/cancelled decision on itself; ride it onto every
-            # traced request in the batch
-            hedged = getattr(finish, "_kmls_hedge", None)
-            for pending in batch:
-                if pending.trace is not None:
-                    pending.trace.span(
-                        "queue", pending.t_enqueue, t_dispatch,
-                        {"batch": len(batch)},
-                    )
-                    pending.trace.span(
-                        "device", t_dispatch, t_complete, {"replica": idx},
-                    )
-                    if hedged is not None:
-                        pending.trace.annotate("hedged", hedged)
+            _record_request_spans(
+                batch, btrace, t_dispatch, t_slot, idx, finish
+            )
             for pending, result in zip(batch, results):
                 if not pending.future.done():  # deadline may have expired it
                     pending.future.set_result(result)
+            if btrace is not None:
+                # finish() returned → futures set
+                btrace.lap("resolve")
+                self.recorder.finish_batch(btrace)
             if self.metrics is not None:
                 for pending in batch:
                     self.metrics.record_attribution(
@@ -1106,11 +1172,17 @@ class AsyncMicroBatcher:
         metrics=None,
         lag_monitor=None,
         forecaster=None,
+        recorder=None,
     ):
         from concurrent.futures import ThreadPoolExecutor
 
         self.engine = engine
         self.max_size = max_size
+        # the app's SpanRecorder, as in MicroBatcher (None = no batch
+        # traces); _slot_wait_from is when a flush was first refused for
+        # a full pipeline while it was active (None = not waiting)
+        self.recorder = recorder
+        self._slot_wait_from: float | None = None
         self.max_inflight = max(1, max_inflight)  # per replica
         # predictive serving (ISSUE 17), mirroring MicroBatcher: None =
         # every touchpoint is one is-None check (the zero-cost contract)
@@ -1149,7 +1221,8 @@ class AsyncMicroBatcher:
         # (the mesh stamps it on peer frames as remaining budget).
         # Detected once here so fakes with the bare legacy signature
         # keep working untouched.
-        self._engine_takes_deadline = _takes_deadline(engine)
+        self._engine_takes_deadline = _engine_takes(engine, "deadline")
+        self._engine_takes_trace = _engine_takes(engine, "trace")
         self._consec_failures: dict[int, int] = {}
         self._ejected: dict[int, float] = {}
         self._probing: set[int] = set()
@@ -1324,26 +1397,32 @@ class AsyncMicroBatcher:
                     "all serving replicas ejected; next probe in "
                     f"<= {self.probe_interval_s:.1f}s"
                 )
+        decision, pressure = "admit", 0.0
         if self.shed_budget_s > 0:
             decision, pressure = self._admission.decide(
                 self.projected_queue_wait_s()
             )
-            if decision == "shed":
-                self.shed_total += 1
-                if self.metrics is not None:
-                    self.metrics.record_shed()
-                # effective wait, mirroring the threaded twin
-                raise Overloaded(
-                    self._admission.retry_after_jittered_s(),
-                    pressure * self.shed_budget_s * 1e3,
-                )
-            if decision == "degrade":
-                self.degrade_total += 1
-                raise OverloadDegraded(pressure)
+        t_admitted = 0.0
+        if trace is not None:
+            # the ladder's decision (mirrors the threaded twin)
+            t_admitted = time.perf_counter()
+            trace.span("admit", now, t_admitted, {"decision": decision})
+        if decision == "shed":
+            self.shed_total += 1
+            if self.metrics is not None:
+                self.metrics.record_shed()
+            # effective wait, mirroring the threaded twin
+            raise Overloaded(
+                self._admission.retry_after_jittered_s(),
+                pressure * self.shed_budget_s * 1e3,
+            )
+        if decision == "degrade":
+            self.degrade_total += 1
+            raise OverloadDegraded(pressure)
         future = loop.create_future()
         pending = _Pending(
             seeds=seeds, future=future, t_enqueue=now, deadline=deadline,
-            trace=trace,
+            trace=trace, t_admitted=t_admitted,
         )
         self._pending.append(pending)
         if deadline is not None:
@@ -1406,6 +1485,7 @@ class AsyncMicroBatcher:
         if any(p.future.done() for p in self._pending):
             self._pending = [p for p in self._pending if not p.future.done()]
         if not self._pending:
+            self._slot_wait_from = None  # nobody is left waiting
             return
         n = self._n_replicas()
         if self._total_inflight() >= min(
@@ -1416,9 +1496,18 @@ class AsyncMicroBatcher:
             # can actually run concurrently: the next completion
             # re-flushes and pending requests pile into a bigger batch
             # (backpressure, not failure)
+            rec = self.recorder
+            if (
+                rec is not None and rec.active
+                and self._slot_wait_from is None
+            ):
+                self._slot_wait_from = time.perf_counter()
             return
         batch = self._pending[: self.max_size]
         del self._pending[: len(batch)]
+        t_slot = self._slot_wait_from or 0.0
+        if not self._pending:
+            self._slot_wait_from = None  # nobody is left waiting
         idx = self._pick_replica(n) if (n > 1 or self.eject_threshold > 0) else 0
         if idx < 0:
             # total replica loss, no probe due: degrade, don't dispatch
@@ -1428,24 +1517,33 @@ class AsyncMicroBatcher:
                     pending.future.set_exception(err)
             return
         t_dispatch = time.perf_counter()
+        btrace = _begin_batch_trace(
+            self.recorder, t_dispatch, len(batch), idx
+        )
         try:
             # replica kwarg only when there's a choice — single-replica
             # engines (fakes, native host kernel) keep the bare
-            # signature; deadline only when the engine declared it
-            # (mesh deadline propagation, mirroring the threaded twin)
+            # signature; deadline and trace only when the engine
+            # declared them (mirroring the threaded twin)
             kwargs = {}
             if n > 1:
                 kwargs["replica"] = idx
             if self._engine_takes_deadline:
                 kwargs["deadline"] = _batch_deadline(batch)
+            if btrace is not None and self._engine_takes_trace:
+                kwargs["trace"] = btrace
             finish = self.engine.recommend_many_async(
                 [p.seeds for p in batch], **kwargs
             )
         except Exception as exc:  # propagate, don't die
+            if btrace is not None:
+                self.recorder.finish_batch(btrace, "error")
             self._on_replica_failure(idx, batch, exc, loop)
             if self._pending:
                 loop.call_soon(self._flush, loop)
             return
+        if self.metrics is not None:
+            self.metrics.record_batch_size(len(batch))
         self._inflight_by_replica[idx] = (
             self._inflight_by_replica.get(idx, 0) + 1
         )
@@ -1474,7 +1572,9 @@ class AsyncMicroBatcher:
                 # kernel stall escalates admission before the next
                 # request is even parsed
                 self.lag_monitor.note(time.perf_counter() - t_dispatch)
-            self._resolve(batch, outcome, t_dispatch, loop, idx, finish)
+            self._resolve(
+                batch, outcome, t_dispatch, loop, idx, finish, t_slot, btrace
+            )
             return
 
         def run_finish():
@@ -1486,7 +1586,8 @@ class AsyncMicroBatcher:
         task = self._executor.submit(run_finish)
         task.add_done_callback(
             lambda f: loop.call_soon_threadsafe(
-                self._complete, batch, f, t_dispatch, loop, idx, finish
+                self._complete, batch, f, t_dispatch, loop, idx, finish,
+                t_slot, btrace,
             )
         )
         if self._pending:
@@ -1494,15 +1595,20 @@ class AsyncMicroBatcher:
             loop.call_soon(self._flush, loop)
 
     def _complete(
-        self, batch, task, t_dispatch: float, loop, idx: int, finish=None
+        self, batch, task, t_dispatch: float, loop, idx: int, finish=None,
+        t_slot: float = 0.0, btrace=None,
     ) -> None:
         # kmls-verify: allow[loopblock] — scheduled via
         # call_soon_threadsafe from the executor task's done-callback,
         # so the task is complete and result() returns immediately
-        self._resolve(batch, task.result(), t_dispatch, loop, idx, finish)
+        outcome = task.result()
+        self._resolve(
+            batch, outcome, t_dispatch, loop, idx, finish, t_slot, btrace
+        )
 
     def _resolve(
-        self, batch, outcome, t_dispatch: float, loop, idx: int, finish=None
+        self, batch, outcome, t_dispatch: float, loop, idx: int, finish=None,
+        t_slot: float = 0.0, btrace=None,
     ) -> None:
         results, err = outcome
         t_complete = time.perf_counter()
@@ -1511,6 +1617,8 @@ class AsyncMicroBatcher:
         if lane:
             lane.popleft()
         if err is not None:
+            if btrace is not None:
+                self.recorder.finish_batch(btrace, "error")
             self._on_replica_failure(idx, batch, err, loop)
         else:
             self._note_replica_ok(idx)
@@ -1526,23 +1634,16 @@ class AsyncMicroBatcher:
                 self._admission.note_queue_wait(
                     t_dispatch - batch[0].t_enqueue, now=t_complete
                 )
-            # spans recorded before the futures resolve (mirrors the
-            # threaded completer's ordering contract)
-            hedged = getattr(finish, "_kmls_hedge", None)
-            for pending in batch:
-                if pending.trace is not None:
-                    pending.trace.span(
-                        "queue", pending.t_enqueue, t_dispatch,
-                        {"batch": len(batch)},
-                    )
-                    pending.trace.span(
-                        "device", t_dispatch, t_complete, {"replica": idx},
-                    )
-                    if hedged is not None:
-                        pending.trace.annotate("hedged", hedged)
+            _record_request_spans(
+                batch, btrace, t_dispatch, t_slot, idx, finish
+            )
             for pending, result in zip(batch, results):
                 if not pending.future.done():
                     pending.future.set_result(result)
+            if btrace is not None:
+                # finish() returned → futures set: the executor→loop hop
+                btrace.lap("resolve")
+                self.recorder.finish_batch(btrace)
             if self.metrics is not None:
                 for pending in batch:
                     self.metrics.record_attribution(

@@ -355,6 +355,12 @@ class RecommendEngine:
         # dispatches whose (batch, length) shape was never pre-warmed —
         # each one paid a jit compile on the serving path; must stay 0
         self.unwarmed_dispatches = 0
+        # seed slots staged for the rule lookup, by what they hold: a
+        # known seed's id, or -1 padding up to the (rows, length) bucket
+        # (kmls_seed_slots_total; the real count is also each batch's
+        # sum of seed lengths, for an exact roofline join)
+        self.seed_slots_real = 0
+        self.seed_slots_padded = 0
         # ---- device-truth cost attribution (ISSUE 12) ----
         # per-kernel MFU/roofline + memory/compile telemetry; None with
         # KMLS_COSTMODEL=0, making every call site one attribute check
@@ -1678,11 +1684,12 @@ class RecommendEngine:
     def _fill_seed_rows(
         bundle: RuleBundle, seed_sets: list[list[str]],
         arr: np.ndarray, length: int,
-    ) -> np.ndarray:
+    ) -> tuple[np.ndarray, int]:
         """Membership-filter each seed set into its -1-padded row of
-        ``arr`` → per-row any-known-seed mask. The ONE copy of the seed
-        filtering rule — the native and device paths both go through it,
-        which is what keeps them bit-identical."""
+        ``arr`` → (per-row any-known-seed mask, how many slots of the
+        array hold a seed). The ONE copy of the seed filtering rule —
+        the native and device paths both go through it, which is what
+        keeps them bit-identical."""
         for r, seeds in enumerate(seed_sets):
             ids = [
                 bundle.index[s]
@@ -1690,11 +1697,24 @@ class RecommendEngine:
                 if s in bundle.index and bundle.known_mask[bundle.index[s]]
             ][:length]
             arr[r, : len(ids)] = ids
-        return (arr[: len(seed_sets)] >= 0).any(axis=1)
+        filled = arr[: len(seed_sets)] >= 0
+        return filled.any(axis=1), int(filled.sum())
+
+    def _note_staged(self, arr: np.ndarray, n_real: int, trace) -> None:
+        """The staged array is filled and on its way: count its slots
+        (always on: two integer adds a batch) and, on a traced batch,
+        close the ``stage`` span and say what was staged."""
+        self.seed_slots_real += n_real
+        self.seed_slots_padded += arr.size - n_real
+        if trace is not None:
+            trace.lap("stage")
+            trace.attrs.update(
+                rows=arr.shape[0], length=arr.shape[1], seeds_real=n_real
+            )
 
     def _stage_seeds(
         self, bundle: RuleBundle, seed_sets: list[list[str]],
-        rows: int, length: int,
+        rows: int, length: int, trace=None,
     ) -> tuple[jax.Array, np.ndarray]:
         """Fill the padded (rows, length) seed-index array and transfer it
         → (device seed array, per-row any-known-seed mask, host). Reuses
@@ -1703,7 +1723,9 @@ class RecommendEngine:
         buffer can be refilled by the next dispatch. The transfer targets
         the bundle's own device, so a replica's dispatch runs on the
         replica's chip — the staging buffer is shared across replicas
-        (fill + transfer are serialized under the lock either way)."""
+        (fill + transfer are serialized under the lock either way).
+        ``trace`` is the batch's trace (None = untraced): its ``stage``
+        span ends where the transfer has been issued."""
         shape = (rows, length)
         with self._staging_lock:
             if _staging_is_safe():
@@ -1717,7 +1739,9 @@ class RecommendEngine:
                 arr.fill(-1)
             else:
                 arr = np.full(shape, -1, dtype=np.int32)
-            known_rows = self._fill_seed_rows(bundle, seed_sets, arr, length)
+            known_rows, n_real = self._fill_seed_rows(
+                bundle, seed_sets, arr, length
+            )
             if bundle.n_shards > 1 and bundle.shard_size > 0:
                 # per-shard dispatch accounting: which vocab shard's rows
                 # this batch's seed ids actually hit (host integer math on
@@ -1730,6 +1754,7 @@ class RecommendEngine:
             seeds_dev = jax.device_put(
                 arr, bundle.seed_sharding or bundle.device
             )
+            self._note_staged(arr, n_real, trace)
         if shape not in bundle.warmed_shapes:
             # a compile is landing on the serving path — count it loudly
             self.unwarmed_dispatches += 1
@@ -1892,7 +1917,7 @@ class RecommendEngine:
 
     def recommend_many_async(
         self, seed_sets: list[list[str]], replica: int | None = None,
-        deadline: float | None = None,
+        deadline: float | None = None, trace=None,
     ):
         """Batched lookup split into DISPATCH (device call enqueued, returns
         immediately — jax dispatch is asynchronous) and FINISH (a zero-arg
@@ -1915,7 +1940,18 @@ class RecommendEngine:
         frame's remaining-budget field — a gang peer sheds work that
         expired in transit instead of computing it (ISSUE 18). The
         local device paths ignore it (their budget is enforced at the
-        app layer, as before)."""
+        app layer, as before).
+
+        ``trace`` is the batch's own trace (``SpanRecorder.begin_batch``;
+        None = untraced, and then each site below is one is-None check).
+        All four variants — fallback, native, device, mesh — record the
+        same spans on it with ``TraceContext.lap``, each where the work
+        happens: ``stage`` (:meth:`_note_staged`) and ``dispatch`` here,
+        ``fetch_rules``, ``fetch_embed`` and ``compose`` in ``finish()``.
+        What lies between ``dispatch`` and ``finish()`` starting is the
+        batcher's hop to its completion thread, and belongs to no span."""
+        if trace is not None:
+            trace.skip()  # the engine's part of the batch starts here
         replicas = self.replicas
         idx = 0
         if replica is not None and replicas:
@@ -1926,15 +1962,20 @@ class RecommendEngine:
             threading.Thread(target=self.reload_if_required, daemon=True).start()
 
             def finish_fallback() -> list[tuple[list[str], str]]:
-                return [
+                if trace is not None:
+                    trace.skip()
+                out = [
                     (self.static_recommendation(s), "fallback")
                     for s in seed_sets
                 ]
+                if trace is not None:
+                    trace.lap("compose")
+                return out
 
             return finish_fallback
         if bundle.layout == "mesh":
             return self._mesh_recommend_async(
-                bundle, seed_sets, idx, deadline=deadline
+                bundle, seed_sets, idx, deadline=deadline, trace=trace
             )
         if bundle.host_rule_ids is not None:
             # native host kernel: no compile, so no shape bucketing — the
@@ -1946,7 +1987,10 @@ class RecommendEngine:
                 self.cfg.max_seed_tracks,
             )
             arr = np.full((len(seed_sets), length), -1, dtype=np.int32)
-            known_rows = self._fill_seed_rows(bundle, seed_sets, arr, length)
+            known_rows, n_real = self._fill_seed_rows(
+                bundle, seed_sets, arr, length
+            )
+            self._note_staged(arr, n_real, trace)
             # the embedding kernel IS jitted even next to the native rule
             # kernel, so ITS seed array rides the warmed bucket grid
             emb = self._dispatch_embed(
@@ -1957,12 +2001,16 @@ class RecommendEngine:
                 ),
             )
             self._note_dispatch(idx)
+            if trace is not None:
+                trace.lap("dispatch")
 
             cm = self.cost_model
 
             def finish_native() -> list[tuple[list[str], str]]:
                 from . import native_serve
 
+                if trace is not None:
+                    trace.skip()
                 # chaos hook ON the completion path — where a real kernel
                 # failure or stall surfaces (delay faults sleep here, fail
                 # faults raise into the batcher's circuit breaker)
@@ -1983,6 +2031,9 @@ class RecommendEngine:
                         k_max=bundle.host_rule_ids.shape[1],
                         v=len(bundle.vocab), k_best=self.cfg.k_best_tracks,
                     )
+                if trace is not None:
+                    # the rule lookup itself here: the host kernel's call
+                    trace.lap("fetch_rules")
                 emb_host = None
                 if emb is not None:
                     # the embed kernel ran on the DEVICE while the native
@@ -2005,6 +2056,8 @@ class RecommendEngine:
                             r=int(bundle.emb_factors.shape[1]),
                             k_best=self.cfg.k_best_tracks,
                         )
+                    if trace is not None:
+                        trace.lap("fetch_embed")
                 out: list[tuple[list[str], str]] = []
                 for r, seeds in enumerate(seed_sets):
                     emb_row = None if emb_host is None else (
@@ -2014,6 +2067,8 @@ class RecommendEngine:
                         bundle, seeds, bool(known_rows[r]),
                         host_ids[r], host_confs[r], emb_row,
                     ))
+                if trace is not None:
+                    trace.lap("compose")
                 return out
 
             return finish_native
@@ -2028,7 +2083,7 @@ class RecommendEngine:
         # scatter/top-k. Every bucket is pre-warmed at bundle publish.
         n_rows = self._bucket_batch(max(len(seed_sets), 1))
         seeds_dev, known_rows = self._stage_seeds(
-            bundle, seed_sets, n_rows, length
+            bundle, seed_sets, n_rows, length, trace
         )
         # sharded layout dispatches the vocab-sharded lookup (per-shard
         # gather/top-k + cross-device max-merge) resolved at publication;
@@ -2043,19 +2098,25 @@ class RecommendEngine:
         # consumed together in finish()
         emb = self._dispatch_embed(bundle, seed_sets, n_rows, length)
         self._note_dispatch(idx)
+        if trace is not None:
+            trace.lap("dispatch")
 
         def finish() -> list[tuple[list[str], str]]:
+            if trace is not None:
+                trace.skip()
             # chaos hook on the completion path (see finish_native)
             faults.fire("replica.kernel", replica=idx)
             host_ids = np.asarray(top_ids)  # blocks on the device transfer
             host_confs = np.asarray(top_confs)
+            if trace is not None:
+                trace.lap("fetch_rules")
             if cm is not None:
                 # fenced per-kernel attribution (ISSUE 12): the host
                 # conversion above IS the fence for the rule kernel (the
                 # device executes in order, so the embed kernel hasn't
                 # started billing yet); dispatch→fence is the same
                 # upper-bound-on-device-time semantics as the batcher's
-                # device span, so the derived MFU is a lower bound
+                # kmls_device_ms interval, so the derived MFU is a lower bound
                 t_rules = time.perf_counter()
                 dims = dict(
                     b=n_rows, l=length, k_max=bundle.rule_ids.shape[1],
@@ -2084,6 +2145,8 @@ class RecommendEngine:
                         r=int(bundle.emb_factors.shape[1]),
                         k_best=self.cfg.k_best_tracks,
                     )
+                if trace is not None:
+                    trace.lap("fetch_embed")
             out: list[tuple[list[str], str]] = []
             for r, seeds in enumerate(seed_sets):
                 emb_row = None if emb_host is None else (
@@ -2093,13 +2156,15 @@ class RecommendEngine:
                     bundle, seeds, bool(known_rows[r]),
                     host_ids[r], host_confs[r], emb_row,
                 ))
+            if trace is not None:
+                trace.lap("compose")
             return out
 
         return finish
 
     def _mesh_recommend_async(
         self, bundle: RuleBundle, seed_sets: list[list[str]], idx: int,
-        deadline: float | None = None,
+        deadline: float | None = None, trace=None,
     ):
         """The pod-spanning dispatch/finish pair: fan the staged batch to
         every gang peer FIRST (socket I/O overlaps the local device
@@ -2122,7 +2187,10 @@ class RecommendEngine:
         # must survive into the peer fan-out — fetch_partials snapshots
         # it before the pool threads serialize it to sockets
         arr = np.full(shape, -1, dtype=np.int32)
-        known_rows = self._fill_seed_rows(bundle, seed_sets, arr, length)
+        known_rows, n_real = self._fill_seed_rows(
+            bundle, seed_sets, arr, length
+        )
+        self._note_staged(arr, n_real, trace)
         if bundle.shard_size > 0:
             hit = arr[arr >= 0]
             if hit.size:
@@ -2156,8 +2224,13 @@ class RecommendEngine:
         )
         emb = self._dispatch_embed(bundle, seed_sets, n_rows, length)
         self._note_dispatch(idx)
+        if trace is not None:
+            # the peer fan-out, the transfer and both device calls
+            trace.lap("dispatch")
 
         def finish() -> list[tuple[list[str], str]]:
+            if trace is not None:
+                trace.skip()
             # chaos hook on the completion path (see finish_native)
             faults.fire("replica.kernel", replica=idx)
             local_ids = np.asarray(part_ids)  # blocks on the device
@@ -2197,9 +2270,14 @@ class RecommendEngine:
                     v=len(bundle.vocab), k_best=kb,
                     shards=bundle.n_shards,
                 )
+            if trace is not None:
+                # this rank's partial, the slowest peer's, and the merge
+                trace.lap("fetch_rules")
             emb_host = None
             if emb is not None:
                 emb_host = (np.asarray(emb[0]), np.asarray(emb[1]), emb[2])
+                if trace is not None:
+                    trace.lap("fetch_embed")
             out: list[tuple[list[str], str]] = []
             for r, seeds in enumerate(seed_sets):
                 emb_row = None if emb_host is None else (
@@ -2220,6 +2298,8 @@ class RecommendEngine:
                     (songs, "degraded:mesh-straggler") for songs, _src in out
                 ]
             finish._kmls_hedge = getattr(finish_remote, "hedge_outcome", None)
+            if trace is not None:
+                trace.lap("compose")
             return out
 
         return finish

@@ -79,6 +79,16 @@ METRIC_REGISTRY: dict[str, str] = {
     "kmls_fleet_peers": "gauge:serving",
     # --- serving: dispatch / layout ---
     "kmls_device_dispatch_total": "counter:serving",
+    # what a dispatch carried (ISSUE 26), counted where the work
+    # happens: requests per dispatched batch (buckets 1..32); seed slots
+    # staged for the rule lookup by kind (real = a known seed's id,
+    # padded = the -1 fill up to the shape bucket; the real series is
+    # also the per-batch sum of seed lengths a roofline join needs); and
+    # dispatches whose shape was never warmed (a compile on the serving
+    # path — must stay 0)
+    "kmls_batch_size": "histogram:serving",
+    "kmls_seed_slots_total": "counter:serving",
+    "kmls_unwarmed_dispatches_total": "counter:serving",
     "kmls_shard_dispatch_total": "counter:serving",
     "kmls_model_shards": "gauge:serving",
     # pod-spanning serve mesh (ISSUE 16): gang shard health by state
@@ -305,6 +315,9 @@ LATENCY_BUCKETS_S = (
     0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
     0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
 )
+# requests per dispatched batch: the engine's batch buckets up to the
+# default batch_max_size (a larger cap lands in +Inf)
+BATCH_SIZE_BUCKETS = (1, 2, 4, 8, 16, 32)
 
 
 class LatencyHistogram:
@@ -396,8 +409,12 @@ class ServingMetrics:
         self.redispatch_total = 0
         self.latency = LatencyReservoir()
         # per-request latency attribution from the micro-batcher:
-        # queue_wait = enqueue→dispatch, device = dispatch→result-on-host
-        # (device compute + transfer + in-order queue), e2e = enqueue→done
+        # queue_wait = enqueue→batch formed; "device" = batch formed →
+        # batch resolved on the HOST's clock, so it holds staging,
+        # dispatch, the device's work, both fetches and the compose of
+        # every member (the span tree's `batch`; the name is kept for the
+        # dashboards and the admission ladder that read it); e2e =
+        # enqueue→done
         self.queue_wait = LatencyReservoir()
         self.device = LatencyReservoir()
         self.e2e = LatencyReservoir()
@@ -406,6 +423,9 @@ class ServingMetrics:
         self.queue_wait_hist = LatencyHistogram()
         self.device_hist = LatencyHistogram()
         self.e2e_hist = LatencyHistogram()
+        # requests per dispatched batch (the same fixed-bucket counter
+        # histogram, over a count instead of seconds)
+        self.batch_size_hist = LatencyHistogram(BATCH_SIZE_BUCKETS)
         self._lock = threading.Lock()
 
     def record(self, source: str, seconds: float) -> None:
@@ -441,6 +461,11 @@ class ServingMetrics:
     def record_redispatch(self, n: int = 1) -> None:
         with self._lock:
             self.redispatch_total += n
+
+    def record_batch_size(self, n_requests: int) -> None:
+        """One dispatched batch of ``n_requests`` (the batchers call it
+        where the engine call returned)."""
+        self.batch_size_hist.observe(n_requests)
 
     def record_attribution(
         self, queue_wait_s: float, device_s: float, e2e_s: float
@@ -481,7 +506,7 @@ class ServingMetrics:
         self, reload_counter: int, finished_loading: bool,
         cache=None, dispatch_counts=None, robustness=None,
         shard_counts=None, cost=None, slo=None, artifact_ages=None,
-        artifact_stale=None, mesh_shards=None, io=None,
+        artifact_stale=None, mesh_shards=None, io=None, seed_slots=None,
     ) -> str:
         """Prometheus text. ``cache`` (a serving.cache.RecommendCache),
         ``dispatch_counts`` (the engine's per-replica dispatch counters),
@@ -493,8 +518,9 @@ class ServingMetrics:
         .CostModel — per-kernel MFU/roofline + memory/compile
         telemetry), ``slo`` (an observability.slo.SloTracker) and
         ``artifact_ages`` (artifact name → seconds since publication)
-        are optional — deployments without them render exactly the old
-        exposition."""
+        and ``seed_slots`` (the engine's ``(real, padded)`` staged-slot
+        counters) are optional — deployments without them render exactly
+        the old exposition."""
         p50, p95, p99 = self.latency.percentiles(0.50, 0.95, 0.99)
         uptime = time.time() - self.started_at
         lines = [
@@ -517,6 +543,10 @@ class ServingMetrics:
         # batcher attribution summaries, milliseconds (absent→all-zero is
         # fine: an unbatched deployment simply never observes into them)
         lines += self._summary_ms("kmls_queue_wait_ms", self.queue_wait)
+        lines.append(
+            "# HELP kmls_device_ms batch formed to batch resolved, host "
+            "clock: staging, dispatch, device, fetch, compose"
+        )
         lines += self._summary_ms("kmls_device_ms", self.device)
         lines += self._summary_ms("kmls_e2e_ms", self.e2e)
         # the same attributions as fixed-bucket histograms (seconds):
@@ -524,8 +554,13 @@ class ServingMetrics:
         # histogram_quantile works where per-pod reservoir quantiles
         # cannot aggregate (ISSUE 9)
         lines += self.queue_wait_hist.render("kmls_queue_wait_seconds")
+        lines.append(
+            "# HELP kmls_device_seconds batch formed to batch resolved, "
+            "host clock: staging, dispatch, device, fetch, compose"
+        )
         lines += self.device_hist.render("kmls_device_seconds")
         lines += self.e2e_hist.render("kmls_e2e_seconds")
+        lines += self.batch_size_hist.render("kmls_batch_size")
         if cache is not None:
             # epoch-keyed recommendation cache: hit/miss/evict counters +
             # the hit-ratio gauge the 10k-QPS claim is judged on
@@ -559,6 +594,14 @@ class ServingMetrics:
             lines += [
                 f'kmls_device_dispatch_total{{device="{i}"}} {count}'
                 for i, count in enumerate(dispatch_counts)
+            ]
+        if seed_slots is not None:
+            # what the staged seed arrays held: padded / (real + padded)
+            # is the share of the lookup's slots that were padding
+            lines += [
+                "# TYPE kmls_seed_slots_total counter",
+                f'kmls_seed_slots_total{{kind="real"}} {seed_slots[0]}',
+                f'kmls_seed_slots_total{{kind="padded"}} {seed_slots[1]}',
             ]
         if shard_counts:
             # sharded model layout: seed ids dispatched per vocab shard —

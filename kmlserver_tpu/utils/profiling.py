@@ -54,25 +54,112 @@ def trace_session(label: str) -> Iterator[None]:
         yield
 
 
-def start_capture(label: str, seconds: float) -> "object":
+def start_capture(label: str, seconds: float, recorder=None) -> "object":
     """Timed on-demand capture (ISSUE 12, the ``/debug/profile``
     endpoint): run :func:`trace_session` for ``seconds`` on a daemon
     thread → the thread (join it to wait; the endpoint doesn't). The
     trace covers whatever the process executes while the window is open
     — for a live server, the serving kernels under real traffic. A no-op
     thread when profiling is disabled (the caller gates on
-    :func:`profile_dir`, this is belt-and-braces)."""
+    :func:`profile_dir`, this is belt-and-braces).
+
+    With ``recorder`` (the app's ``observability.trace.SpanRecorder``)
+    the capture also puts the host's spans on the device trace's clock
+    (ISSUE 26). The recorder is in capture mode from before the session
+    starts until after it stops, so every batch whose programs the
+    session sees has a trace; inside the session the thread emits a
+    clock anchor right after the start, once a second, and right before
+    the stop; and the spans land beside the ``.xplane.pb`` as
+    ``kmls_spans.jsonl``. Two log lines bracket it: ``profile capture
+    open: dir=<session directory>`` before anything starts, ``profile
+    capture closed: dir=<the span file's directory> ...`` once the file
+    is written (a reader that looked too early for the second finds the
+    file under the first's directory)."""
+    import logging
     import threading
 
     def run() -> None:
-        with trace_session(label):
-            time.sleep(max(seconds, 0.0))
+        if recorder is None or profile_dir() is None:
+            with trace_session(label):
+                time.sleep(max(seconds, 0.0))
+            return
+        from ..observability import trace as spantrace
+
+        logging.getLogger("kmlserver_tpu.serving").info(
+            "profile capture open: dir=%s seconds=%g",
+            os.path.join(profile_dir() or "", label), seconds,
+        )
+        anchors: list[tuple[int, int]] = []
+        recorder.capture_begin()
+        try:
+            with trace_session(label):
+                deadline = time.monotonic() + max(seconds, 0.0)
+                anchors.append(spantrace.emit_clock_anchor())
+                while True:
+                    left = deadline - time.monotonic()
+                    if left <= 0:
+                        break
+                    time.sleep(min(1.0, left))
+                    anchors.append(spantrace.emit_clock_anchor())
+        finally:
+            traces = recorder.capture_end()
+        _write_capture_spans(label, seconds, anchors, traces)
 
     thread = threading.Thread(
         target=run, daemon=True, name="kmls-profile-capture"
     )
     thread.start()
     return thread
+
+
+def _write_capture_spans(
+    label: str, seconds: float, anchors: list, traces: list[dict]
+) -> None:
+    """``kmls_spans.jsonl`` beside the capture's ``.xplane.pb`` (the
+    session's own directory where the profiler wrote none): a header
+    line with the anchors' ``perf_counter_ns`` pairs, then one line per
+    request trace and per batch trace, spans with absolute
+    ``perf_counter`` nanoseconds. Then the one log line a reader finds
+    the capture by."""
+    import glob
+    import json
+    import logging
+
+    from ..io.artifacts import atomic_write_text
+    from ..observability.trace import SPANS_FILENAME
+
+    session_dir = os.path.join(profile_dir() or "", label)
+    dumps = glob.glob(
+        os.path.join(session_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    target = (
+        os.path.dirname(max(dumps, key=os.path.getmtime))
+        if dumps else session_dir
+    )
+    requests = sum(1 for t in traces if t["kind"] == "request")
+    spans = sum(len(t["spans"]) for t in traces)
+    header = {
+        "kind": "header", "version": 1, "label": label,
+        "seconds": seconds, "clock": "perf_counter_ns",
+        "anchors": [list(pair) for pair in anchors],
+        "requests": requests, "batches": len(traces) - requests,
+        "spans": spans,
+    }
+    lines = [json.dumps(header)] + [json.dumps(t) for t in traces]
+    logger = logging.getLogger("kmlserver_tpu.serving")
+    try:
+        atomic_write_text(
+            os.path.join(target, SPANS_FILENAME), "\n".join(lines) + "\n",
+            durable=False,
+        )
+    except OSError:
+        # the profiler's own dump stands; only the host's spans are lost
+        logger.exception("profile capture: %s not written", SPANS_FILENAME)
+        return
+    logger.info(
+        "profile capture closed: dir=%s requests=%d batches=%d spans=%d",
+        target, requests, len(traces) - requests, spans,
+    )
 
 
 class PhaseTimer:

@@ -10,7 +10,7 @@ is the client half: one JSONL record per echoed id with send/recv wall
 clocks. This tool is the consumer both sides were waiting for — it joins
 the two halves on the trace id into ONE per-request timeline:
 
-    client_send ──▶ [server: queue span, device span, ...] ──▶ client_recv
+    client_send ──▶ [server: queue span, batch span, ...] ──▶ client_recv
 
 and derives the number neither side can compute alone:
 ``client_overhead_ms = client RTT − server-observed duration`` — the

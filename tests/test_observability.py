@@ -429,8 +429,10 @@ class TestSpanRecorder:
         rec = SpanRecorder(sample=1.0, rng=random.Random(5))
         t = self._ctx(rec, "rt-1")
         t0 = t.t0
-        t.span("queue", t0, t0 + 0.002, {"batch": 3})
-        t.span("device", t0 + 0.002, t0 + 0.004, {"replica": 0})
+        queue_id = t.span("queue", t0, t0 + 0.002, {"batch": 3})
+        batch_id = t.span(
+            "batch", t0 + 0.002, t0 + 0.004, {"replica": 0, "batch_id": 7}
+        )
         t.annotate("admission", "degrade")
         rec.finish(t, "degraded", 0.005)
         (trace,) = rec.debug_payload()["traces"]
@@ -438,9 +440,19 @@ class TestSpanRecorder:
         assert trace["trace_id"] == "rt-1"
         assert trace["status"] == "degraded"
         assert trace["attrs"]["admission"] == "degrade"
-        assert [s["name"] for s in trace["spans"]] == ["queue", "device"]
-        assert trace["spans"][0]["attrs"] == {"batch": 3}
-        assert trace["spans"][0]["duration_ms"] == pytest.approx(2.0, abs=0.1)
+        # the root comes first and is the trace itself; ids are small
+        # integers local to the trace, and every other span names a parent
+        assert [s["name"] for s in trace["spans"]] == [
+            "request", "queue", "batch",
+        ]
+        root, queue, batch = trace["spans"]
+        assert (root["id"], root["parent"]) == (0, None)
+        assert root["duration_ms"] == trace["duration_ms"] == 5.0
+        assert (queue["id"], queue["parent"]) == (queue_id, 0)
+        assert (batch["id"], batch["parent"]) == (batch_id, 0)
+        assert len({root["id"], queue_id, batch_id}) == 3
+        assert queue["attrs"] == {"batch": 3}
+        assert queue["duration_ms"] == pytest.approx(2.0, abs=0.1)
 
 
 class TestZeroCostWhenDisabled:
@@ -458,6 +470,10 @@ class TestZeroCostWhenDisabled:
             assert status == 200
             assert "X-KMLS-Trace" not in headers
         assert app.recorder.began == 0
+        # the batch trace's counter too: the batcher had the recorder
+        # and dispatched three batches without opening one
+        assert app.batcher.recorder is app.recorder
+        assert app.recorder.batches_began == 0
         assert app.recorder.retained_total == 0
         status, _, payload = app.handle("GET", "/metrics", None)
         text = payload.decode()
@@ -479,9 +495,15 @@ def _assert_traced_breakdown(doc: dict, trace_id: str, parent_id=None):
     assert trace["parent_id"] == parent_id
     assert trace["status"] == "ok"
     names = [s["name"] for s in trace["spans"]]
-    for required in ("queue", "device", "compose"):
+    assert names[0] == "request"
+    for required in ("parse", "admit", "queue", "batch", "respond"):
         assert required in names, names
-    span_sum = sum(s["duration_ms"] for s in trace["spans"])
+    assert "device" not in names and "compose" not in names
+    # the root's children tile the request (the batch's own inside is
+    # the batch trace's, looked up by batch_id, not copied in here)
+    span_sum = sum(
+        s["duration_ms"] for s in trace["spans"] if s["parent"] == 0
+    )
     e2e = trace["duration_ms"]
     # spans must fit inside the request and account for most of it; the
     # uncovered remainder is validation + completion handoff (bounded
@@ -526,11 +548,22 @@ class TestTracePropagationThreaded:
             trace = _assert_traced_breakdown(
                 doc, "threaded-cli-1", parent_id="bench-run-7"
             )
-            # batcher path annotated its dispatch
-            device = next(
-                s for s in trace["spans"] if s["name"] == "device"
+            # batcher path annotated its dispatch, and names the batch's
+            # own trace, which holds the engine's spans
+            batch = next(
+                s for s in trace["spans"] if s["name"] == "batch"
             )
-            assert "replica" in device["attrs"]
+            assert "replica" in batch["attrs"]
+            by_batch = {
+                b["attrs"]["batch_id"]: b for b in doc["batches"]
+            }
+            inside = [
+                s["name"]
+                for s in by_batch[batch["attrs"]["batch_id"]]["spans"]
+            ]
+            assert inside[0] == "batch"
+            for required in ("stage", "fetch_rules", "compose", "resolve"):
+                assert required in inside, inside
         finally:
             server.shutdown()
 
@@ -547,7 +580,8 @@ class TestTracePropagationThreaded:
         hit = by_id["hit-1"]
         assert hit["attrs"].get("cached") is True
         names = [s["name"] for s in hit["spans"]]
-        assert "device" not in names and "compose" in names
+        assert "batch" not in names and "queue" not in names
+        assert "cache" in names and "respond" in names
 
 
 class TestTracePropagationAsync:
@@ -1651,3 +1685,550 @@ class TestJobPhaseCostTelemetry:
         for line in prom.splitlines():
             if line.startswith('kmls_job_phase_flops{phase="mine"}'):
                 assert float(line.rsplit(" ", 1)[1]) > 0
+
+
+# ---------------------------------------------------------------------------
+# span tree, batch traces, capture mode, dispatch counters (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+REQUEST_SPANS = {
+    "request", "parse", "cache", "admit", "queue", "batch", "respond",
+}
+# what the engine's native host variant records (the CPU default); the
+# jitted variant records the same names (pinned below)
+BATCH_SPANS = {
+    "batch", "stage", "dispatch", "fetch_rules", "compose", "resolve",
+}
+
+
+def _serve_async(app) -> int:
+    """Run ``app`` behind the asyncio transport on a daemon thread → the
+    bound port."""
+    import asyncio
+
+    from kmlserver_tpu.serving.aioserver import run_async
+
+    port_box: list[int] = []
+    ready = threading.Event()
+
+    def runner():
+        asyncio.run(run_async(
+            app, 0, ready=lambda p: (port_box.append(p), ready.set()),
+        ))
+
+    threading.Thread(target=runner, daemon=True).start()
+    assert ready.wait(timeout=30)
+    return port_box[0]
+
+
+def _http_post(port: int, songs, trace_id=None):
+    import http.client
+
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=15)
+    headers = {"Content-Type": "application/json"}
+    if trace_id:
+        headers["X-KMLS-Trace"] = trace_id
+    try:
+        conn.request(
+            "POST", "/api/recommend/",
+            body=json.dumps({"songs": songs}).encode(), headers=headers,
+        )
+        resp = conn.getresponse()
+        resp.read()
+        return resp.status, dict(resp.getheaders())
+    finally:
+        conn.close()
+
+
+def _assert_tree(trace: dict) -> None:
+    """Ids unique, one root first, every other span names a parent that
+    exists, and every span's interval lies inside its parent's."""
+    json.loads(json.dumps(trace))
+    spans = trace["spans"]
+    by_id = {s["id"]: s for s in spans}
+    assert len(by_id) == len(spans)
+    root = spans[0]
+    assert (root["id"], root["parent"]) == (0, None)
+    assert root["name"] == trace["kind"]
+    assert root["duration_ms"] == trace["duration_ms"]
+    slack = 2e-4  # start_ms / duration_ms are each rounded to 1e-4 ms
+    for span in spans[1:]:
+        parent = by_id[span["parent"]]
+        assert span["start_ms"] >= parent["start_ms"] - slack, span
+        assert (
+            span["start_ms"] + span["duration_ms"]
+            <= parent["start_ms"] + parent["duration_ms"] + slack
+        ), (span, parent)
+
+
+class _GatedEngine:
+    """A fake engine whose finish() waits for a gate, so a test decides
+    when the pipeline frees; takes the batch trace like the real one."""
+
+    cache_value = "fake-model-date"
+
+    def __init__(self):
+        self.gate = threading.Event()
+        self.batches: list[int] = []
+
+    def recommend_many_async(self, seed_sets, trace=None):
+        self.batches.append(len(seed_sets))
+        if trace is not None:
+            trace.skip()
+            trace.lap("stage")
+            trace.lap("dispatch")
+
+        def finish():
+            if trace is not None:
+                trace.skip()
+            assert self.gate.wait(timeout=10)
+            if trace is not None:
+                trace.lap("fetch_rules")
+                trace.lap("compose")
+            return [([f"rec-{s[0]}"], "rules") for s in seed_sets]
+
+        return finish
+
+    def static_recommendation(self, songs, deadline=None):
+        return ["popular-1"]
+
+
+class TestSpanTree:
+    def test_lap_and_skip_tile_without_overlap(self):
+        rec = SpanRecorder(sample=1.0, rng=random.Random(11))
+        t0 = time.perf_counter()
+        bt = rec.begin_batch(t0, requests=2, replica=0)
+        assert bt.kind == "batch" and bt.attrs["batch_id"] == 1
+        bt.skip()
+        first = bt.lap("stage")
+        second = bt.lap("dispatch")
+        time.sleep(0.002)
+        bt.skip()  # the hop between dispatch and finish is nobody's
+        third = bt.lap("compose")
+        rec.finish_batch(bt)
+        assert [first, second, third] == [1, 2, 3]
+        (doc,) = rec.debug_payload()["batches"]
+        _assert_tree(doc)
+        stage, dispatch, compose = doc["spans"][1:]
+        assert dispatch["start_ms"] == pytest.approx(
+            stage["start_ms"] + stage["duration_ms"], abs=2e-4
+        )
+        gap = compose["start_ms"] - (
+            dispatch["start_ms"] + dispatch["duration_ms"]
+        )
+        assert gap >= 1.9  # the skipped 2 ms
+        assert rec.begin_batch(t0).attrs["batch_id"] == 2
+
+    @pytest.mark.parametrize("transport", ["threaded", "async"])
+    def test_both_batchers_record_the_same_span_names(
+        self, mined_pvc, transport
+    ):
+        """Satellite: the two batchers share one span helper, so the same
+        traffic yields the same names; only ``write`` is the asyncio
+        transport's own. Ids and parents round-trip to JSON and every
+        span lies inside its parent."""
+        cfg, _, _ = mined_pvc
+        cfg = dataclasses.replace(cfg, trace_sample=1.0)
+        seeds = _rule_seeds(cfg)[:2]
+        if transport == "threaded":
+            app = RecommendApp(cfg)
+            assert app.engine.load()
+            status, headers, _ = _post(app, seeds, trace_header="same-1")
+            assert status == 200 and headers["X-KMLS-Trace"] == "same-1"
+            want = REQUEST_SPANS
+        else:
+            app = RecommendApp(cfg, defer_batcher=True)
+            assert app.engine.load()
+            status, headers = _http_post(
+                _serve_async(app), seeds, trace_id="same-1"
+            )
+            assert status == 200 and headers["X-KMLS-Trace"] == "same-1"
+            want = REQUEST_SPANS | {"write"}
+        deadline = time.time() + 5
+        while time.time() < deadline:  # the write closes the trace
+            doc = app.recorder.debug_payload()
+            if doc["traces"]:
+                break
+            time.sleep(0.01)
+        (trace,) = doc["traces"]
+        assert {s["name"] for s in trace["spans"]} == want
+        _assert_tree(trace)
+        (batch,) = doc["batches"]
+        assert {s["name"] for s in batch["spans"]} == BATCH_SPANS
+        _assert_tree(batch)
+        link = next(s for s in trace["spans"] if s["name"] == "batch")
+        assert link["attrs"]["batch_id"] == batch["attrs"]["batch_id"]
+        assert batch["attrs"]["requests"] == 1
+        assert batch["attrs"]["seeds_real"] == len(seeds)
+        queue = next(s for s in trace["spans"] if s["name"] == "queue")
+        assert queue["attrs"]["slot_wait_ms"] == 0.0
+        admit = next(s for s in trace["spans"] if s["name"] == "admit")
+        assert admit["attrs"]["decision"] == "admit"
+
+    def test_jitted_variant_records_fetch_spans_where_it_blocks(
+        self, mined_pvc
+    ):
+        cfg, _, _ = mined_pvc
+        engine_cfg = dataclasses.replace(cfg, native_serve=False)
+        from kmlserver_tpu.serving.engine import RecommendEngine
+
+        engine = RecommendEngine(engine_cfg)
+        assert engine.load()
+        rec = SpanRecorder(sample=1.0, rng=random.Random(12))
+        bt = rec.begin_batch(time.perf_counter(), requests=3, replica=0)
+        seeds = _rule_seeds(cfg)
+        results = engine.recommend_many_async(
+            [seeds[:1], seeds[:2], seeds[:3]], trace=bt
+        )()
+        assert len(results) == 3
+        rec.finish_batch(bt)
+        (doc,) = rec.debug_payload()["batches"]
+        assert [s["name"] for s in doc["spans"]] == [
+            "batch", "stage", "dispatch", "fetch_rules", "compose",
+        ]
+        _assert_tree(doc)
+        # three requests of 1, 2 and 3 seeds land in the (4, 8) bucket
+        assert doc["attrs"]["rows"] == 4 and doc["attrs"]["length"] == 8
+        assert doc["attrs"]["seeds_real"] == 6
+
+    def test_three_requests_one_batch_trace_three_batch_spans(self):
+        """Satellite: requests that share a dispatch share one batch
+        trace, named by each member's ``batch`` span; the wait for a
+        pipeline slot shows as ``slot_wait_ms``."""
+        import asyncio
+
+        rec = SpanRecorder(sample=1.0, rng=random.Random(13))
+        engine = _GatedEngine()
+        metrics = ServingMetrics()
+
+        async def scenario():
+            batcher = AsyncMicroBatcher(
+                engine, max_size=3, window_ms=1.0, max_inflight=1,
+                metrics=metrics, recorder=rec,
+            )
+            loop = asyncio.get_running_loop()
+            first = rec.begin("lead")
+            lead = batcher.submit(["lead"], trace=first)  # fills the pipe
+            traces = [rec.begin(f"m{i}") for i in range(3)]
+            futures = [
+                batcher.submit([f"m{i}"], trace=t)
+                for i, t in enumerate(traces)
+            ]
+            await asyncio.sleep(0.03)  # the three wait for the slot
+            assert engine.batches == [1]
+            loop.call_later(0.0, engine.gate.set)
+            await asyncio.gather(lead, *futures)
+            return [first] + traces
+
+        traces = asyncio.run(scenario())
+        assert engine.batches == [1, 3]
+        for t in traces:
+            rec.finish(t, "ok", time.perf_counter() - t.t0)
+        doc = rec.debug_payload()
+        assert doc["batches_began"] == 2 and len(doc["batches"]) == 2
+        shared = doc["batches"][1]
+        assert shared["attrs"]["requests"] == 3
+        assert {s["name"] for s in shared["spans"]} == BATCH_SPANS
+        members = [t for t in doc["traces"] if t["trace_id"] != "lead"]
+        assert len(members) == 3
+        for member in members:
+            link = next(s for s in member["spans"] if s["name"] == "batch")
+            assert link["attrs"]["batch_id"] == shared["attrs"]["batch_id"]
+            queue = next(s for s in member["spans"] if s["name"] == "queue")
+            assert queue["attrs"]["batch"] == 3
+            # nearly all of the ~30 ms queue was the wait for the slot
+            assert 20.0 < queue["attrs"]["slot_wait_ms"] <= (
+                queue["duration_ms"] + 1e-3
+            )
+        # the batch-size histogram saw the same two dispatches
+        counts, total, n = metrics.batch_size_hist.snapshot()
+        assert (n, total) == (2, 4.0)
+
+    def test_admit_span_carries_the_ladders_decision(self):
+        import asyncio
+
+        rec = SpanRecorder(sample=1.0, rng=random.Random(14))
+
+        async def scenario():
+            batcher = AsyncMicroBatcher(
+                _GatedEngine(), shed_queue_budget_ms=50.0, recorder=rec,
+            )
+            batcher._admission.decide = lambda projected: ("shed", 2.0)
+            trace = rec.begin("shed-1")
+            with pytest.raises(Overloaded):
+                batcher.submit(["s"], trace=trace)
+            return trace
+
+        trace = asyncio.run(scenario())
+        (admit,) = [s for s in trace.spans if s[2] == "admit"]
+        assert admit[5] == {"decision": "shed"}
+        assert rec.batches_began == 0  # nothing was dispatched
+
+    def test_a_failed_dispatch_closes_its_batch_trace_as_error(self):
+        import asyncio
+
+        class Broken(_GatedEngine):
+            def recommend_many_async(self, seed_sets, trace=None):
+                raise RuntimeError("dispatch refused")
+
+        rec = SpanRecorder(sample=1.0, rng=random.Random(15))
+
+        async def scenario():
+            batcher = AsyncMicroBatcher(Broken(), recorder=rec)
+            with pytest.raises(RuntimeError):
+                await batcher.submit(["s"], trace=rec.begin("x"))
+
+        asyncio.run(scenario())
+        (doc,) = rec.debug_payload()["batches"]
+        assert doc["status"] == "error"
+
+    def test_deferred_trace_ends_where_the_transport_says(self, mined_pvc):
+        """The asyncio transport owns a trace's end: no response builder
+        finishes it, and ``trace_written`` adds the ``write`` span and
+        closes the root over it."""
+        import asyncio
+
+        cfg, _, _ = mined_pvc
+        app = RecommendApp(
+            dataclasses.replace(cfg, trace_sample=1.0), defer_batcher=True
+        )
+        assert app.engine.load()
+        body = json.dumps({"songs": _rule_seeds(cfg)[:1]}).encode()
+
+        async def scenario():
+            app.batcher = AsyncMicroBatcher(
+                app.engine, metrics=app.metrics, recorder=app.recorder
+            )
+            t_received = time.perf_counter()
+            response, future, t0, trace = app.submit_recommend(
+                body, "defer-1", None, t_received
+            )
+            assert response is None and trace.deferred
+            await future
+            response = app.finish_recommend(future, t0, trace=trace)
+            assert response[0] == 200
+            assert response[1]["X-KMLS-Trace"] == "defer-1"
+            # built, stamped, not closed: the write has not happened
+            assert trace.status == "ok" and not trace.finished
+            assert app.recorder.retained() == 0
+            t_w = time.perf_counter()
+            app.trace_written(trace, t_w, t_w + 0.001)
+            return t_received, t_w
+
+        t_received, t_w = asyncio.run(scenario())
+        (doc,) = app.recorder.debug_payload()["traces"]
+        assert doc["spans"][-1]["name"] == "write"
+        assert doc["duration_ms"] == pytest.approx(
+            (t_w + 0.001 - t_received) * 1e3, abs=1e-3
+        )
+        _assert_tree(doc)
+
+
+class TestZeroCostAcrossTheNewSites:
+    def test_no_trace_object_at_any_new_site_async_transport(self, mined_pvc):
+        """Acceptance: with no capture open and KMLS_TRACE_SAMPLE=0 the
+        parse, admit, queue, batch, stage..resolve, respond and write
+        sites create nothing — neither a request trace nor a batch
+        trace — through the asyncio transport and the loop-native
+        batcher (the threaded pair is pinned above)."""
+        cfg, _, _ = mined_pvc
+        app = RecommendApp(cfg, defer_batcher=True)
+        assert app.engine.load()
+        assert not app.recorder.enabled and not app.recorder.active
+        port = _serve_async(app)
+        for i, seed in enumerate(_rule_seeds(cfg)[:3]):
+            status, headers = _http_post(port, [seed], trace_id=f"want-{i}")
+            assert status == 200
+            assert not any(k.lower() == "x-kmls-trace" for k in headers)
+        assert app.batcher.recorder is app.recorder
+        assert app.recorder.began == 0
+        assert app.recorder.batches_began == 0
+        assert app.recorder.retained_total == 0
+        # the always-on counters moved all the same
+        assert app.metrics.batch_size_hist.snapshot()[2] == 3
+        assert app.engine.seed_slots_real == 3
+
+
+class TestCaptureMode:
+    def test_capture_traces_everything_whatever_the_sample_says(self):
+        rec = SpanRecorder(sample=0.0)
+        assert not rec.active and rec.begin() is None
+        rec.capture_begin()
+        assert rec.active and not rec.enabled
+        t = rec.begin("cap-1")
+        t.span("queue", t.t0, t.t0 + 0.001)
+        assert rec.finish(t, "ok", 0.002) is False  # the ring stays shut
+        bt = rec.begin_batch(t.t0, requests=1, replica=0)
+        rec.finish_batch(bt)
+        traces = rec.capture_end()
+        assert not rec.active and rec.begin() is None
+        assert [d["kind"] for d in traces] == ["request", "batch"]
+        span = traces[0]["spans"][1]
+        # absolute perf_counter nanoseconds, the anchors' clock
+        assert span["t_start_ns"] == int(t.t0 * 1e9)
+        assert span["t_end_ns"] - span["t_start_ns"] == pytest.approx(
+            1e6, abs=2
+        )
+        assert rec.retained() == 0 and rec.debug_payload()["batches"] == []
+        assert rec.capture_end() == []  # closed: nothing accumulates
+
+    def test_profile_capture_switches_it_and_leaves_the_span_file(
+        self, mined_pvc, monkeypatch, tmp_path, caplog
+    ):
+        """Satellite: ``start_capture`` turns capture mode on and off,
+        and leaves ``kmls_spans.jsonl`` beside the ``.xplane.pb`` with a
+        header, the anchors and one line per trace (CPU backend)."""
+        import glob
+        import logging
+
+        from kmlserver_tpu.observability.trace import SPANS_FILENAME
+
+        cfg, _, _ = mined_pvc
+        target = tmp_path / "profiles"
+        target.mkdir()
+        monkeypatch.setenv("KMLS_PROFILE_DIR", str(target))
+        app = RecommendApp(cfg)  # KMLS_TRACE_SAMPLE=0
+        assert app.engine.load()
+        assert not app.recorder.active
+        caplog.set_level(logging.INFO, logger="kmlserver_tpu.serving")
+        status, _, payload = app.handle(
+            "GET", "/debug/profile?seconds=1.2", None
+        )
+        assert status == 202, payload
+        deadline = time.time() + 10
+        while not app.recorder.active and time.time() < deadline:
+            time.sleep(0.005)
+        assert app.recorder.active and not app.recorder.enabled
+        seeds = _rule_seeds(cfg)
+        for i in range(3):
+            status, headers, _ = _post(app, [seeds[i]], trace_header=f"c{i}")
+            assert status == 200 and headers["X-KMLS-Trace"] == f"c{i}"
+        app._profile_thread.join(timeout=60)
+        assert not app._profile_thread.is_alive()
+        assert not app.recorder.active
+        assert _post(app, [seeds[3]])[0] == 200
+        assert app.recorder.began == 3  # nothing after the capture closed
+        (xplane,) = glob.glob(str(
+            target / "*" / "plugins" / "profile" / "*" / "*.xplane.pb"
+        ))
+        path = os.path.join(os.path.dirname(xplane), SPANS_FILENAME)
+        with open(path) as fh:
+            lines = [json.loads(line) for line in fh]
+        header, traces = lines[0], lines[1:]
+        assert header["kind"] == "header" and header["version"] == 1
+        assert header["requests"] == 3 and header["batches"] == 3
+        assert header["spans"] == sum(len(t["spans"]) for t in traces)
+        # right after the start, once a second, right before the stop
+        assert len(header["anchors"]) >= 3
+        named = [a[0] for a in header["anchors"]]
+        assert named == sorted(named)
+        assert all(0 <= opened - ns < 1e9 for ns, opened in header["anchors"])
+        assert 1.2e9 <= named[-1] - named[0] < 10e9  # the capture's length
+        assert [t["kind"] for t in traces].count("request") == 3
+        for t in traces:
+            _assert_tree(t)
+            assert named[0] - 1e9 < t["spans"][0]["t_start_ns"] < named[-1] + 1e9
+        assert (
+            f"profile capture closed: dir={os.path.dirname(xplane)} "
+            "requests=3 batches=3" in caplog.text
+        )
+        session = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.dirname(xplane)
+        )))
+        assert f"profile capture open: dir={session} seconds=1.2" in caplog.text
+        # the anchors are in the capture's host plane, by name
+        from jax.profiler import ProfileData
+
+        found = [
+            e.name
+            for plane in ProfileData.from_file(xplane).planes
+            for line in plane.lines
+            for e in line.events
+            if e.name.startswith("kmls/clock:")
+        ]
+        assert sorted(found) == [f"kmls/clock:{ns}" for ns in named]
+
+
+class TestDispatchCounters:
+    def test_seed_slots_count_exactly_rows_times_length(self, mined_pvc):
+        """``kmls_seed_slots_total``: real + padded is the staged array's
+        size, dispatch by dispatch, on a hand-built batch (jitted path:
+        the shape is a bucket)."""
+        from kmlserver_tpu.serving.engine import RecommendEngine
+
+        cfg, _, _ = mined_pvc
+        engine = RecommendEngine(dataclasses.replace(cfg, native_serve=False))
+        assert engine.load()
+        seeds = _rule_seeds(cfg)
+        real0, padded0 = engine.seed_slots_real, engine.seed_slots_padded
+        # 3 requests -> 4 rows; longest 9 seeds (3 known) -> length 32
+        batch = [seeds[:1], seeds[:3] + ["nobody-knows-me"] * 6, seeds[:2]]
+        engine.recommend_many_async(batch)()
+        assert engine.seed_slots_real - real0 == 6
+        assert engine.seed_slots_padded - padded0 == 4 * 32 - 6
+        engine.recommend_many_async([seeds[:1]])()  # (1, 1): no padding
+        assert engine.seed_slots_real - real0 == 7
+        assert engine.seed_slots_padded - padded0 == 4 * 32 - 6
+
+    def test_new_series_render_valid_and_registry_backed(self, mined_pvc):
+        cfg, _, _ = mined_pvc
+        app = RecommendApp(cfg)
+        assert app.engine.load()
+        for seed in _rule_seeds(cfg)[:2]:
+            assert _post(app, [seed, "nobody-knows-me"])[0] == 200
+        text = app.handle("GET", "/metrics", None)[2].decode()
+        types, _ = parse_exposition(text)
+        for name, mtype in (
+            ("kmls_batch_size", "histogram"),
+            ("kmls_seed_slots_total", "counter"),
+            ("kmls_unwarmed_dispatches_total", "counter"),
+        ):
+            assert types[name] == mtype
+            assert METRIC_REGISTRY[name] == f"{mtype}:serving"
+        # native path: the array is exact-sized, one known seed a request
+        assert 'kmls_seed_slots_total{kind="real"} 2' in text
+        assert 'kmls_seed_slots_total{kind="padded"} 2' in text
+        assert "kmls_batch_size_count 2" in text
+        assert "kmls_batch_size_sum 2.000000" in text
+        assert "kmls_unwarmed_dispatches_total 0" in text
+        # and the help text says what the interval called "device" is
+        assert "# HELP kmls_device_ms batch formed to batch resolved" in text
+
+    def test_batch_size_buckets_are_cumulative(self):
+        m = ServingMetrics()
+        for n in (1, 1, 2, 3, 8, 32, 40):
+            m.record_batch_size(n)
+        lines = m.batch_size_hist.render("kmls_batch_size")
+        got = {
+            line.split(" ")[0]: int(line.split(" ")[1])
+            for line in lines if "_bucket" in line
+        }
+        assert got == {
+            'kmls_batch_size_bucket{le="1"}': 2,
+            'kmls_batch_size_bucket{le="2"}': 3,
+            'kmls_batch_size_bucket{le="4"}': 4,
+            'kmls_batch_size_bucket{le="8"}': 5,
+            'kmls_batch_size_bucket{le="16"}': 5,
+            'kmls_batch_size_bucket{le="32"}': 6,
+            'kmls_batch_size_bucket{le="+Inf"}': 7,
+        }
+        assert "kmls_batch_size_sum 87.000000" in lines
+        assert "kmls_batch_size_count 7" in lines
+
+    def test_unwarmed_dispatches_total_moves_on_an_unwarmed_shape(
+        self, mined_pvc
+    ):
+        cfg, _, _ = mined_pvc
+        app = RecommendApp(dataclasses.replace(cfg, native_serve=False))
+        assert app.engine.load()
+        seeds = _rule_seeds(cfg)
+        app.engine.recommend_many_async([seeds[:1]])()
+        assert "kmls_unwarmed_dispatches_total 0" in (
+            app.handle("GET", "/metrics", None)[2].decode()
+        )
+        # 33 rows is past batch_max_size: rounded up to 64, never warmed
+        app.engine.recommend_many_async([seeds[:1]] * 33)()
+        assert app.engine.unwarmed_dispatches == 1
+        assert "kmls_unwarmed_dispatches_total 1" in (
+            app.handle("GET", "/metrics", None)[2].decode()
+        )
