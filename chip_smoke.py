@@ -877,7 +877,7 @@ def child_kernels(tiny: bool) -> dict:
     import jax
     import jax.numpy as jnp
     from kmlserver_tpu.ops import popcount
-    from kmlserver_tpu.ops.embed import embed_topk
+    from kmlserver_tpu.ops.embed import embed_topk, factor_table
     from kmlserver_tpu.utils.jaxcache import enable_compilation_cache
 
     enable_compilation_cache()
@@ -918,7 +918,7 @@ def child_kernels(tiny: bool) -> dict:
     factors /= np.linalg.norm(factors, axis=1, keepdims=True)
     seeds = rng.integers(0, v, size=(b, length)).astype(np.int32)
     seeds[:, length // 2:] = -1
-    ids, sims = embed_topk(jnp.asarray(factors), jnp.asarray(seeds), k_best=K_BEST)
+    ids, sims = embed_topk(factor_table(factors), jnp.asarray(seeds), k_best=K_BEST)
     ids, sims = np.asarray(ids), np.asarray(sims)
     if not np.isfinite(sims).all() or ids.shape != (b, K_BEST):
         raise SystemExit(f"embed_topk returned {ids.shape}, finite={np.isfinite(sims).all()}")

@@ -23,7 +23,7 @@ import numpy as np
 from ..config import MiningConfig
 from ..io import artifacts
 from ..mining.vocab import Baskets
-from ..ops.embed import embed_topk
+from ..ops.embed import embed_topk, factor_table
 
 
 @dataclasses.dataclass
@@ -32,7 +32,7 @@ class EmbeddingModel:
 
     vocab: list[str]
     index: dict[str, int]
-    item_factors: jax.Array  # float32 (V, rank), rows L2-normalized, device
+    item_factors: jax.Array  # float32 (rank, V) factor_table(), unit columns, device
     rank: int
 
     # ---------- construction ----------
@@ -45,7 +45,7 @@ class EmbeddingModel:
         return cls(
             vocab=list(vocab),
             index={n: i for i, n in enumerate(vocab)},
-            item_factors=jax.device_put(jnp.asarray(item_factors)),
+            item_factors=factor_table(item_factors),
             rank=int(item_factors.shape[1]),
         )
 

@@ -225,19 +225,21 @@ def _mesh_serve_bytes(dims: dict) -> float:
 
 
 def _embed_flops(dims: dict) -> float:
-    # lax.scan over l seed slots: one (b, r) x (r, v) matmul each
-    # (2·b·r·v), the running max-merge (b·v per step), final top-k
+    # one blocked pass: a (b·l, r) x (r, v) product walked in column
+    # tiles (2·b·l·r·v), the max over each request's l rows while the
+    # tile is at hand (b·l·v), final top-k over the (b, v) maxima
     b, length, v = _d(dims, "b"), _d(dims, "l"), _d(dims, "v")
     r, k_best = _d(dims, "r"), _d(dims, "k_best", 10)
     return b * length * v * (2.0 * r + 1.0) + b * v * _log2k(k_best)
 
 
 def _embed_bytes(dims: dict) -> float:
-    # the factor matrix re-read per scan step + the (b, v) running max
-    # written+read per step + seeds/outputs
+    # the factor table read ONCE per batch + the gathered seed rows +
+    # the (b, v) maxima written by the tile loop and read by the top-k
+    # + seeds/outputs
     b, length, v = _d(dims, "b"), _d(dims, "l"), _d(dims, "v")
     r, k_best = _d(dims, "r"), _d(dims, "k_best", 10)
-    return length * (v * r * 4.0 + 2.0 * b * v * 4.0) + b * (
+    return v * r * 4.0 + b * length * r * 4.0 + 2.0 * b * v * 4.0 + b * (
         length * 4.0 + k_best * 8.0
     )
 

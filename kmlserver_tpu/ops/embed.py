@@ -2,7 +2,7 @@
 
 The second model family's lookup kernel (the rule twin is
 ``ops/serve.py``): seed songs' unit item vectors are gathered from the
-HBM-resident factor matrix, scored against EVERY item by dot product
+HBM-resident factor table, scored against EVERY item by dot product
 (cosine similarity — the factors are row-normalized at publication),
 max-merged over the seeds, and the top-K extracted — batched over B
 concurrent requests, same shape-bucket discipline as the rule kernel so
@@ -22,13 +22,20 @@ diverging only where the geometry demands it:
   own antecedent;
 - rows with no valid seed return all ``-1`` (the engine's membership
   filter degrades those to the popularity fallback before dispatch, so
-  this is belt-and-braces, not the primary path).
+  this is belt-and-braces, not the primary path);
+- equal scores come out lowest item id first (``lax.top_k``'s order).
 
-Memory shape: the similarity pass runs as a ``lax.scan`` over the seed
-axis — each step is one (B, R) × (R, V) matmul into a (B, V) running
-max — so peak live memory is O(B·V), never the O(B·L·V) a one-shot
-einsum would materialize (at a 100k-track vocabulary that difference is
-the whole HBM budget).
+Memory shape: ONE pass over the factors per batch. The seeds are the
+rows of the matrix product and the catalog its blocked axis: the batch's
+``B·L`` seed vectors are gathered once, then the ``(R, V)`` factor table
+(:func:`factor_table` — laid out once, where the model is placed, so the
+catalog is the minor axis and the program re-lays nothing) is walked in
+column tiles; each tile is one ``(B·L, R) × (R, tile)`` product at the
+backend's default precision (bfloat16 products, float32 sums on the
+TPU, at every batch size), max-reduced over each request's ``L`` rows
+while the tile is at hand. Only the ``(B, V)`` maxima are kept; the tile
+width follows the shapes (:func:`_tile_plan`) so the live product stays
+under ``_TILE_ELEMS`` elements however large ``B·L`` is.
 """
 
 from __future__ import annotations
@@ -37,46 +44,96 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # large-but-finite floor instead of -inf: masked lanes stay out of every
 # max without breeding NaNs through 0·inf corners
 _NEG = jnp.float32(-3.0e38)
 
+# the most elements one tile's (B·L, tile) float32 product may hold:
+# 32 MiB, what the v5e keeps beside the table in on-chip memory — at four
+# times that a full (32, 128) batch spills the product to HBM and runs 3×
+# slower, at half of it the loop's trip count starts to show (PERF.md §6)
+_TILE_ELEMS = 1 << 23
+
+# products of fewer rows than one sublane tile are lowered to a float32
+# vector-unit reduction, not the matrix unit's bfloat16 pass: a lone
+# one-seed request would be scored at another precision than the same
+# request inside a batch. The seed axis of so small a batch is repeated
+# up to this many rows (a max over duplicates changes nothing).
+_MIN_ROWS = 8
+
+
+def factor_table(item_factors) -> jax.Array:
+    """Unit item factors ``(V, R)`` → the ``(R, V)`` float32 table
+    :func:`embed_topk` walks. Called ONCE where a model is placed (engine
+    bundle, ``EmbeddingModel``, the eval phase, the chip smoke) — the
+    kernel itself never transposes or copies the table."""
+    return jnp.asarray(
+        np.ascontiguousarray(np.asarray(item_factors, dtype=np.float32).T)
+    )
+
+
+def _tile_plan(rows: int, v: int) -> tuple[int, int]:
+    """→ ``(n_tiles, tile)``: the fewest equal column tiles, each a whole
+    number of 128-lane groups, whose ``(rows, tile)`` product stays under
+    ``_TILE_ELEMS``. The last tile is clamped to end at ``v`` (it overlaps
+    its neighbor by < 128·n_tiles columns), so ``v`` needs no padding."""
+    widest = max(128, _TILE_ELEMS // rows // 128 * 128)
+    n_tiles = -(-v // widest)
+    tile = min(v, -(-v // (n_tiles * 128)) * 128)
+    return n_tiles, tile
+
 
 def _embed_topk_impl(
-    item_factors: jax.Array,  # f32 (V, R), rows L2-normalized
+    item_factors: jax.Array,  # f32 (R, V) factor_table(), unit columns
     seed_ids: jax.Array,  # int32 (B, L), -1 padded
     *,
     k_best: int,
 ):
     """→ ``(top_ids int32 (B, k_best) with -1 padding, top_sims f32)``."""
-    v = item_factors.shape[0]
+    r, v = item_factors.shape
     b = seed_ids.shape[0]
-    safe_seeds = jnp.where(seed_ids >= 0, seed_ids, 0)
+    if seed_ids.size < _MIN_ROWS:
+        seed_ids = jnp.tile(seed_ids, (1, -(-_MIN_ROWS // seed_ids.size)))
+    length = seed_ids.shape[1]
+    valid = seed_ids >= 0
+    # padding slots score as a repeat of one of the row's real seeds, so
+    # the tile loop needs no mask; all-padding rows are blanked after the
+    # top-k, on (B, k) instead of (B, V)
+    stand_in = jnp.max(seed_ids, axis=1, keepdims=True)
+    safe_seeds = jnp.maximum(jnp.where(valid, seed_ids, stand_in), 0)
+    vecs = jnp.take(item_factors, safe_seeds.reshape(-1), axis=1).T  # (B·L, R)
 
-    def step(running_max, cols):
-        seed_col, safe_col = cols  # each (B,)
-        vecs = item_factors[safe_col]  # (B, R)
-        sims = vecs @ item_factors.T  # (B, V) — one MXU matmul per seed slot
-        sims = jnp.where((seed_col >= 0)[:, None], sims, _NEG)
-        return jnp.maximum(running_max, sims), None
+    n_tiles, tile = _tile_plan(b * length, v)
 
-    init = jnp.full((b, v), _NEG, dtype=item_factors.dtype)
-    scores, _ = jax.lax.scan(step, init, (seed_ids.T, safe_seeds.T))
-    # mask the seeds out of their own candidate set (self-similarity is
-    # trivially maximal); padding dumps into an extra slot V, sliced off
-    padded = jnp.concatenate(
-        [scores, jnp.full((b, 1), _NEG, dtype=scores.dtype)], axis=1
+    def score_tile(scores, i):
+        start = jnp.minimum(i * tile, v - tile)
+        block = jax.lax.dynamic_slice(item_factors, (0, start), (r, tile))
+        # an identity (float32's own format) that pins the table to
+        # float32 INSIDE the loop: without it XLA's bfloat16 propagation
+        # converts the whole table ahead of the loop on every call — a
+        # second pass over the factors and a 145 MB temporary
+        block = jax.lax.reduce_precision(block, exponent_bits=8, mantissa_bits=23)
+        sims = jnp.dot(vecs, block, preferred_element_type=jnp.float32)
+        best = sims.reshape(b, length, tile).max(axis=1)
+        return jax.lax.dynamic_update_slice(scores, best, (0, start)), None
+
+    scores, _ = jax.lax.scan(
+        score_tile,
+        jnp.full((b, v), _NEG, dtype=jnp.float32),
+        jnp.arange(n_tiles, dtype=jnp.int32),
     )
-    targets = jnp.where(seed_ids >= 0, seed_ids, v)
+    # mask the seeds out of their own candidate set (self-similarity is
+    # trivially maximal); padding scatters out of bounds and is dropped
     batch_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
-    padded = padded.at[batch_idx, targets].set(_NEG)
-    scores = padded[:, :v]
+    targets = jnp.where(valid, seed_ids, v)
+    scores = scores.at[batch_idx, targets].set(_NEG, mode="drop")
     k = min(k_best, v)
     top_sims, top_ids = jax.lax.top_k(scores, k)
-    valid = top_sims > _NEG / 2
-    top_ids = jnp.where(valid, top_ids, -1)
-    top_sims = jnp.where(valid, top_sims, 0.0)
+    found = (top_sims > _NEG / 2) & valid.any(axis=1, keepdims=True)
+    top_ids = jnp.where(found, top_ids, -1)
+    top_sims = jnp.where(found, top_sims, 0.0)
     if k < k_best:  # static pad so callers always see k_best columns
         pad = ((0, 0), (0, k_best - k))
         top_ids = jnp.pad(top_ids, pad, constant_values=-1)

@@ -220,7 +220,7 @@ def run_eval_phase(
     phase's checkpoint payload AND the ``quality.report.json`` body)."""
     from ..mining import als as als_mod
     from ..mining.miner import mine
-    from ..ops.embed import embed_topk
+    from ..ops.embed import embed_topk, factor_table
     from ..ops.serve import recommend_batch
     from ..ops.support import min_count_for
     from ..serving.engine import blend_candidates
@@ -327,7 +327,7 @@ def run_eval_phase(
             for seeds in split.seed_names
         ]
         e_ids, e_sims = _batched_candidates(
-            embed_topk, (jnp.asarray(emb["factors"]),), emb_seed_ids, k
+            embed_topk, (factor_table(emb["factors"]),), emb_seed_ids, k
         )
         emb_pairs = [
             [

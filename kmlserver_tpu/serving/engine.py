@@ -86,7 +86,7 @@ from ..config import ServingConfig
 from ..io import artifacts, iohealth, registry
 from ..io.artifacts import ArtifactIntegrityError
 from ..observability import costmodel as costmodel_mod
-from ..ops.embed import embed_topk
+from ..ops.embed import embed_topk, factor_table
 from ..ops.serve import recommend_batch
 
 logger = logging.getLogger("kmlserver_tpu.serving")
@@ -716,7 +716,8 @@ class RecommendEngine:
             return True, f"{type(exc).__name__}: {exc}"
         emb_vocab = loaded["vocab"]
         emb_index = {n: i for i, n in enumerate(emb_vocab)}
-        factors = jnp.asarray(loaded["item_factors"])
+        # laid out once, here, as the (rank, V) table the kernel walks
+        factors = factor_table(loaded["item_factors"])
         for bundle in replicas:
             bundle.emb_vocab = emb_vocab
             bundle.emb_index = emb_index
@@ -2053,7 +2054,7 @@ class RecommendEngine:
                                 max((len(s) for s in seed_sets), default=1)
                             ),
                             v=len(bundle.emb_vocab or ()),
-                            r=int(bundle.emb_factors.shape[1]),
+                            r=int(bundle.emb_factors.shape[0]),
                             k_best=self.cfg.k_best_tracks,
                         )
                     if trace is not None:
@@ -2142,7 +2143,7 @@ class RecommendEngine:
                         "embed_topk",
                         time.perf_counter() - t_rules,
                         b=n_rows, l=length, v=len(bundle.emb_vocab or ()),
-                        r=int(bundle.emb_factors.shape[1]),
+                        r=int(bundle.emb_factors.shape[0]),
                         k_best=self.cfg.k_best_tracks,
                     )
                 if trace is not None:
