@@ -151,6 +151,15 @@ def _staging_is_safe() -> bool:
     return _HOST_STAGING_SAFE
 
 
+def _start_host_copies(*arrays) -> None:
+    """Start each device result's copy to the host without waiting for
+    it: the copies queue behind their programs on the device's stream,
+    so the ``np.asarray`` that picks a result up later finds a copy that
+    is done or under way, and does not start one and sit it out."""
+    for a in arrays:
+        a.copy_to_host_async()
+
+
 def blend_candidates(
     rule_pairs: list[tuple[str, float]],
     emb_pairs: list[tuple[str, float]],
@@ -1775,8 +1784,10 @@ class RecommendEngine:
         """Dispatch the embedding cosine top-k for a batch → ``(device
         top_ids, device top_sims, host known-row mask)``, or None when the
         bundle carries no factors / the operator pinned rules-only. Runs
-        on the DISPATCH path (no host syncs — jax dispatch is async); the
-        caller's ``finish()`` converts the device results. The (n_rows,
+        on the DISPATCH path (no host syncs — jax dispatch is async), so
+        its fill, transfer and enqueue lie inside the batch's ``dispatch``
+        span; the caller's ``finish()`` converts the device results (the
+        device path starts their copies first). The (n_rows,
         length) shape must come from the warmed bucket grid — an unwarmed
         shape is counted and logged exactly like the rule kernel's."""
         if bundle.emb_factors is None or self.cfg.hybrid_mode == "rules":
@@ -1920,9 +1931,16 @@ class RecommendEngine:
         self, seed_sets: list[list[str]], replica: int | None = None,
         deadline: float | None = None, trace=None,
     ):
-        """Batched lookup split into DISPATCH (device call enqueued, returns
+        """Batched lookup split into DISPATCH (device calls enqueued, returns
         immediately — jax dispatch is asynchronous) and FINISH (a zero-arg
-        callable that blocks on the result and builds the responses).
+        callable that blocks on the results and builds the responses).
+
+        On the device path every result's device → host copy is started
+        at dispatch (:func:`_start_host_copies`), behind its program on
+        the device's stream, and ``finish()`` picks the results up in
+        program order, the rule pair and then the embedding pair: a
+        pick-up finds its copy done or under way, and no longer starts
+        one and sits it out before the next can begin.
 
         The split lets the micro-batcher pipeline device calls: a
         dispatch-block-respond loop caps throughput at batch_size over
@@ -1947,8 +1965,12 @@ class RecommendEngine:
         None = untraced, and then each site below is one is-None check).
         All four variants — fallback, native, device, mesh — record the
         same spans on it with ``TraceContext.lap``, each where the work
-        happens: ``stage`` (:meth:`_note_staged`) and ``dispatch`` here,
-        ``fetch_rules``, ``fetch_embed`` and ``compose`` in ``finish()``.
+        happens: ``stage`` (:meth:`_note_staged`: the rule seeds' fill
+        and transfer) and ``dispatch`` (the rule enqueue, the embedding
+        seeds' fill, transfer and enqueue, and on the device path the
+        starting of the results' copies) here; ``fetch_rules`` (the rule
+        pair's pick-up), ``fetch_embed`` (the embedding pair's) and
+        ``compose`` in ``finish()``.
         What lies between ``dispatch`` and ``finish()`` starting is the
         batcher's hop to its completion thread, and belongs to no span."""
         if trace is not None:
@@ -2098,6 +2120,9 @@ class RecommendEngine:
         # the rule kernel onto the same replica device — both async, both
         # consumed together in finish()
         emb = self._dispatch_embed(bundle, seed_sets, n_rows, length)
+        # every result starts for the host now, behind its program, so
+        # that finish() does not start four copies one after the other
+        _start_host_copies(top_ids, top_confs, *(emb[:2] if emb else ()))
         self._note_dispatch(idx)
         if trace is not None:
             trace.lap("dispatch")
@@ -2107,7 +2132,9 @@ class RecommendEngine:
                 trace.skip()
             # chaos hook on the completion path (see finish_native)
             faults.fire("replica.kernel", replica=idx)
-            host_ids = np.asarray(top_ids)  # blocks on the device transfer
+            # the rule pair first: its program ran first, and its pick-up
+            # is the fence between the two programs below
+            host_ids = np.asarray(top_ids)  # waits for the copy started above
             host_confs = np.asarray(top_confs)
             if trace is not None:
                 trace.lap("fetch_rules")

@@ -515,3 +515,180 @@ class TestEmbeddingChaos:
         assert not app.engine.embedding_degraded
         status, _h, body = app.handle("GET", "/readyz", None)
         assert status == 200 and json.loads(body)["status"] == "ready"
+
+
+# ---------------------------------------------------------------------------
+# result copies started at dispatch (ISSUE 32): the batched device path
+# against the single-request path, and the mechanism itself
+# ---------------------------------------------------------------------------
+
+HYBRID_MODES = ("blend", "rules", "embed")
+ROW_KINDS = ("rule_only", "embed_only", "neither", "over_long", "empty")
+
+
+@pytest.fixture(scope="module")
+def hybrid_pvc(tmp_path_factory):
+    base = str(tmp_path_factory.mktemp("hybrid_pvc"))
+    run_mining_job(_make_pvc(base))
+    return base
+
+
+@pytest.fixture(scope="module")
+def device_engines(hybrid_pvc):
+    """One jitted-path engine per hybrid mode on one mined PVC, and the
+    batch every case below dispatches: a row for each of ``ROW_KINDS``.
+    The rule-only row's seed is taken out of the embedding index (the
+    live factors may be older than the rules, after a delta), so it is
+    known to the rule family alone."""
+    engines = {
+        mode: _serving_app(
+            hybrid_pvc, hybrid_mode=mode, native_serve=False
+        ).engine
+        for mode in HYBRID_MODES
+    }
+    cold, hot = _cold_and_hot_seeds(engines["blend"])
+    bundle = engines["blend"].bundle
+    hots = sorted(
+        bundle.vocab[i] for i in range(len(bundle.vocab))
+        if bundle.known_mask[i]
+    )
+    rule_only = hots[-1]
+    assert rule_only != hot
+    for engine in engines.values():
+        for replica in engine.replicas:
+            if replica.emb_index is not None:
+                replica.emb_index = {
+                    k: v for k, v in replica.emb_index.items()
+                    if k != rule_only
+                }
+    cap = engines["blend"].cfg.max_seed_tracks
+    rows = {
+        "rule_only": [rule_only],
+        "embed_only": [cold],
+        "neither": ["definitely-not-a-track"],
+        # longer than the widest bucket: both paths cut it at the cap,
+        # which drops the last two names
+        "over_long": [hot] * (cap - 1) + [hots[1], hots[2], cold],
+        "empty": [],
+    }
+    assert len(rows["over_long"]) > cap
+    return engines, rows
+
+
+class TestBatchedMatchesSingle:
+    @pytest.mark.parametrize("kind", ROW_KINDS)
+    @pytest.mark.parametrize("mode", HYBRID_MODES)
+    def test_row_for_row_what_recommend_gives(self, device_engines, mode, kind):
+        """``recommend_many_async(...)()`` answers each row of a mixed
+        batch with the songs and the source that ``recommend()`` gives
+        the same seed set alone, whatever the mode and whichever family
+        knows the row; the row is also checked as a batch of its own
+        (where a row no family knows dispatches one program, or none)."""
+        engines, rows = device_engines
+        engine = engines[mode]
+        expected = engine.recommend(rows[kind])
+        batch = [rows[k] for k in ROW_KINDS]
+        got = engine.recommend_many_async(batch)()
+        assert len(got) == len(batch)
+        assert got[ROW_KINDS.index(kind)] == expected
+        assert engine.recommend_many_async([rows[kind]])() == [expected]
+        assert engine.unwarmed_dispatches == 0
+
+    def test_the_rows_are_what_their_names_say(self, device_engines):
+        engines, rows = device_engines
+        sources = {
+            kind: engines["blend"].recommend(rows[kind])[1] for kind in rows
+        }
+        assert sources == {
+            "rule_only": "rules", "embed_only": "embed",
+            "neither": "fallback", "over_long": "hybrid",
+            "empty": "fallback",
+        }
+
+
+class _Watched:
+    """Stands in for a device result: says when its copy to the host was
+    started and when it was picked up, and is the array otherwise."""
+
+    def __init__(self, arr, name, log):
+        self.arr, self.name, self.log = arr, name, log
+
+    def copy_to_host_async(self):
+        self.log.append(("copy", self.name))
+        self.arr.copy_to_host_async()
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("fetch", self.name))
+        return np.asarray(self.arr)
+
+
+class TestCopiesStartAtDispatch:
+    """The mechanism, on the CPU backend: every result's copy to the host
+    is started inside dispatch, and ``finish()`` picks the rule pair up
+    before the embedding pair."""
+
+    @staticmethod
+    def _watch(engine, monkeypatch):
+        from kmlserver_tpu.serving import engine as engine_mod
+
+        log: list[tuple[str, str]] = []
+        real_kernel, real_embed = engine._kernel, engine_mod.embed_topk
+
+        def kernel(*args):
+            ids, confs = real_kernel(*args)
+            return (
+                _Watched(ids, "rule_ids", log),
+                _Watched(confs, "rule_confs", log),
+            )
+
+        def embed(*args, **kwargs):
+            ids, sims = real_embed(*args, **kwargs)
+            return (
+                _Watched(ids, "emb_ids", log),
+                _Watched(sims, "emb_sims", log),
+            )
+
+        monkeypatch.setattr(engine, "_kernel", kernel)
+        monkeypatch.setattr(engine_mod, "embed_topk", embed)
+        return log
+
+    def test_hybrid_batch_four_copies_picked_up_in_program_order(
+        self, device_engines, monkeypatch
+    ):
+        engines, rows = device_engines
+        engine = engines["blend"]
+        batch = [rows["over_long"], rows["embed_only"], rows["rule_only"]]
+        expected = engine.recommend_many_async(batch)()  # unwatched, warm
+        log = self._watch(engine, monkeypatch)
+        finish = engine.recommend_many_async(batch)
+        # dispatch is over: every result is already on its way to the
+        # host, and none has been waited for
+        assert log == [
+            ("copy", "rule_ids"), ("copy", "rule_confs"),
+            ("copy", "emb_ids"), ("copy", "emb_sims"),
+        ]
+        del log[:]
+        assert finish() == expected
+        assert log == [
+            ("fetch", "rule_ids"), ("fetch", "rule_confs"),
+            ("fetch", "emb_ids"), ("fetch", "emb_sims"),
+        ]
+
+    @pytest.mark.parametrize("case", ["rules_mode", "no_embed_known_seed"])
+    def test_rules_only_batch_starts_the_rule_pair_alone(
+        self, device_engines, monkeypatch, case
+    ):
+        """The embedding dispatch is skipped where the code can see it
+        has nothing to answer: the operator pinned rules-only, or no row
+        of the batch has an embed-known seed. Two copies start, not four."""
+        engines, rows = device_engines
+        engine = engines["rules" if case == "rules_mode" else "blend"]
+        batch = [rows["rule_only"], rows["neither"], rows["empty"]]
+        if case == "rules_mode":
+            batch.append(rows["over_long"])
+        expected = engine.recommend_many_async(batch)()
+        log = self._watch(engine, monkeypatch)
+        finish = engine.recommend_many_async(batch)
+        assert log == [("copy", "rule_ids"), ("copy", "rule_confs")]
+        assert finish() == expected
+        assert log[2:] == [("fetch", "rule_ids"), ("fetch", "rule_confs")]

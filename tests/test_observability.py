@@ -8,6 +8,7 @@ PR 8 inline-path blind spot, and the mining job_metrics.prom textfile.
 
 import bisect
 import dataclasses
+import inspect
 import json
 import math
 import os
@@ -1890,6 +1891,85 @@ class TestSpanTree:
         # three requests of 1, 2 and 3 seeds land in the (4, 8) bucket
         assert doc["attrs"]["rows"] == 4 and doc["attrs"]["length"] == 8
         assert doc["attrs"]["seeds_real"] == 6
+
+    def test_hybrid_batch_keeps_the_span_names_the_benchmark_reads(
+        self, tmp_path
+    ):
+        """ISSUE 32: with every result's copy started at dispatch, a
+        traced hybrid batch still records exactly
+        ``stage``, ``dispatch``, ``fetch_rules``, ``fetch_embed``,
+        ``compose``: each side of the hop to the completion thread tiles
+        without a gap, and the slot counters count the rule array."""
+        from .test_embedding import (
+            _cold_and_hot_seeds, _make_pvc, _serving_app,
+        )
+
+        run_mining_job(_make_pvc(str(tmp_path)))
+        engine = _serving_app(str(tmp_path), native_serve=False).engine
+        assert engine.embedding_active
+        cold, hot = _cold_and_hot_seeds(engine)
+        rec = SpanRecorder(sample=1.0, rng=random.Random(32))
+        bt = rec.begin_batch(time.perf_counter(), requests=3, replica=0)
+        real0, padded0 = engine.seed_slots_real, engine.seed_slots_padded
+        # the cold seed is in the embedding array alone: 3 rule seeds
+        results = engine.recommend_many_async(
+            [[hot], [cold], [hot, cold, hot]], trace=bt
+        )()
+        assert [src for _songs, src in results] == ["hybrid", "embed", "hybrid"]
+        rec.finish_batch(bt)
+        (doc,) = rec.debug_payload()["batches"]
+        names = [s["name"] for s in doc["spans"]]
+        assert names == [
+            "batch", "stage", "dispatch", "fetch_rules", "fetch_embed",
+            "compose",
+        ]
+        _assert_tree(doc)
+        span = {s["name"]: s for s in doc["spans"]}
+        slack = 2e-4  # start_ms / duration_ms are each rounded to 1e-4 ms
+
+        def end(name):
+            return span[name]["start_ms"] + span[name]["duration_ms"]
+
+        for before, after in (
+            ("stage", "dispatch"), ("fetch_rules", "fetch_embed"),
+            ("fetch_embed", "compose"),
+        ):
+            assert abs(span[after]["start_ms"] - end(before)) <= slack
+        assert span["fetch_rules"]["start_ms"] >= end("dispatch") - slack
+        assert doc["attrs"]["rows"] == 4 and doc["attrs"]["length"] == 8
+        assert doc["attrs"]["seeds_real"] == 3
+        assert engine.seed_slots_real - real0 == 3
+        assert engine.seed_slots_padded - padded0 == 4 * 8 - 3
+
+    def test_every_engine_span_name_has_a_bucket_in_the_benchmark(
+        self, mined_pvc
+    ):
+        """A span name that ``benchmark/spans.py`` does not know leaves
+        its idle time unclaimed (PR 31 read 5.7% there): whatever any
+        variant of ``recommend_many_async`` records is a key of
+        ``BUCKET_OF``, and stands in ``PRECEDENCE``."""
+        from benchmark import spans as bench_spans
+        from kmlserver_tpu.serving.engine import RecommendEngine
+
+        source = inspect.getsource(RecommendEngine)
+        laps = set(re.findall(r'trace\.lap\("(\w+)"', source))
+        assert {"stage", "dispatch", "fetch_rules", "fetch_embed",
+                "compose"} <= laps
+        assert laps <= set(bench_spans.BUCKET_OF)
+        assert laps <= set(bench_spans.PRECEDENCE)
+        # and what a live batch records is among them, on both variants
+        cfg, _, _ = mined_pvc
+        for native in (True, False):
+            engine = RecommendEngine(
+                dataclasses.replace(cfg, native_serve=native)
+            )
+            assert engine.load()
+            rec = SpanRecorder(sample=1.0, rng=random.Random(5))
+            bt = rec.begin_batch(time.perf_counter(), requests=1, replica=0)
+            engine.recommend_many_async([_rule_seeds(cfg)[:2]], trace=bt)()
+            rec.finish_batch(bt)
+            (doc,) = rec.debug_payload()["batches"]
+            assert {s["name"] for s in doc["spans"][1:]} <= laps
 
     def test_three_requests_one_batch_trace_three_batch_spans(self):
         """Satellite: requests that share a dispatch share one batch
