@@ -18,7 +18,7 @@ import time
 
 import numpy as np
 
-from . import loadgen, manifest, readers, traffic
+from . import loadgen, manifest, readers, spans, traffic
 from . import server as server_mod
 from . import trace as trace_mod
 
@@ -218,13 +218,15 @@ class Session:
         return win
 
     def wait_capture(self, timeout: float = 150.0) -> str | None:
-        """The capture's file, once the server has finished writing it."""
+        """The capture's file, once the server has finished writing it and,
+        after it, the spans beside it (its log then says closed, or that
+        the spans were not written)."""
         deadline = time.monotonic() + timeout
         path, size = None, -1
         while time.monotonic() < deadline:
             path = trace_mod.find_capture(self.env_extra["KMLS_PROFILE_DIR"])
             now = os.path.getsize(path) if path else -1
-            if path and now == size:
+            if path and now == size and spans.DONE_LINE.search(self.srv.output()):
                 return path
             size = now
             time.sleep(1.0)

@@ -56,6 +56,8 @@ SPANS_FILENAME = "kmls_spans.jsonl"
 ANCHOR_PREFIX = "kmls/clock:"
 CLOSED_LINE = re.compile(r"profile capture closed: dir=(\S+) ")
 OPEN_LINE = re.compile(r"profile capture open: dir=(\S+) ")
+# the capture thread's last word: the spans are written, or could not be
+DONE_LINE = re.compile(r"profile capture( closed: dir=|: \S+ not written)")
 
 # which open span an idle instant is put down to, first match first: what
 # stands between a request and the chip (nearest the chip first), then what

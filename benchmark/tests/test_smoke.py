@@ -119,6 +119,21 @@ def test_without_an_accelerator_nothing_is_measured():
         ses.close()
 
 
+@pytest.mark.parametrize("cell", CELLS)
+def test_an_open_loops_tail_has_ten_samples_beyond_it(cell):
+    """The highest percentile a cell reports has ten requests of a window
+    beyond it (the choosing-metrics guide): with five, whether one of them
+    met a pause of the machine decides the tail."""
+    loaded = manifest.Cell(cell)
+    tails = [int(m.group(1)) for m in (
+        re.fullmatch(r".*_p(\d+)_ms", e["name"]) for e in loaded.end_to_end()
+    ) if m]
+    if loaded.workload.get("closed_callers") or not tails:
+        pytest.skip("no fixed rate, or no percentile among the cell's end-to-end metrics")
+    n = float(loaded.workload["rate_rps"]) * loaded.bench["run_seconds"]
+    assert n * (1.0 - max(tails) / 100.0) >= 10.0, (cell, n, max(tails))
+
+
 def test_manifest_names_units_and_files():
     bench = manifest.benchmark()
     assert set(bench) == {"command", "paths", "run_seconds", "configs", "workloads",
