@@ -1318,7 +1318,7 @@ with tempfile.TemporaryDirectory(prefix="kmls_chaos_") as base:
     # so the killed replica stays out (recovery time stays well-defined)
     cfg = dataclasses.replace(
         ServingConfig.from_env(), base_dir=base,
-        serve_devices=2, native_serve=False,
+        serve_devices=2,
         batch_max_size=64, shed_queue_budget_ms=0.0,
         replica_eject_threshold=3, replica_probe_interval_s=3600.0,
         # >= eject_threshold: a request can be failed at most
@@ -2946,7 +2946,7 @@ with tempfile.TemporaryDirectory(prefix="kmls_costattrib_") as base:
     def build(enabled):
         cfg = dataclasses.replace(
             ServingConfig.from_env(), base_dir=base,
-            cache_enabled=False, native_serve=False,
+            cache_enabled=False,
             costmodel_enabled=enabled,
         )
         app = RecommendApp(cfg)
@@ -3245,7 +3245,7 @@ with tempfile.TemporaryDirectory(prefix="kmls_confserve_") as base:
     run_mining_job(mcfg)
     cfg = dataclasses.replace(
         ServingConfig.from_env(dotenv_path=None), base_dir=base,
-        native_serve=False, batch_max_size=64, shed_queue_budget_ms=0.0,
+        batch_max_size=64, shed_queue_budget_ms=0.0,
     )
     app = RecommendApp(cfg)
     assert app.engine.load(), "mined artifacts must load"
@@ -3313,7 +3313,6 @@ with tempfile.TemporaryDirectory(prefix="kmls_shardserve_") as base:
 
     common = dict(
         base_dir=base, batch_max_size=32, max_seed_tracks=8,
-        native_serve=False,
     )
     rep = RecommendEngine(dataclasses.replace(
         ServingConfig.from_env(dotenv_path=None), serve_devices=1, **common
@@ -3438,7 +3437,6 @@ with tempfile.TemporaryDirectory(prefix="kmls_meshserve_") as base:
 
     common = dict(
         base_dir=base, batch_max_size=32, max_seed_tracks=8,
-        native_serve=False,
     )
     rep = RecommendEngine(dataclasses.replace(
         ServingConfig.from_env(dotenv_path=None), serve_devices=1, **common

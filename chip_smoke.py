@@ -191,9 +191,8 @@ class Smoke:
             self.env.update({
                 "JAX_PLATFORMS": "cpu",
                 "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
-                # on a CPU backend the defaults pick the host twins; the
-                # smoke is about the jitted device paths
-                "KMLS_NATIVE_SERVE": "0",
+                # on a CPU backend the miner's default is its host twin;
+                # the smoke is about the jitted device paths
                 "KMLS_NATIVE_PAIR_COUNTS": "0",
                 "KMLS_SERVE_DEVICES": "4",
                 # toy warm-up grid: 2 length × 3 batch buckets
@@ -320,6 +319,7 @@ class Smoke:
         oracle_box: dict = {}
         oracle_thread = threading.Thread(
             target=lambda: oracle_box.update(rules=_oracle_rules(table)),
+            daemon=True,
         )
         oracle_thread.start()
         # with several devices the default mesh shards; the first mine is
@@ -330,8 +330,12 @@ class Smoke:
                 "mine", table, {"KMLS_MESH_SHAPE": "1x1"} if many else {}
             )
         finally:
-            oracle_thread.join()
-        _check("rules" in oracle_box, "mine: the oracle thread died")
+            # the mine's own limit again: no wait here is without one
+            oracle_thread.join(timeout=900)
+        _check(
+            "rules" in oracle_box,
+            "mine: the oracle thread died or ran past 900 s",
+        )
         _check(
             mined["path"] == "dense-fused",
             f"mine: Pair-count path {mined['path']!r}, wanted the device "

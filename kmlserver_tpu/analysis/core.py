@@ -130,7 +130,6 @@ class AnalysisConfig:
         "MicroBatcher._n_lock",
         "MicroBatcher._rate_lock",
         "RecommendEngine._dispatch_lock",
-        "RecommendEngine._staging_lock",
         "RecommendCache._lock",
         "ServingMetrics._lock",
         "LatencyReservoir._lock",
@@ -261,7 +260,6 @@ class AnalysisConfig:
     costspec_required: tuple[str, ...] = (
         "serve_rules",
         "serve_sharded",
-        "serve_native",
         "embed_topk",
         "als_sweep",
         "support_count",
@@ -374,7 +372,7 @@ class ModuleInfo:
     relpath: str
     tree: ast.Module
     source_lines: list[str]
-    # local name -> project module relpath ("from . import native_serve",
+    # local name -> project module relpath ("from . import mesh",
     # "from ..io import artifacts", "import kmlserver_tpu.faults as faults")
     module_imports: dict[str, str] = dataclasses.field(default_factory=dict)
     # local name -> (relpath, original name) for "from X import name"

@@ -19,7 +19,7 @@ will trust anyway. This checker closes both directions statically:
   checkable when the name is visible at the call site (forwarding
   helpers carry a pragma);
 - the REQUIRED kernel names (the dispatched jitted kernels: replicated/
-  sharded/native serve, embed top-k, ALS sweep, support count, delta
+  sharded serve, embed top-k, ALS sweep, support count, delta
   recount) must all be registered — the anchor that keeps a rename from
   silently hollowing the checker;
 - every ``kmls_*`` series the cost model renders must be declared in

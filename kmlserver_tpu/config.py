@@ -165,7 +165,6 @@ KNOB_REGISTRY: dict[str, str] = {
     "KMLS_CACHE_ENABLED": "serving",
     "KMLS_CACHE_MAX_ENTRIES": "serving",
     "KMLS_PREFER_TENSOR_ARTIFACT": "serving",
-    "KMLS_NATIVE_SERVE": "serving",
     "KMLS_DRAIN_SETTLE_S": "serving",
     "KMLS_GIL_SWITCH_S": "serving",
     # --- serving: fault tolerance ---
@@ -865,8 +864,8 @@ class ServingConfig:
     # replicas.
     batch_max_inflight: int = 4
     # Serving replicas, one per local device: 0 = auto (every local device
-    # on accelerator backends; 1 on CPU, where the native host kernel owns
-    # the hot path and extra virtual-device replicas only multiply warmup
+    # on accelerator backends; 1 on CPU, where virtual devices share the
+    # same host cores and extra replicas only multiply warmup
     # compiles). N > 0 pins min(N, local device count) replicas — e.g.
     # KMLS_SERVE_DEVICES=8 on an 8-virtual-device CPU host exercises the
     # full data-parallel dispatch tier without hardware.
@@ -879,9 +878,8 @@ class ServingConfig:
     # rows, so the servable catalog scales with the mesh; "auto" measures
     # the loaded tensor bytes against device_budget_bytes and shards only
     # when a replica would not fit. Sharded layout serves through the
-    # jitted sharded kernel (the native host kernel has no per-device
-    # state to partition, so it is bypassed) and presents as one replica
-    # to the dispatcher.
+    # jitted sharded kernel and presents as one replica to the
+    # dispatcher.
     model_layout: str = "replicated"
     # Per-device byte budget the auto layout measures rule+confidence
     # tensor bytes against. 0 disables the auto trigger (auto then always
@@ -895,13 +893,6 @@ class ServingConfig:
     cache_max_entries: int = 8192
     # Prefer the tensor-native npz artifact over the pickle when present.
     prefer_tensor_artifact: bool = True
-    # On a CPU backend, serve lookups with the native C++ kernel
-    # (native/kmls_serve.cpp) instead of the jitted XLA kernel — exact
-    # (lax.top_k tie order reproduced), ~24x faster on the scatter-bound
-    # XLA:CPU path (measured 12.6 -> 0.52 ms per 32-row ds2 batch).
-    # Ignored on accelerators; falls back automatically when the .so
-    # can't build. KMLS_NATIVE=0 also kills it.
-    native_serve: bool = True
 
     # --- robustness knobs (fault-tolerance layer) ---
     # Validate artifacts against the mining job's integrity manifest
@@ -1164,7 +1155,6 @@ class ServingConfig:
             cache_enabled=_getenv_bool("KMLS_CACHE_ENABLED", True),
             cache_max_entries=_getenv_int("KMLS_CACHE_MAX_ENTRIES", 8192),
             prefer_tensor_artifact=_getenv_bool("KMLS_PREFER_TENSOR_ARTIFACT", True),
-            native_serve=_getenv_bool("KMLS_NATIVE_SERVE", True),
             verify_manifest=_getenv_bool("KMLS_VERIFY_MANIFEST", True),
             quarantine_after_failures=_getenv_int(
                 "KMLS_QUARANTINE_AFTER_FAILURES", 2

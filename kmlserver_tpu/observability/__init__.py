@@ -1,6 +1,6 @@
 """Observability layer: per-request span tracing with tail-based
 retention (``trace``, ISSUE 9), runtime-health collection — event-loop
-lag + inline-kernel stalls — feeding the admission ladder (``runtime``),
+lag — feeding the admission ladder (``runtime``),
 mining-side textfile telemetry (``jobmetrics``), device-truth cost
 attribution — per-kernel MFU/roofline, memory and compile telemetry
 (``costmodel``, ISSUE 12) — and multi-window SLO burn rates (``slo``).

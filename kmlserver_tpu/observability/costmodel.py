@@ -13,9 +13,8 @@ Three pieces:
 - **Analytic cost specs** (:data:`KERNEL_COST_SPECS`): for every jitted
   kernel the project dispatches — the rule scatter-max serve kernel
   (``ops/serve.py recommend_batch``), its vocab-sharded twin
-  (``sharded_recommend_fn``), the native host kernel (same algorithm,
-  host peaks), the embedding cosine top-k (``ops/embed.py embed_topk``),
-  the ALS half-sweeps (``mining/als.py``), the pair-support count
+  (``sharded_recommend_fn``), the embedding cosine top-k
+  (``ops/embed.py embed_topk``), the ALS half-sweeps (``mining/als.py``), the pair-support count
   (``parallel/support.py`` / ``ops/support.py``), and the delta
   restricted recount (``parallel/support.restricted_pair_counts``) — a
   FLOPs(shape) and bytes-moved(shape) formula. The formulas are
@@ -342,11 +341,6 @@ KERNEL_COST_SPECS: dict[str, CostSpec] = {
         "pod-spanning gang lookup: local slab partial + rank-stacked "
         "merge (ops/serve.py shard_partial_topk/merge_partial_topk via "
         "serving/mesh.py; dims + shards)",
-    ),
-    "serve_native": CostSpec(
-        "serve_native", _serve_flops, _serve_bytes,
-        "native host scatter-max kernel — identical algorithm to "
-        "serve_rules, measured against host peaks",
     ),
     "embed_topk": CostSpec(
         "embed_topk", _embed_flops, _embed_bytes,

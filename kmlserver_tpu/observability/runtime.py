@@ -1,27 +1,21 @@
-"""Runtime-health collection: event-loop lag + inline-kernel stalls.
+"""Runtime-health collection: event-loop lag.
 
 The PR 8 postmortem (ROADMAP "load-adaptive serving") names the blind
-spot this closes: when the native CPU serve kernel computes ON the
-asyncio event loop (the inline fast path — sub-millisecond when
-healthy), a stalled kernel blocks the loop itself. Requests pile into
-the socket accept backlog where the admission controller's queue-wait
-projection cannot see them — the projection measures the batcher's
-queue, and nothing ever reaches the batcher while the loop is wedged.
-Verified with an injected 200 ms kernel delay: the executor path sheds
-correctly, the inline path answered everything late.
+spot this closes: whatever blocks the asyncio event loop (then, a serve
+kernel computed ON the loop; any callback that overstays, now that every
+batch's ``finish()`` runs on the executor) keeps requests in the socket
+accept backlog where the admission controller's queue-wait projection
+cannot see them — the projection measures the batcher's queue, and
+nothing reaches the batcher while the loop is wedged.
 
-:class:`LoopLagMonitor` measures the stall from two directions:
-
-- a **timer-drift tick**: ``loop.call_later`` re-arms every
-  ``interval_s``; the difference between when the tick was due and when
-  it actually ran IS the time something blocked the loop (the same
-  technique node.js exposes as ``eventLoopDelay``). A thread variant
-  (:meth:`start_thread`) gives the threaded transport host-scheduling
-  visibility with the same signal shape.
-- a **direct stall note**: the async batcher's inline branch times the
-  in-line ``finish()`` call and reports it via :meth:`note` — the
-  synchronous ground truth, available the instant the loop unblocks
-  (the drift tick only runs one loop iteration later).
+:class:`LoopLagMonitor` measures the stall with a **timer-drift tick**:
+``loop.call_later`` re-arms every ``interval_s``; the difference between
+when the tick was due and when it actually ran IS the time something
+blocked the loop (the same technique node.js exposes as
+``eventLoopDelay``). A thread variant (:meth:`start_thread`) gives the
+threaded transport host-scheduling visibility with the same signal
+shape. :meth:`note` folds one measured blockage in; the ticks call it,
+and so may any caller that has timed a stall itself.
 
 The signal is a peak-hold with exponential decay (half-life
 ``half_life_s``): one 200 ms stall registers immediately and fades over

@@ -73,8 +73,8 @@ AGGREGATE capacity: ``max_inflight`` batches per replica, and a projected
 queue wait of (batches ahead × device-time EWMA) / replica count —
 N devices drain the same queue N times faster. Engines without a replica
 set (``n_replicas`` absent or 1) get the exact single-lane behavior the
-fakes and the native host kernel expect: the ``replica`` kwarg is only
-passed when there is a choice to make.
+fakes expect: the ``replica`` kwarg is only passed when there is a choice
+to make.
 
 **Replica health management** (``eject_threshold > 0``): a per-replica
 consecutive-failure circuit breaker. A replica whose batches keep failing
@@ -419,7 +419,6 @@ class MicroBatcher:
         # runtime-health signal (observability.runtime.LoopLagMonitor):
         # folded into admission pressure so a host-scheduling stall the
         # queue projection can't see still escalates the ladder
-        self.lag_monitor = lag_monitor
         self._admission = AdmissionController(
             self.shed_budget_s,
             soft_ratio=shed_soft_ratio,
@@ -944,8 +943,8 @@ class MicroBatcher:
             )
             try:
                 # the replica kwarg is passed only when there's a choice:
-                # single-replica engines (fakes, the native host kernel)
-                # keep the bare signature they always had; the deadline
+                # single-replica engines (fakes) keep the bare
+                # signature they always had; the deadline
                 # and trace kwargs only when the engine declared them
                 # (deadline propagation across the mesh; batch spans)
                 kwargs = {}
@@ -1194,13 +1193,10 @@ class AsyncMicroBatcher:
         self.window_min_s = min(window_min_ms / 1e3, self.window_s)
         self.shed_budget_s = shed_queue_budget_ms / 1e3
         self.shed_retry_after_s = shed_retry_after_s
-        # runtime health: the inline native path computes ON the loop, so
-        # a stalled kernel blocks the loop itself and backpressure piles
-        # into the socket backlog where the queue projection is blind
-        # (the PR 8 postmortem). The inline branch reports its measured
-        # in-line compute time here — the synchronous ground truth — and
-        # the controller folds the decayed peak into pressure.
-        self.lag_monitor = lag_monitor
+        # runtime health: a stalled event loop piles backpressure into
+        # the socket backlog where the queue projection is blind (the
+        # PR 8 postmortem); the controller folds the monitor's decayed
+        # lag peak into pressure
         self._admission = AdmissionController(
             self.shed_budget_s,
             soft_ratio=shed_soft_ratio,
@@ -1241,8 +1237,8 @@ class AsyncMicroBatcher:
         # predictive pre-fetch (ISSUE 17) — hop here via
         # call_soon_threadsafe instead of calling in from their thread
         self._loop = None
-        # finish() blocks (device transfer, or the GIL-releasing native
-        # call) — it must run off-loop; pool depth = aggregate pipeline
+        # finish() blocks (the results' device → host copies) — it must
+        # run off-loop; pool depth = aggregate pipeline
         # depth. The replica count isn't known until the engine's first
         # load, so the pool is sized for the largest realistic replica set
         # (threads spawn on demand — headroom costs nothing) and the
@@ -1438,21 +1434,6 @@ class AsyncMicroBatcher:
             future.add_done_callback(lambda _f: handle.cancel())
         if len(self._pending) >= self.max_size:
             self._flush(loop)  # full batch: dispatch now
-        elif getattr(self.engine, "host_kernel_active", False):
-            # inline mode (native host kernel, computed ON the loop):
-            # there is no pipeline to keep busy, so amortization comes
-            # from a short scheduled window — but only when the observed
-            # rate says more arrivals will actually land inside it;
-            # sparse traffic dispatches immediately
-            if self._flush_handle is None:
-                gap = self._arrival_gap_s()
-                window = self._busy_window_s(now)
-                if gap is None or gap >= window or window <= 0.0:
-                    self._flush(loop)
-                else:
-                    self._flush_handle = loop.call_later(
-                        window, self._flush, loop
-                    )
         elif self._total_inflight() < max(
             1, self._n_effective(self._n_replicas())
         ):
@@ -1522,9 +1503,9 @@ class AsyncMicroBatcher:
         )
         try:
             # replica kwarg only when there's a choice — single-replica
-            # engines (fakes, native host kernel) keep the bare
-            # signature; deadline and trace only when the engine
-            # declared them (mirroring the threaded twin)
+            # engines (fakes) keep the bare signature; deadline and
+            # trace only when the engine declared them (mirroring the
+            # threaded twin)
             kwargs = {}
             if n > 1:
                 kwargs["replica"] = idx
@@ -1550,33 +1531,6 @@ class AsyncMicroBatcher:
         self._dispatch_times.setdefault(
             idx, collections.deque()
         ).append(t_dispatch)
-        if getattr(self.engine, "host_kernel_active", False) and not any(
-            p.deadline is not None for p in batch
-        ):
-            # inline: the native kernel is a sub-ms GIL-releasing C call —
-            # running it here costs less than one thread handoff, and the
-            # whole request lifecycle stays on a single thread. NOT taken
-            # when any request carries a deadline: inline blocks the LOOP,
-            # so a genuinely stalled kernel would freeze the expiry timers
-            # (and every other connection) for exactly as long as the
-            # stall — the executor hop keeps the loop free to degrade
-            # on time.
-            try:
-                outcome = (finish(), None)
-            except Exception as exc:
-                outcome = (None, exc)
-            if self.lag_monitor is not None:
-                # direct stall note: this finish() just blocked the loop
-                # for exactly this long — report it NOW (the drift tick
-                # only sees it one loop iteration later), so a 200 ms
-                # kernel stall escalates admission before the next
-                # request is even parsed
-                self.lag_monitor.note(time.perf_counter() - t_dispatch)
-            self._resolve(
-                batch, outcome, t_dispatch, loop, idx, finish, t_slot, btrace
-            )
-            return
-
         def run_finish():
             try:
                 return finish(), None

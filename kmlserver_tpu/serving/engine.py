@@ -92,65 +92,6 @@ from ..ops.serve import recommend_batch
 logger = logging.getLogger("kmlserver_tpu.serving")
 
 
-_HOST_STAGING_SAFE: bool | None = None
-
-
-def _staging_buffer(shape: tuple[int, int]) -> np.ndarray:
-    """int32 staging buffer at an address ≡ 4 (mod 64) — deliberately NOT
-    64-byte aligned. jax's CPU client ZERO-COPIES ``device_put`` of a
-    host array that meets XLA's alignment requirement (re-observed on
-    jax 0.9.0: 64-byte-aligned int32 buffers alias, anything less
-    copies), and an aliased device array turns staging-buffer reuse into
-    answer corruption: the next same-shape dispatch refills the buffer
-    the in-flight computation is still reading. ``np.empty`` leaves
-    alignment to allocator luck — page-aligned for large buffers, so
-    exactly the big batches aliased — which made the corruption a
-    once-in-a-while flake instead of a loud failure. Offsetting to
-    4 (mod 64) defeats every power-of-two alignment gate ≥ 8 while
-    keeping the 4-byte alignment the int32 view needs, so device_put
-    must copy; :func:`_staging_is_safe` probes THIS allocator so a
-    future jax that aliases anyway disables reuse instead of corrupting."""
-    n_bytes = int(np.prod(shape)) * 4
-    raw = np.empty(n_bytes + 68, dtype=np.uint8)
-    off = (4 - raw.ctypes.data) % 64
-    return raw[off:off + n_bytes].view(np.int32).reshape(shape)
-
-
-def _staging_is_safe() -> bool:
-    """True when reusing one host staging buffer across dispatches is
-    provably safe: the buffer is refilled while earlier transfers may
-    still be in flight, so ``jax.device_put`` must have fully consumed it
-    by the time it returns. Only the CPU backend qualifies — its
-    transfers are synchronous COPIES for the misaligned buffers
-    :func:`_staging_buffer` produces, and the probe below confirms the
-    copy against that same allocator at a realistic size (``jnp.asarray``
-    is zero-copy there, which is exactly why the staging path goes
-    through ``device_put``; a sufficiently ALIGNED buffer is zero-copied
-    even by device_put — the hazard the allocator's deliberate
-    misalignment defeats). On accelerators the transfer may complete
-    asynchronously AFTER device_put returns — a probe passing proves
-    nothing about a larger buffer still in flight — so reuse stays off
-    and each dispatch allocates fresh."""
-    global _HOST_STAGING_SAFE
-    if _HOST_STAGING_SAFE is None:
-        if jax.default_backend() != "cpu":
-            _HOST_STAGING_SAFE = False
-            return False
-        probe = _staging_buffer((2, 64))
-        probe.fill(-1)
-        on_device = jax.device_put(probe)
-        probe[0, 0] = 123
-        # kmls-verify: allow[hotpath] — one 512-byte probe, cached for the
-        # process lifetime; steady-state dispatches never reach this sync
-        _HOST_STAGING_SAFE = int(np.asarray(on_device)[0, 0]) == -1
-        if not _HOST_STAGING_SAFE:
-            logger.warning(
-                "device_put aliases host buffers on this backend; "
-                "staging-buffer reuse disabled (fresh allocation per batch)"
-            )
-    return _HOST_STAGING_SAFE
-
-
 def _start_host_copies(*arrays) -> None:
     """Start each device result's copy to the host without waiting for
     it: the copies queue behind their programs on the device's stream,
@@ -158,6 +99,22 @@ def _start_host_copies(*arrays) -> None:
     is done or under way, and does not start one and sit it out."""
     for a in arrays:
         a.copy_to_host_async()
+
+
+def _stamp_hedge_outcome(finish, remote):
+    """The mesh layout's ``finish``: run the engine's one ``finish()`` and
+    stamp the coordinator's hedge outcome (ISSUE 18) on the callable the
+    batcher holds, whose request spans read ``_kmls_hedge``. Kept apart so
+    that ``finish()`` does not name itself: a closure that does is freed
+    by the cycle collector, not when its batch resolves, and the batch's
+    device results with it."""
+
+    def stamped() -> list[tuple[list[str], str]]:
+        out = finish()
+        stamped._kmls_hedge = getattr(remote, "hedge_outcome", None)
+        return out
+
+    return stamped
 
 
 def blend_candidates(
@@ -207,8 +164,8 @@ class RuleBundle:
     rule_confs: jax.Array  # device, float32 (V, K)
     known_mask: np.ndarray  # host, bool (V,) — rule-dict key membership
     model_token: str  # token value when loaded
-    # the device this replica's tensors are committed to (None = host-
-    # kernel bundle or default placement) and the generation counter the
+    # the device this replica's tensors are committed to (None = default
+    # placement) and the generation counter the
     # recommendation cache keys on — monotonic per engine, bumped on every
     # successful publication, so a cache entry can never outlive its rules
     device: object = None
@@ -217,13 +174,6 @@ class RuleBundle:
     # serving thread checks membership so an unwarmed dispatch (a compile
     # on the hot path) is counted and logged, never silent
     warmed_shapes: set = dataclasses.field(default_factory=set)
-    # host copies of the rule tensors, present ONLY when the native CPU
-    # serving kernel is active (serving/native_serve.py): XLA:CPU lowers
-    # the scatter-max to ~190ns/update, which IS the serving tail on a
-    # CPU pod; the native kernel does identical updates at ~2ns. None on
-    # accelerator backends — their lookups stay on the device.
-    host_rule_ids: np.ndarray | None = None
-    host_rule_confs: np.ndarray | None = None
     # ---- model layout (KMLS_MODEL_LAYOUT, parallel/layout.py) ----
     # "replicated": this bundle is one full-tensor replica on `device`.
     # "sharded": ONE logical bundle whose rule tensors are vocab-sharded
@@ -267,8 +217,8 @@ class RuleBundle:
     emb_index: dict[str, int] | None = None
     # (batch, length) shapes the embedding kernel was compiled for at
     # publication — same zero-compiles-post-publish discipline as
-    # warmed_shapes, tracked separately because the native-rule-kernel
-    # bundle has no rule shapes to warm but still jits the embed kernel
+    # warmed_shapes, tracked separately because a delta apply carries
+    # the factors (and their warmed shapes) over to re-warmed rule tensors
     emb_warmed_shapes: set = dataclasses.field(default_factory=set)
 
 
@@ -382,12 +332,6 @@ class RecommendEngine:
         # freshness-age surface /readyz and kmls_artifact_age_seconds
         # report; empty before the first load
         self._artifact_written_at: dict[str, float] = {}
-        # reusable host staging buffers, one per padded seed shape: steady
-        # state does no fresh host allocation per batch. Guarded by the
-        # lock (fill + transfer must not interleave across threads) and by
-        # _staging_is_safe() (device_put must copy).
-        self._staging: dict[tuple[int, int], np.ndarray] = {}
-        self._staging_lock = threading.Lock()
         # ---- pod-spanning serve mesh (ISSUE 16) ----
         # armed when KMLS_SERVE_GANG_COORDINATOR + SIZE>1 name a gang this
         # process belongs to; the worker serves THIS rank's partial top-k
@@ -806,9 +750,7 @@ class RecommendEngine:
         self, rec_path: str, npz_path: str, use_npz: bool = True
     ) -> list[RuleBundle]:
         """Load the rule tensors once, then replicate them onto every
-        serving device (``device_put`` per device) — or onto the host when
-        the native CPU kernel is active (one replica: the host kernel has
-        no per-device state to parallelize over). Host-side state (vocab,
+        serving device (``device_put`` per device). Host-side state (vocab,
         index, known mask) is shared across the set."""
         token = self._read_token() or ""
         loaded = None
@@ -933,21 +875,6 @@ class RecommendEngine:
                     token, devs,
                 )
             ]
-        if self._use_native_serve():
-            # rule rows are trailing-padded (emission writes the top-k
-            # descending, then -1 fill) — the native kernel's early-break
-            # contract; ascontiguousarray guards a sliced npz view
-            host_ids = np.ascontiguousarray(rule_ids, dtype=np.int32)
-            host_confs = np.ascontiguousarray(rule_confs, dtype=np.float32)
-            # jnp.asarray is zero-copy on the CPU backend, so keeping the
-            # "device" tensors next to the host copies costs no memory
-            return [RuleBundle(
-                vocab=vocab, index=index,
-                rule_ids=jnp.asarray(host_ids),
-                rule_confs=jnp.asarray(host_confs),
-                known_mask=known_mask, model_token=token,
-                host_rule_ids=host_ids, host_rule_confs=host_confs,
-            )]
         ids_arr = jnp.asarray(rule_ids)
         confs_arr = jnp.asarray(rule_confs)
         return [
@@ -1180,27 +1107,13 @@ class RecommendEngine:
         bundle = self.bundle
         return bundle.n_shards if bundle is not None else 1
 
-    def _use_native_serve(self) -> bool:
-        """Native host kernel iff the backend is CPU (an accelerator's
-        lookups belong on the accelerator), the knob allows it, and the
-        .so is loadable."""
-        if not self.cfg.native_serve or jax.default_backend() != "cpu":
-            return False
-        from . import native_serve
-
-        return native_serve.available()
-
     def _warmup(self, bundle: RuleBundle) -> None:
         """Compile EVERY (batch-bucket, length-bucket) shape before the
         bundle publishes: no request — whatever its batch size — ever pays
         a compile or a 32-wide kernel for a batch of 3. Covers BOTH model
-        families: the rule max-merge kernel (skipped for the native host
-        kernel, which never compiles) and, when embeddings are attached,
-        the cosine top-k kernel over the same bucket grid."""
-        warm_rules = bundle.host_rule_ids is None
+        families: the rule max-merge kernel and, when embeddings are
+        attached, the cosine top-k kernel over the same bucket grid."""
         warm_emb = bundle.emb_factors is not None
-        if not warm_rules and not warm_emb:
-            return  # native host kernel, no embeddings: nothing compiles
         # sharded layout warms ITS kernel (per-shard lookup + cross-device
         # max-merge) over the same bucket grid — every sharded bucket is
         # compiled before publication, same zero-compile contract. Mesh
@@ -1208,11 +1121,8 @@ class RecommendEngine:
         # local slab partial (served to peers AND dispatched locally) and
         # the rank-stacked merge — every gang member compiles both for
         # every bucket before its bundle publishes.
-        warm_mesh = warm_rules and bundle.layout == "mesh"
-        kernel = (
-            (bundle.shard_kernel or self._kernel)
-            if warm_rules and not warm_mesh else None
-        )
+        warm_mesh = bundle.layout == "mesh"
+        kernel = bundle.shard_kernel or self._kernel
         if warm_mesh:
             from ..ops.serve import merge_partial_topk, shard_partial_topk
         for length in self._len_buckets():
@@ -1243,12 +1153,11 @@ class RecommendEngine:
                             v=bundle.mesh_v, k_best=kb,
                         )
                     )
-                    bundle.warmed_shapes.add((batch, length))
-                elif warm_rules:
+                else:
                     jax.block_until_ready(
                         kernel(bundle.rule_ids, bundle.rule_confs, rule_seeds)
                     )
-                    bundle.warmed_shapes.add((batch, length))
+                bundle.warmed_shapes.add((batch, length))
                 if warm_emb:
                     # the embedding kernel dispatches with _dispatch_embed's
                     # placement (bundle.device; default placement in the
@@ -1288,9 +1197,7 @@ class RecommendEngine:
         length = self._len_buckets()[-1]
         touched = 0
         for bundle in replicas:
-            warm_rules = (
-                bundle.host_rule_ids is None and bundle.layout != "mesh"
-            )
+            warm_rules = bundle.layout != "mesh"
             warm_emb = bundle.emb_factors is not None
             if not warm_rules and not warm_emb:
                 continue
@@ -1360,15 +1267,6 @@ class RecommendEngine:
         hybrid merge path is live)."""
         bundle = self.bundle
         return bundle is not None and bundle.emb_factors is not None
-
-    @property
-    def host_kernel_active(self) -> bool:
-        """True when the current bundle serves through the native host
-        kernel — its ``finish()`` is a sub-millisecond, GIL-releasing C
-        call, safe to run inline on an event loop (the async batcher uses
-        this to skip the executor hop entirely)."""
-        bundle = self.bundle
-        return bundle is not None and bundle.host_rule_ids is not None
 
     def reload_if_required(self) -> None:
         """Reference: reload when stale or never fully loaded
@@ -1457,22 +1355,21 @@ class RecommendEngine:
                 bundle.device
             ),
         )
-        if bundle.host_rule_ids is None:
-            if bundle.layout == "mesh":
-                # the gang dispatch composes the kernel's two factored
-                # halves — watch both jit caches under one name (the
-                # snapshot sums, so any post-publish compile on either
-                # half reads as serving-path compile growth)
-                from ..ops.serve import merge_partial_topk, shard_partial_topk
+        if bundle.layout == "mesh":
+            # the gang dispatch composes the kernel's two factored
+            # halves — watch both jit caches under one name (the
+            # snapshot sums, so any post-publish compile on either
+            # half reads as serving-path compile growth)
+            from ..ops.serve import merge_partial_topk, shard_partial_topk
 
-                cm.watch_compiles("serve_mesh", shard_partial_topk)
-                cm.watch_compiles("serve_mesh_merge", merge_partial_topk)
-            elif bundle.shard_kernel is not None:
-                cm.watch_compiles("serve_sharded", bundle.shard_kernel)
-            else:
-                # the engine wraps the jitted fn in a partial(k_best=);
-                # the jit cache lives on the underlying function
-                cm.watch_compiles("serve_rules", self._kernel.func)
+            cm.watch_compiles("serve_mesh", shard_partial_topk)
+            cm.watch_compiles("serve_mesh_merge", merge_partial_topk)
+        elif bundle.shard_kernel is not None:
+            cm.watch_compiles("serve_sharded", bundle.shard_kernel)
+        else:
+            # the engine wraps the jitted fn in a partial(k_best=);
+            # the jit cache lives on the underlying function
+            cm.watch_compiles("serve_rules", self._kernel.func)
         if bundle.emb_factors is not None:
             cm.watch_compiles("embed_topk", embed_topk)
         cm.mark_published()
@@ -1697,9 +1594,7 @@ class RecommendEngine:
     ) -> tuple[np.ndarray, int]:
         """Membership-filter each seed set into its -1-padded row of
         ``arr`` → (per-row any-known-seed mask, how many slots of the
-        array hold a seed). The ONE copy of the seed filtering rule —
-        the native and device paths both go through it, which is what
-        keeps them bit-identical."""
+        array hold a seed). The ONE copy of the seed filtering rule."""
         for r, seeds in enumerate(seed_sets):
             ids = [
                 bundle.index[s]
@@ -1714,8 +1609,9 @@ class RecommendEngine:
         """The staged array is filled and on its way: count its slots
         (always on: two integer adds a batch) and, on a traced batch,
         close the ``stage`` span and say what was staged."""
-        self.seed_slots_real += n_real
-        self.seed_slots_padded += arr.size - n_real
+        with self._dispatch_lock:
+            self.seed_slots_real += n_real
+            self.seed_slots_padded += arr.size - n_real
         if trace is not None:
             trace.lap("stage")
             trace.attrs.update(
@@ -1725,46 +1621,35 @@ class RecommendEngine:
     def _stage_seeds(
         self, bundle: RuleBundle, seed_sets: list[list[str]],
         rows: int, length: int, trace=None,
-    ) -> tuple[jax.Array, np.ndarray]:
+    ) -> tuple[np.ndarray, jax.Array, np.ndarray]:
         """Fill the padded (rows, length) seed-index array and transfer it
-        → (device seed array, per-row any-known-seed mask, host). Reuses
-        one staging buffer per shape when the backend's ``device_put``
-        copies (probed); the known-row mask is snapshotted BEFORE the
-        buffer can be refilled by the next dispatch. The transfer targets
-        the bundle's own device, so a replica's dispatch runs on the
-        replica's chip — the staging buffer is shared across replicas
-        (fill + transfer are serialized under the lock either way).
-        ``trace`` is the batch's trace (None = untraced): its ``stage``
-        span ends where the transfer has been issued."""
+        → (host seed array, device seed array, per-row any-known-seed
+        mask). The host array is a fresh one per dispatch and is never
+        refilled, so a transfer still under way (or, in the mesh layout,
+        a peer fan-out still serializing it) cannot see another batch's
+        seeds. The transfer targets the bundle's own placement (its
+        device; the mesh-replicated sharding in the sharded layout), so a
+        replica's dispatch runs on the replica's chip. ``trace`` is the
+        batch's trace (None = untraced): its ``stage`` span ends where
+        the transfer has been issued."""
         shape = (rows, length)
-        with self._staging_lock:
-            if _staging_is_safe():
-                arr = self._staging.get(shape)
-                if arr is None:
-                    # _staging_buffer, not np.empty: a 64-byte-aligned
-                    # buffer would be zero-copied (aliased) by device_put
-                    arr = self._staging.setdefault(
-                        shape, _staging_buffer(shape)
-                    )
-                arr.fill(-1)
-            else:
-                arr = np.full(shape, -1, dtype=np.int32)
-            known_rows, n_real = self._fill_seed_rows(
-                bundle, seed_sets, arr, length
-            )
-            if bundle.n_shards > 1 and bundle.shard_size > 0:
-                # per-shard dispatch accounting: which vocab shard's rows
-                # this batch's seed ids actually hit (host integer math on
-                # the already-staged buffer — no device sync)
-                hit = arr[arr >= 0]
-                if hit.size:
-                    self._note_shard_dispatch(np.bincount(
-                        hit // bundle.shard_size, minlength=bundle.n_shards
-                    ))
-            seeds_dev = jax.device_put(
-                arr, bundle.seed_sharding or bundle.device
-            )
-            self._note_staged(arr, n_real, trace)
+        arr = np.full(shape, -1, dtype=np.int32)
+        known_rows, n_real = self._fill_seed_rows(
+            bundle, seed_sets, arr, length
+        )
+        if bundle.n_shards > 1 and bundle.shard_size > 0:
+            # per-shard dispatch accounting: which vocab shard's rows
+            # this batch's seed ids actually hit (host integer math on
+            # the already-staged array — no device sync)
+            hit = arr[arr >= 0]
+            if hit.size:
+                self._note_shard_dispatch(np.bincount(
+                    hit // bundle.shard_size, minlength=bundle.n_shards
+                ))
+        seeds_dev = jax.device_put(
+            arr, bundle.seed_sharding or bundle.device
+        )
+        self._note_staged(arr, n_real, trace)
         if shape not in bundle.warmed_shapes:
             # a compile is landing on the serving path — count it loudly
             self.unwarmed_dispatches += 1
@@ -1773,7 +1658,94 @@ class RecommendEngine:
                 "serving path); warmed buckets: batches %s x lengths %s",
                 shape, self._batch_buckets(), self._len_buckets(),
             )
-        return seeds_dev, known_rows
+        return arr, seeds_dev, known_rows
+
+    def _dispatch_rules(
+        self, bundle: RuleBundle, arr: np.ndarray, seeds_dev: jax.Array,
+        deadline: float | None,
+    ):
+        """Enqueue the rule lookup for a staged batch: the one step of a
+        dispatch that a layout varies → ``(device results, pick_up,
+        remote)``. ``device results`` are the arrays whose copies to the
+        host the caller starts; ``pick_up()`` blocks and returns the
+        batch's host ``(top ids, top confidences)``; ``remote`` is the
+        mesh coordinator's peer-fetch handle (its ``dropped`` ranks and
+        ``hedge_outcome`` are read after the pick-up), None in the local
+        layouts.
+
+        Local layouts (replicated, sharded) enqueue the bundle's kernel —
+        the vocab-sharded lookup resolved at publication, or the
+        per-replica one — and pick its two results up as they are.
+
+        The mesh layout fans the host array to every gang peer FIRST
+        (socket I/O overlaps the local device work), enqueues this
+        rank's slab partial, and at pick-up stacks the rank-ordered
+        partials and runs the merge — the same two functions the
+        single-process shard_map kernel composes, so the answer is
+        bit-identical by construction. A dead gang member surfaces as
+        :class:`~.mesh.MeshShardUnavailable` out of ``pick_up()``: the
+        app maps it to the gang-degraded signal (503 +
+        ``X-KMLS-Mesh-Unavailable`` under fleet routing) and the routed
+        client spills the request to the next ring peer."""
+        if bundle.layout != "mesh":
+            top_ids, top_confs = (bundle.shard_kernel or self._kernel)(
+                bundle.rule_ids, bundle.rule_confs, seeds_dev
+            )
+
+            def pick_up_local() -> tuple[np.ndarray, np.ndarray]:
+                # waits for the copies the caller started at dispatch
+                return np.asarray(top_ids), np.asarray(top_confs)
+
+            return (top_ids, top_confs), pick_up_local, None
+
+        from ..ops.serve import merge_partial_topk, shard_partial_topk
+
+        kb = self.cfg.k_best_tracks
+        # deadline propagation: stamp the REMAINING budget on the peer
+        # frames (computed now — staging time already spent), so a
+        # backed-up worker sheds expired partials instead of computing
+        # results nobody will wait for
+        budget_ms = None
+        if deadline is not None:
+            budget_ms = max(0.0, (deadline - time.perf_counter()) * 1e3)
+        remote = self.mesh_coordinator.fetch_partials(
+            arr, bundle.model_token or "", budget_ms=budget_ms
+        )
+        part_ids, part_confs = shard_partial_topk(
+            bundle.rule_ids, bundle.rule_confs, seeds_dev, bundle.mesh_lo,
+            v=bundle.mesh_v, k_best=kb,
+        )
+
+        def pick_up_mesh() -> tuple[np.ndarray, np.ndarray]:
+            local_ids = np.asarray(part_ids)  # blocks on the device
+            local_confs = np.asarray(part_confs)
+            # blocks on the slowest peer; raises MeshShardUnavailable
+            # for the first rank the gang cannot serve through
+            parts = remote()
+            stack_ids = np.empty(
+                (bundle.n_shards,) + local_ids.shape, dtype=np.int32
+            )
+            stack_confs = np.empty(
+                (bundle.n_shards,) + local_confs.shape, dtype=np.float32
+            )
+            stack_ids[bundle.gang_rank] = local_ids
+            stack_confs[bundle.gang_rank] = local_confs
+            for rank, (ids_r, confs_r) in parts.items():
+                stack_ids[rank] = ids_r
+                stack_confs[rank] = confs_r
+            # hedged straggler-drop / deadline-shed (ISSUE 18): ranks the
+            # coordinator dropped contribute NOTHING to the merge — their
+            # slots get -inf confidences so the max-merge never selects
+            # them (the caller marks every answer degraded)
+            for rank in getattr(remote, "dropped", None) or ():
+                stack_ids[rank] = 0
+                stack_confs[rank] = np.float32(-np.inf)
+            merged_ids, merged_confs = merge_partial_topk(
+                stack_ids, stack_confs, v=bundle.mesh_v, k_best=kb
+            )
+            return np.asarray(merged_ids), np.asarray(merged_confs)
+
+        return (part_ids, part_confs), pick_up_mesh, remote
 
     # ---------- second model family: embedding dispatch + hybrid merge ----
 
@@ -1786,10 +1758,10 @@ class RecommendEngine:
         bundle carries no factors / the operator pinned rules-only. Runs
         on the DISPATCH path (no host syncs — jax dispatch is async), so
         its fill, transfer and enqueue lie inside the batch's ``dispatch``
-        span; the caller's ``finish()`` converts the device results (the
-        device path starts their copies first). The (n_rows,
-        length) shape must come from the warmed bucket grid — an unwarmed
-        shape is counted and logged exactly like the rule kernel's."""
+        span; the caller starts the device results' copies and its
+        ``finish()`` picks them up. The (n_rows, length) shape must come
+        from the warmed bucket grid — an unwarmed shape is counted and
+        logged exactly like the rule kernel's."""
         if bundle.emb_factors is None or self.cfg.hybrid_mode == "rules":
             return None
         arr = np.full((n_rows, length), -1, dtype=np.int32)
@@ -1863,7 +1835,7 @@ class RecommendEngine:
 
     def recommend(self, seed_tracks: list[str]) -> tuple[list[str], str]:
         """→ (songs, source), source ∈ {"rules", "embed", "hybrid",
-        "fallback", "empty"}.
+        "fallback", "empty"}: a batch of one.
 
         Mirrors rest_api/app/main.py:224-254, including: degraded fallback
         while rules are loading (:225-228), membership filter (:235),
@@ -1871,61 +1843,7 @@ class RecommendEngine:
         (:236-238 — the reference knows only rules), and results that may
         legitimately be empty when all known seeds have empty rows.
         """
-        bundle = self.bundle
-        if bundle is None:
-            # degrade + nudge a reload, like the reference's late-load path
-            threading.Thread(target=self.reload_if_required, daemon=True).start()
-            return self.static_recommendation(seed_tracks), "fallback"
-        if bundle.layout == "mesh":
-            # a mesh answer needs the gang fan-out either way — route
-            # through the batched dispatch/finish pair (per-request
-            # semantics are identical; MeshShardUnavailable propagates)
-            return self._mesh_recommend_async(bundle, [seed_tracks], 0)()[0]
-        known_ids = [
-            bundle.index[s]
-            for s in seed_tracks
-            if s in bundle.index and bundle.known_mask[bundle.index[s]]
-        ]
-        # dispatch the embedding kernel FIRST (async — the known mask is
-        # host-computed at dispatch, no sync), then the rule kernel, and
-        # only convert results after both are in flight: the two device
-        # calls overlap instead of serializing, mirroring the batched
-        # path's dispatch-both-then-finish discipline
-        emb = self._dispatch_embed(
-            bundle, [seed_tracks], 1,
-            self._bucket_len(max(len(seed_tracks), 1)),
-        )
-        if not known_ids and (emb is None or not emb[2][0]):
-            logger.info("no seed of %d known; static fallback", len(seed_tracks))
-            return self.static_recommendation(seed_tracks), "fallback"
-        ids = confs = None
-        if known_ids:
-            known_ids = known_ids[: self.cfg.max_seed_tracks]
-            if bundle.host_rule_ids is not None:
-                from . import native_serve
-
-                arr = np.full((1, max(len(known_ids), 1)), -1, dtype=np.int32)
-                arr[0, : len(known_ids)] = known_ids
-                top_ids, top_confs = native_serve.serve_topk(
-                    bundle.host_rule_ids, bundle.host_rule_confs, arr,
-                    self.cfg.k_best_tracks,
-                )
-                ids, confs = top_ids[0], top_confs[0]
-            else:
-                length = self._bucket_len(len(known_ids))
-                seeds_dev, _ = self._stage_seeds(bundle, [seed_tracks], 1, length)
-                top_ids, top_confs = (
-                    bundle.shard_kernel or self._kernel
-                )(bundle.rule_ids, bundle.rule_confs, seeds_dev)
-                ids = np.asarray(top_ids[0])
-                confs = np.asarray(top_confs[0])
-        self._note_dispatch(0)
-        emb_row = None
-        if emb is not None:
-            emb_row = (np.asarray(emb[0])[0], np.asarray(emb[1])[0], emb[2][0])
-        return self._compose_answer(
-            bundle, seed_tracks, bool(known_ids), ids, confs, emb_row
-        )
+        return self.recommend_many_async([seed_tracks])()[0]
 
     def recommend_many_async(
         self, seed_sets: list[list[str]], replica: int | None = None,
@@ -1935,42 +1853,47 @@ class RecommendEngine:
         immediately — jax dispatch is asynchronous) and FINISH (a zero-arg
         callable that blocks on the results and builds the responses).
 
-        On the device path every result's device → host copy is started
-        at dispatch (:func:`_start_host_copies`), behind its program on
-        the device's stream, and ``finish()`` picks the results up in
-        program order, the rule pair and then the embedding pair: a
-        pick-up finds its copy done or under way, and no longer starts
-        one and sits it out before the next can begin.
+        One skeleton for every layout: stage the rule seeds (one fresh
+        host array, one transfer), enqueue the rule lookup
+        (:meth:`_dispatch_rules` — the only step a layout varies), stage
+        and enqueue the embedding lookup (:meth:`_dispatch_embed`), and
+        start every result's device → host copy
+        (:func:`_start_host_copies`), behind its program on the device's
+        stream. ``finish()`` picks the results up in program order, the
+        rule pair and then the embedding pair: a pick-up finds its copy
+        done or under way, and does not start one and sit it out before
+        the next can begin.
 
         The split lets the micro-batcher pipeline device calls: a
         dispatch-block-respond loop caps throughput at batch_size over
         the blocked call's latency; overlapping the next dispatch with
         the previous batch's device time and transfer removes that
-        ceiling. Per-request semantics identical to :meth:`recommend`.
+        ceiling. :meth:`recommend` is this path with a batch of one.
 
         ``replica`` selects which device replica executes the batch (the
-        least-loaded dispatcher in serving/batcher.py passes it); None —
-        or the native host kernel — uses the primary. Concurrent batches
-        on DIFFERENT replicas run on different devices instead of
-        serializing on one in-order execution queue.
+        least-loaded dispatcher in serving/batcher.py passes it); None
+        uses the primary. Concurrent batches on DIFFERENT replicas run
+        on different devices instead of serializing on one in-order
+        execution queue.
 
         ``deadline`` (perf_counter seconds, the batcher's earliest
         pending deadline) propagates across the mesh as each partial
         frame's remaining-budget field — a gang peer sheds work that
         expired in transit instead of computing it (ISSUE 18). The
-        local device paths ignore it (their budget is enforced at the
+        local layouts ignore it (their budget is enforced at the
         app layer, as before).
 
         ``trace`` is the batch's own trace (``SpanRecorder.begin_batch``;
         None = untraced, and then each site below is one is-None check).
-        All four variants — fallback, native, device, mesh — record the
-        same spans on it with ``TraceContext.lap``, each where the work
-        happens: ``stage`` (:meth:`_note_staged`: the rule seeds' fill
-        and transfer) and ``dispatch`` (the rule enqueue, the embedding
-        seeds' fill, transfer and enqueue, and on the device path the
-        starting of the results' copies) here; ``fetch_rules`` (the rule
-        pair's pick-up), ``fetch_embed`` (the embedding pair's) and
-        ``compose`` in ``finish()``.
+        Every layout records the same spans on it with
+        ``TraceContext.lap``, each where the work happens: ``stage``
+        (:meth:`_note_staged`: the rule seeds' fill and transfer) and
+        ``dispatch`` (the rule enqueue — in the mesh layout the peer
+        fan-out too — the embedding seeds' fill, transfer and enqueue,
+        and the starting of the results' copies) here; ``fetch_rules``
+        (the rule pair's pick-up), ``fetch_embed`` (the embedding
+        pair's) and ``compose`` in ``finish()``. The fallback (nothing
+        published yet) records ``compose`` alone.
         What lies between ``dispatch`` and ``finish()`` starting is the
         batcher's hop to its completion thread, and belongs to no span."""
         if trace is not None:
@@ -1981,7 +1904,7 @@ class RecommendEngine:
             idx = replica % len(replicas)
         bundle = replicas[idx] if replicas else self.bundle
         if bundle is None:
-            # same late-load nudge as the single-request path
+            # degrade + nudge a reload, like the reference's late-load path
             threading.Thread(target=self.reload_if_required, daemon=True).start()
 
             def finish_fallback() -> list[tuple[list[str], str]]:
@@ -1996,105 +1919,6 @@ class RecommendEngine:
                 return out
 
             return finish_fallback
-        if bundle.layout == "mesh":
-            return self._mesh_recommend_async(
-                bundle, seed_sets, idx, deadline=deadline, trace=trace
-            )
-        if bundle.host_rule_ids is not None:
-            # native host kernel: no compile, so no shape bucketing — the
-            # seed array is exact-sized, built fresh (it must survive
-            # until finish() runs on the completion thread, so it can't
-            # share the device path's reusable staging buffers)
-            length = min(
-                max((len(s) for s in seed_sets), default=1),
-                self.cfg.max_seed_tracks,
-            )
-            arr = np.full((len(seed_sets), length), -1, dtype=np.int32)
-            known_rows, n_real = self._fill_seed_rows(
-                bundle, seed_sets, arr, length
-            )
-            self._note_staged(arr, n_real, trace)
-            # the embedding kernel IS jitted even next to the native rule
-            # kernel, so ITS seed array rides the warmed bucket grid
-            emb = self._dispatch_embed(
-                bundle, seed_sets,
-                self._bucket_batch(max(len(seed_sets), 1)),
-                self._bucket_len(
-                    max((len(s) for s in seed_sets), default=1)
-                ),
-            )
-            self._note_dispatch(idx)
-            if trace is not None:
-                trace.lap("dispatch")
-
-            cm = self.cost_model
-
-            def finish_native() -> list[tuple[list[str], str]]:
-                from . import native_serve
-
-                if trace is not None:
-                    trace.skip()
-                # chaos hook ON the completion path — where a real kernel
-                # failure or stall surfaces (delay faults sleep here, fail
-                # faults raise into the batcher's circuit breaker)
-                faults.fire("replica.kernel", replica=idx)
-                t_kernel = time.perf_counter() if cm is not None else 0.0
-                # the ctypes call releases the GIL for the whole batch
-                host_ids, host_confs = native_serve.serve_topk(
-                    bundle.host_rule_ids, bundle.host_rule_confs, arr,
-                    self.cfg.k_best_tracks,
-                )
-                if cm is not None:
-                    # same algorithm as serve_rules, on the host — the
-                    # synchronous call IS its own fence
-                    cm.observe_kernel(
-                        "serve_native",
-                        time.perf_counter() - t_kernel,
-                        b=len(seed_sets), l=length,
-                        k_max=bundle.host_rule_ids.shape[1],
-                        v=len(bundle.vocab), k_best=self.cfg.k_best_tracks,
-                    )
-                if trace is not None:
-                    # the rule lookup itself here: the host kernel's call
-                    trace.lap("fetch_rules")
-                emb_host = None
-                if emb is not None:
-                    # the embed kernel ran on the DEVICE while the native
-                    # kernel ran on the host — this fence measures only
-                    # the residual wait, so the embed attribution here is
-                    # a floor on device time (rates read high; the MFU
-                    # cap keeps the headline honest, and the jitted-path
-                    # attribution above is the one benches measure)
-                    t_emb = time.perf_counter() if cm is not None else 0.0
-                    emb_host = (np.asarray(emb[0]), np.asarray(emb[1]), emb[2])
-                    if cm is not None:
-                        cm.observe_kernel(
-                            "embed_topk",
-                            time.perf_counter() - t_emb,
-                            b=self._bucket_batch(max(len(seed_sets), 1)),
-                            l=self._bucket_len(
-                                max((len(s) for s in seed_sets), default=1)
-                            ),
-                            v=len(bundle.emb_vocab or ()),
-                            r=int(bundle.emb_factors.shape[0]),
-                            k_best=self.cfg.k_best_tracks,
-                        )
-                    if trace is not None:
-                        trace.lap("fetch_embed")
-                out: list[tuple[list[str], str]] = []
-                for r, seeds in enumerate(seed_sets):
-                    emb_row = None if emb_host is None else (
-                        emb_host[0][r], emb_host[1][r], emb_host[2][r]
-                    )
-                    out.append(self._compose_answer(
-                        bundle, seeds, bool(known_rows[r]),
-                        host_ids[r], host_confs[r], emb_row,
-                    ))
-                if trace is not None:
-                    trace.lap("compose")
-                return out
-
-            return finish_native
 
         length = self._bucket_len(
             max((len(s) for s in seed_sets), default=1)
@@ -2105,16 +1929,13 @@ class RecommendEngine:
         # made a batch of 3 pay a 32-row kernel — ~8x the work on the
         # scatter/top-k. Every bucket is pre-warmed at bundle publish.
         n_rows = self._bucket_batch(max(len(seed_sets), 1))
-        seeds_dev, known_rows = self._stage_seeds(
+        arr, seeds_dev, known_rows = self._stage_seeds(
             bundle, seed_sets, n_rows, length, trace
         )
-        # sharded layout dispatches the vocab-sharded lookup (per-shard
-        # gather/top-k + cross-device max-merge) resolved at publication;
-        # replicated keeps the per-replica kernel
         cm = self.cost_model
         t_kernel = time.perf_counter() if cm is not None else 0.0
-        top_ids, top_confs = (bundle.shard_kernel or self._kernel)(
-            bundle.rule_ids, bundle.rule_confs, seeds_dev
+        rule_results, pick_up_rules, remote = self._dispatch_rules(
+            bundle, arr, seeds_dev, deadline
         )
         # second model family: the embedding lookup dispatches alongside
         # the rule kernel onto the same replica device — both async, both
@@ -2122,7 +1943,7 @@ class RecommendEngine:
         emb = self._dispatch_embed(bundle, seed_sets, n_rows, length)
         # every result starts for the host now, behind its program, so
         # that finish() does not start four copies one after the other
-        _start_host_copies(top_ids, top_confs, *(emb[:2] if emb else ()))
+        _start_host_copies(*rule_results, *(emb[:2] if emb else ()))
         self._note_dispatch(idx)
         if trace is not None:
             trace.lap("dispatch")
@@ -2130,28 +1951,32 @@ class RecommendEngine:
         def finish() -> list[tuple[list[str], str]]:
             if trace is not None:
                 trace.skip()
-            # chaos hook on the completion path (see finish_native)
+            # chaos hook ON the completion path — where a real kernel
+            # failure or stall surfaces (delay faults sleep here, fail
+            # faults raise into the batcher's circuit breaker)
             faults.fire("replica.kernel", replica=idx)
             # the rule pair first: its program ran first, and its pick-up
             # is the fence between the two programs below
-            host_ids = np.asarray(top_ids)  # waits for the copy started above
-            host_confs = np.asarray(top_confs)
+            host_ids, host_confs = pick_up_rules()
             if trace is not None:
                 trace.lap("fetch_rules")
             if cm is not None:
-                # fenced per-kernel attribution (ISSUE 12): the host
-                # conversion above IS the fence for the rule kernel (the
-                # device executes in order, so the embed kernel hasn't
-                # started billing yet); dispatch→fence is the same
-                # upper-bound-on-device-time semantics as the batcher's
-                # kmls_device_ms interval, so the derived MFU is a lower bound
+                # per-kernel attribution (ISSUE 12): dispatch → the rule
+                # pair's pick-up, an upper bound on the rule lookup's
+                # device time (the same semantics as the batcher's
+                # kmls_device_ms interval), so the derived MFU is a
+                # lower bound
                 t_rules = time.perf_counter()
                 dims = dict(
                     b=n_rows, l=length, k_max=bundle.rule_ids.shape[1],
                     v=len(bundle.vocab), k_best=self.cfg.k_best_tracks,
                     shards=bundle.n_shards,
                 )
-                if bundle.shard_kernel is not None:
+                if bundle.layout == "mesh":
+                    cm.observe_kernel(
+                        "serve_mesh", t_rules - t_kernel, **dims
+                    )
+                elif bundle.shard_kernel is not None:
                     cm.observe_kernel(
                         "serve_sharded", t_rules - t_kernel, **dims
                     )
@@ -2163,9 +1988,9 @@ class RecommendEngine:
             if emb is not None:
                 emb_host = (np.asarray(emb[0]), np.asarray(emb[1]), emb[2])
                 if cm is not None:
-                    # incremental fence: rule kernel already fenced at
-                    # t_rules, so this span bills only the embed kernel's
-                    # compute + transfer (in-order device queue)
+                    # the rule pair was picked up at t_rules, so this
+                    # interval bills what was left of the embedding
+                    # lookup and its copy (in-order device queue)
                     cm.observe_kernel(
                         "embed_topk",
                         time.perf_counter() - t_rules,
@@ -2184,153 +2009,22 @@ class RecommendEngine:
                     bundle, seeds, bool(known_rows[r]),
                     host_ids[r], host_confs[r], emb_row,
                 ))
-            if trace is not None:
-                trace.lap("compose")
-            return out
-
-        return finish
-
-    def _mesh_recommend_async(
-        self, bundle: RuleBundle, seed_sets: list[list[str]], idx: int,
-        deadline: float | None = None, trace=None,
-    ):
-        """The pod-spanning dispatch/finish pair: fan the staged batch to
-        every gang peer FIRST (socket I/O overlaps the local device
-        work), dispatch this rank's slab partial, and at finish() stack
-        the rank-ordered partials and run the merge — the same two
-        functions the single-process shard_map kernel composes, so the
-        answer is bit-identical by construction. A dead gang member
-        surfaces as :class:`~.mesh.MeshShardUnavailable` out of finish():
-        the app maps it to the gang-degraded signal (503 +
-        ``X-KMLS-Mesh-Unavailable`` under fleet routing) and the routed
-        client spills the request to the next ring peer."""
-        from ..ops.serve import merge_partial_topk, shard_partial_topk
-
-        length = self._bucket_len(
-            max((len(s) for s in seed_sets), default=1)
-        )
-        n_rows = self._bucket_batch(max(len(seed_sets), 1))
-        shape = (n_rows, length)
-        # exact-built host staging (not the reusable buffers): the batch
-        # must survive into the peer fan-out — fetch_partials snapshots
-        # it before the pool threads serialize it to sockets
-        arr = np.full(shape, -1, dtype=np.int32)
-        known_rows, n_real = self._fill_seed_rows(
-            bundle, seed_sets, arr, length
-        )
-        self._note_staged(arr, n_real, trace)
-        if bundle.shard_size > 0:
-            hit = arr[arr >= 0]
-            if hit.size:
-                self._note_shard_dispatch(np.bincount(
-                    hit // bundle.shard_size, minlength=bundle.n_shards
-                ))
-        # deadline propagation: stamp the REMAINING budget on the peer
-        # frames (computed now — staging time already spent), so a
-        # backed-up worker sheds expired partials instead of computing
-        # results nobody will wait for
-        budget_ms = None
-        if deadline is not None:
-            budget_ms = max(0.0, (deadline - time.perf_counter()) * 1e3)
-        finish_remote = self.mesh_coordinator.fetch_partials(
-            arr, bundle.model_token or "", budget_ms=budget_ms
-        )
-        if shape not in bundle.warmed_shapes:
-            self.unwarmed_dispatches += 1
-            logger.warning(
-                "unwarmed seed shape %s dispatched (compile on the "
-                "serving path); warmed buckets: batches %s x lengths %s",
-                shape, self._batch_buckets(), self._len_buckets(),
-            )
-        seeds_dev = jax.device_put(arr)
-        kb = self.cfg.k_best_tracks
-        cm = self.cost_model
-        t_kernel = time.perf_counter() if cm is not None else 0.0
-        part_ids, part_confs = shard_partial_topk(
-            bundle.rule_ids, bundle.rule_confs, seeds_dev, bundle.mesh_lo,
-            v=bundle.mesh_v, k_best=kb,
-        )
-        emb = self._dispatch_embed(bundle, seed_sets, n_rows, length)
-        self._note_dispatch(idx)
-        if trace is not None:
-            # the peer fan-out, the transfer and both device calls
-            trace.lap("dispatch")
-
-        def finish() -> list[tuple[list[str], str]]:
-            if trace is not None:
-                trace.skip()
-            # chaos hook on the completion path (see finish_native)
-            faults.fire("replica.kernel", replica=idx)
-            local_ids = np.asarray(part_ids)  # blocks on the device
-            local_confs = np.asarray(part_confs)
-            # blocks on the slowest peer; raises MeshShardUnavailable
-            # for the first rank the gang cannot serve through
-            parts = finish_remote()
-            stack_ids = np.empty(
-                (bundle.n_shards,) + local_ids.shape, dtype=np.int32
-            )
-            stack_confs = np.empty(
-                (bundle.n_shards,) + local_confs.shape, dtype=np.float32
-            )
-            stack_ids[bundle.gang_rank] = local_ids
-            stack_confs[bundle.gang_rank] = local_confs
-            for rank, (ids_r, confs_r) in parts.items():
-                stack_ids[rank] = ids_r
-                stack_confs[rank] = confs_r
-            # hedged straggler-drop / deadline-shed (ISSUE 18): ranks the
-            # coordinator dropped contribute NOTHING to the merge — their
-            # slots get -inf confidences so the max-merge never selects
-            # them, and every answer is marked degraded (a partial
-            # catalog is a degraded answer, never a silent one)
-            dropped = getattr(finish_remote, "dropped", None) or []
-            for rank in dropped:
-                stack_ids[rank] = 0
-                stack_confs[rank] = np.float32(-np.inf)
-            merged_ids, merged_confs = merge_partial_topk(
-                stack_ids, stack_confs, v=bundle.mesh_v, k_best=kb
-            )
-            host_ids = np.asarray(merged_ids)
-            host_confs = np.asarray(merged_confs)
-            if cm is not None:
-                cm.observe_kernel(
-                    "serve_mesh", time.perf_counter() - t_kernel,
-                    b=n_rows, l=length, k_max=bundle.rule_ids.shape[1],
-                    v=len(bundle.vocab), k_best=kb,
-                    shards=bundle.n_shards,
-                )
-            if trace is not None:
-                # this rank's partial, the slowest peer's, and the merge
-                trace.lap("fetch_rules")
-            emb_host = None
-            if emb is not None:
-                emb_host = (np.asarray(emb[0]), np.asarray(emb[1]), emb[2])
-                if trace is not None:
-                    trace.lap("fetch_embed")
-            out: list[tuple[list[str], str]] = []
-            for r, seeds in enumerate(seed_sets):
-                emb_row = None if emb_host is None else (
-                    emb_host[0][r], emb_host[1][r], emb_host[2][r]
-                )
-                out.append(self._compose_answer(
-                    bundle, seeds, bool(known_rows[r]),
-                    host_ids[r], host_confs[r], emb_row,
-                ))
-            if dropped:
-                # the degraded source string is the per-request side
-                # channel: the app maps it to X-KMLS-Degraded (never a
-                # 5xx) and the answer cache refuses to store it, so a
-                # recovered gang never serves a stale partial-catalog
-                # answer from cache
+            if getattr(remote, "dropped", None):
+                # a merge without a dropped rank's slab is a partial
+                # catalog. The degraded source string is the
+                # per-request side channel: the app maps it to
+                # X-KMLS-Degraded (never a 5xx) and the answer cache
+                # refuses to store it, so a recovered gang never serves
+                # a stale partial-catalog answer from cache
                 self.mesh_straggler_degraded += len(out)
                 out = [
                     (songs, "degraded:mesh-straggler") for songs, _src in out
                 ]
-            finish._kmls_hedge = getattr(finish_remote, "hedge_outcome", None)
             if trace is not None:
                 trace.lap("compose")
             return out
 
-        return finish
+        return finish if remote is None else _stamp_hedge_outcome(finish, remote)
 
     def recommend_many(
         self, seed_sets: list[list[str]]

@@ -1502,7 +1502,7 @@ def test_real_tree_indexes_the_things_checkers_depend_on():
     )
     sites, unresolved = collect_observe_sites(index)
     assert {
-        "serve_rules", "serve_sharded", "serve_native", "embed_topk",
+        "serve_rules", "serve_sharded", "serve_mesh", "embed_topk",
         "support_count", "als_sweep", "delta_recount",
     } <= set(sites), sorted(sites)
     assert any(

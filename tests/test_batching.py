@@ -1,6 +1,6 @@
 """The tail-latency serving layer: shape-bucketed pre-warm (no compile on
 the serving path), adaptive deadline-aware batching, load shedding (429 +
-Retry-After), staging-buffer reuse exactness, and queue/device latency
+Retry-After), fresh-staging-array exactness, and queue/device latency
 attribution."""
 
 import dataclasses
@@ -8,7 +8,6 @@ import json
 import threading
 import time
 
-import jax
 import numpy as np
 import pytest
 
@@ -21,7 +20,7 @@ from kmlserver_tpu.serving.batcher import (
     Overloaded,
     OverloadDegraded,
 )
-from kmlserver_tpu.serving.engine import RecommendEngine, _staging_is_safe
+from kmlserver_tpu.serving.engine import RecommendEngine
 from kmlserver_tpu.serving.metrics import ServingMetrics
 from kmlserver_tpu.serving.replay import replay, sample_seed_sets
 
@@ -65,12 +64,9 @@ class TestBucketedCompilation:
         from kmlserver_tpu.ops import serve as serve_ops
 
         cfg, _, _ = mined_pvc
-        # device path under test: the native host kernel (which never
-        # compiles anything) must be off, as it is on every accelerator
-        engine = RecommendEngine(dataclasses.replace(cfg, native_serve=False))
+        engine = RecommendEngine(cfg)
         assert engine.load()
         bundle = engine.bundle
-        assert bundle.host_rule_ids is None
         for batch in engine._batch_buckets():
             for length in engine._len_buckets():
                 assert (batch, length) in bundle.warmed_shapes
@@ -83,14 +79,14 @@ class TestBucketedCompilation:
             )
             assert len(results) == b
         engine.recommend(seeds[:2])
-        engine.recommend(["totally-unknown"])  # fallback path, no kernel
+        engine.recommend(["totally-unknown"])  # answered by the fallback
         assert engine.unwarmed_dispatches == 0
         if counter:
             assert counter() == n0, "a serving request compiled a kernel"
 
     def test_unwarmed_shape_is_counted_not_silent(self, mined_pvc):
         cfg, _, _ = mined_pvc
-        engine = RecommendEngine(dataclasses.replace(cfg, native_serve=False))
+        engine = RecommendEngine(cfg)
         assert engine.load()
         seeds = _rule_seeds(cfg)
         # an oversized direct batch (> batch_max_size) has no warmed bucket
@@ -99,36 +95,16 @@ class TestBucketedCompilation:
 
 
 class TestStagingReuse:
-    def test_staging_buffers_are_misaligned_so_device_put_copies(self):
-        """Regression for the reuse-corruption flake: jax's CPU client
-        ZERO-COPIES device_put of a 64-byte-aligned host array, so a
-        np.empty staging buffer that happened to land page-aligned was
-        aliased into the device array — the next same-shape dispatch's
-        refill corrupted the in-flight batch (answers swapped between
-        batches, allocator-luck-dependent). The allocator must produce
-        addresses that defeat every power-of-two alignment gate >= 8,
-        and device_put of its buffers must genuinely copy."""
-        from kmlserver_tpu.serving.engine import _staging_buffer
-
-        for shape in ((2, 2), (2, 64), (8, 128), (64, 256)):
-            arr = _staging_buffer(shape)
-            assert arr.shape == shape and arr.dtype == np.int32
-            addr = arr.ctypes.data
-            assert addr % 64 == 4, f"{shape}: addr % 64 == {addr % 64}"
-            arr.fill(-1)
-            on_device = jax.device_put(arr)
-            arr[0, 0] = 123
-            assert int(np.asarray(on_device)[0, 0]) == -1, (
-                f"{shape}: device_put aliased the staging buffer"
-            )
+    """What staging-buffer reuse once had to defend, now guarded by the
+    rule that every dispatch fills a fresh host array."""
 
     def test_overlapping_same_shape_dispatches_stay_exact(self, mined_pvc):
-        """The aliasing hazard the probe guards: two in-flight batches of
-        the SAME padded shape share (refill) one staging buffer. Results
-        must match the per-request oracle — if the device transfer aliased
-        the host buffer, batch 1 would answer with batch 2's seeds."""
+        """Two in-flight batches of the SAME padded shape each stage an
+        array of their own. Results must match the per-request oracle —
+        were a host array shared and the device transfer aliased to it,
+        batch 1 would answer with batch 2's seeds."""
         cfg, _, _ = mined_pvc
-        engine = RecommendEngine(dataclasses.replace(cfg, native_serve=False))
+        engine = RecommendEngine(cfg)
         assert engine.load()
         seeds = _rule_seeds(cfg)
         assert len(seeds) >= 4
@@ -137,21 +113,15 @@ class TestStagingReuse:
         expected = {s: engine.recommend([s]) for s in seeds[:4]}
         finish_a = engine.recommend_many_async(sets_a)
         finish_b = engine.recommend_many_async(sets_b)  # same (2, L) bucket
-        if _staging_is_safe():
-            # reuse is actually active on this backend: both dispatches
-            # went through ONE buffer, and it now sits in the pool
-            assert any(
-                shape[0] == 2 for shape in engine._staging
-            ), "staging pool never populated"
         for seed_sets, finish in ((sets_a, finish_a), (sets_b, finish_b)):
             for (got, source), (seed,) in zip(finish(), seed_sets):
                 assert set(got) == set(expected[seed][0])
                 assert source == expected[seed][1]
 
     def test_fallback_rows_survive_buffer_refill(self, mined_pvc):
-        # the known-row mask is snapshotted before the buffer can be
-        # refilled — an all-unknown row must still fall back correctly
-        # even with another dispatch in between
+        # the known-row mask belongs to its own dispatch — an all-unknown
+        # row must still fall back correctly with another dispatch in
+        # between
         cfg, _, _ = mined_pvc
         engine = RecommendEngine(cfg)
         assert engine.load()
@@ -551,72 +521,8 @@ class TestLoopbackNormalization:
         )[0] == 403
 
 
-class TestNativeServeKernel:
-    def test_native_matches_device_kernel_exactly(self, mined_pvc):
-        """The native serve kernel must be bit-identical to the jitted
-        device kernel — ids AND order (lax.top_k tie semantics), across
-        random batches including unknown-seed rows."""
-        from kmlserver_tpu.serving import native_serve
-
-        if not native_serve.available():
-            pytest.skip("native serve kernel unavailable (no toolchain)")
-        cfg, _, _ = mined_pvc
-        eng_native = RecommendEngine(cfg)
-        assert eng_native.load()
-        assert eng_native.bundle.host_rule_ids is not None
-        assert eng_native.host_kernel_active
-        eng_device = RecommendEngine(
-            dataclasses.replace(cfg, native_serve=False)
-        )
-        assert eng_device.load()
-        assert not eng_device.host_kernel_active
-        vocab = eng_native.bundle.vocab
-        rng = np.random.default_rng(3)
-        for trial in range(20):
-            n = int(rng.integers(1, 12))
-            sets = []
-            for _ in range(n):
-                k = int(rng.integers(1, 6))
-                s = [vocab[i] for i in rng.integers(0, len(vocab), k)]
-                if rng.random() < 0.15:
-                    s = [f"unknown-{trial}"]
-                sets.append(s)
-            got_n = eng_native.recommend_many(sets)
-            got_d = eng_device.recommend_many(sets)
-            assert got_n == got_d  # exact: same songs, same ORDER, same source
-
-    def test_native_skips_warmup_and_never_compiles(self, mined_pvc):
-        from kmlserver_tpu.ops import serve as serve_ops
-        from kmlserver_tpu.serving import native_serve
-
-        if not native_serve.available():
-            pytest.skip("native serve kernel unavailable (no toolchain)")
-        cfg, _, _ = mined_pvc
-        counter = getattr(serve_ops.recommend_batch, "_cache_size", None)
-        n0 = counter() if counter else None
-        engine = RecommendEngine(cfg)
-        assert engine.load()
-        seeds = _rule_seeds(cfg)
-        engine.recommend_many([[s] for s in seeds[:5]])
-        engine.recommend(seeds[:2])
-        if counter:
-            assert counter() == n0  # the native path never touches the jit
-
-    def test_kill_switch_falls_back_to_device_path(self, mined_pvc, monkeypatch):
-        monkeypatch.setenv("KMLS_NATIVE", "0")
-        cfg, _, _ = mined_pvc
-        engine = RecommendEngine(cfg)
-        assert engine.load()
-        assert engine.bundle.host_rule_ids is None  # device path active
-        seeds = _rule_seeds(cfg)
-        recs, source = engine.recommend([seeds[0]])
-        assert source in ("rules", "empty")
-
-
 class TestAsyncMicroBatcher:
-    class _InstantNativeEngine:
-        host_kernel_active = True
-
+    class _InstantEngine:
         def __init__(self):
             self.batch_sizes = []
 
@@ -628,18 +534,18 @@ class TestAsyncMicroBatcher:
 
             return finish
 
-    def test_inline_results_and_batching(self):
+    def test_results_and_batching(self):
         import asyncio
         from kmlserver_tpu.serving.batcher import AsyncMicroBatcher
 
         async def scenario():
-            engine = self._InstantNativeEngine()
+            engine = self._InstantEngine()
             metrics = ServingMetrics()
             batcher = AsyncMicroBatcher(
                 engine, max_size=8, window_ms=20.0, metrics=metrics
             )
             futures = [batcher.submit([f"s{i}"]) for i in range(8)]
-            # the leader dispatches immediately (no rate evidence yet);
+            # the leader dispatches immediately (the pipeline is idle);
             # the rest coalesce into the scheduled window flush
             results = [await f for f in futures]
             assert [g for g, _ in results] == [[f"s{i}"] for i in range(8)]
@@ -656,7 +562,7 @@ class TestAsyncMicroBatcher:
         from kmlserver_tpu.serving.batcher import AsyncMicroBatcher
 
         async def scenario():
-            engine = self._InstantNativeEngine()
+            engine = self._InstantEngine()
             batcher = AsyncMicroBatcher(engine, max_size=8, window_ms=400.0)
             t0 = time.perf_counter()
             got, _ = await batcher.submit(["lone"])
@@ -671,8 +577,6 @@ class TestAsyncMicroBatcher:
         from kmlserver_tpu.serving.batcher import AsyncMicroBatcher
 
         class SlowEngine:
-            host_kernel_active = False
-
             def recommend_many_async(self, seed_sets):
                 def finish():
                     time.sleep(0.05)
@@ -713,7 +617,7 @@ class TestAsyncMicroBatcher:
         from kmlserver_tpu.serving.batcher import AsyncMicroBatcher
 
         cfg, _, _ = mined_pvc
-        engine = RecommendEngine(dataclasses.replace(cfg, native_serve=False))
+        engine = RecommendEngine(cfg)
         assert engine.load()
         seeds = _rule_seeds(cfg)[:4]
         expected = {s: engine.recommend([s]) for s in seeds}

@@ -260,7 +260,6 @@ class _FlakyReplicaEngine:
     """Two-replica fake: replica `bad` fails at finish() until healed."""
 
     n_replicas = 2
-    host_kernel_active = False
 
     def __init__(self, bad: int = 1):
         self.bad = bad
@@ -330,7 +329,6 @@ class TestReplicaEjection:
     def test_total_replica_loss_raises_no_healthy(self):
         class DeadEngine:
             n_replicas = 1
-            host_kernel_active = False
 
             def recommend_many_async(self, seed_sets, replica=None):
                 def finish():
@@ -390,7 +388,6 @@ class TestShedCapacityProjection:
 
     class _TwoReplicaEngine:
         n_replicas = 2
-        host_kernel_active = False
 
         def recommend_many_async(self, seed_sets, replica=None):
             def finish():
@@ -456,7 +453,6 @@ class TestEpochFlipStampede:
 
     class _CountingEngine:
         n_replicas = 1
-        host_kernel_active = False
         bundle_epoch = 1
         cache_value = "tok-1"
 
@@ -583,7 +579,6 @@ class TestDeadlineDegradation:
     def test_queue_expiry_uses_deadline_exceeded(self):
         class StallEngine:
             n_replicas = 1
-            host_kernel_active = False
 
             def recommend_many_async(self, seed_sets, replica=None):
                 def finish():
@@ -643,7 +638,7 @@ class TestZero5xxUnderCompoundChaos:
         degraded) and the recovery counters move."""
         cfg, _, _ = mined_pvc
         cfg = dataclasses.replace(
-            cfg, serve_devices=2, native_serve=False,
+            cfg, serve_devices=2,
             request_deadline_ms=2000.0, replica_eject_threshold=2,
             replica_probe_interval_s=30.0,
         )

@@ -346,7 +346,7 @@ class TestHybridServing:
         cfg = _make_pvc(str(tmp_path))
         run_mining_job(cfg)
         app = _serving_app(
-            str(tmp_path), serve_devices=2, native_serve=False
+            str(tmp_path), serve_devices=2
         )
         engine = app.engine
         assert engine.n_replicas >= 2
@@ -381,25 +381,6 @@ class TestHybridServing:
             recs, _source, cached = app.recommend_direct([seed])
             assert not cached  # old epoch's entries are unreachable
             assert recs == songs
-
-    def test_native_and_device_paths_agree(self, tmp_path):
-        """The native-rule-kernel path and the jit-kernel path must
-        compose identical hybrid answers (the embedding kernel is shared;
-        the rule sides are bit-identical by PR 1's contract)."""
-        from kmlserver_tpu.serving import native_serve
-
-        if not native_serve.available():
-            pytest.skip("native serve kernel unavailable")
-        cfg = _make_pvc(str(tmp_path))
-        run_mining_job(cfg)
-        native_app = _serving_app(str(tmp_path), native_serve=True)
-        device_app = _serving_app(str(tmp_path), native_serve=False)
-        assert native_app.engine.host_kernel_active
-        cold, hot = _cold_and_hot_seeds(device_app.engine)
-        for seeds in ([hot], [cold], [hot, cold]):
-            a = native_app.engine.recommend_many_async([seeds])()
-            b = device_app.engine.recommend_many_async([seeds])()
-            assert a == b
 
 
 @pytest.mark.chaos
@@ -542,7 +523,7 @@ def device_engines(hybrid_pvc):
     known to the rule family alone."""
     engines = {
         mode: _serving_app(
-            hybrid_pvc, hybrid_mode=mode, native_serve=False
+            hybrid_pvc, hybrid_mode=mode
         ).engine
         for mode in HYBRID_MODES
     }

@@ -264,7 +264,7 @@ class TestDeltaBitIdentity:
             ServingConfig(
                 base_dir=str(tmp_path), pickle_dir="pickles/",
                 delta_enabled=True, model_layout="sharded",
-                serve_devices=4, native_serve=False,
+                serve_devices=4,
             )
         )
         assert engine.load()

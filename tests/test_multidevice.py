@@ -22,7 +22,7 @@ def _multi_cfg(cfg, n_devices=8):
     """Device-kernel path across n replicas, with small shape buckets so
     the per-replica warmup stays cheap (3 batch x 2 length buckets)."""
     return dataclasses.replace(
-        cfg, native_serve=False, serve_devices=n_devices,
+        cfg, serve_devices=n_devices,
         batch_max_size=4, max_seed_tracks=8,
     )
 
@@ -49,7 +49,7 @@ class TestReplicaSet:
         # serve_devices=0 (auto) on a CPU backend: one replica, exactly
         # the pre-multi-device behavior (virtual devices share host cores)
         cfg, _, _ = mined_pvc
-        engine = RecommendEngine(dataclasses.replace(cfg, native_serve=False))
+        engine = RecommendEngine(cfg)
         assert engine.load()
         assert engine.n_replicas == 1
 

@@ -2,8 +2,9 @@
 trace propagation through both HTTP front ends, fixed-bucket latency
 histograms pinned against the reservoirs, exposition validity (one TYPE
 per name, valid charset, no NaN), the scrape-never-blocks-observe
-reservoir contract, the event-loop-lag admission fold that closes the
-PR 8 inline-path blind spot, and the mining job_metrics.prom textfile.
+reservoir contract, the event-loop-lag admission fold and the executor
+hop that close the PR 8 blind spot, and the mining job_metrics.prom
+textfile.
 """
 
 import bisect
@@ -806,12 +807,11 @@ class TestLoopLagMonitor:
         assert blind.decide(0.0)[0] == "admit"
 
 
-class _InlineStallEngine:
-    """The PR 8 repro engine: the native host kernel computing ON the
-    loop, with the injected delay fired at the real fault site name.
-    Carries the two fallback hooks the degraded response path reads."""
+class _StallEngine:
+    """The PR 8 repro engine: an injected delay fired at the real fault
+    site name inside ``finish()``. Carries the two fallback hooks the
+    degraded response path reads."""
 
-    host_kernel_active = True
     cache_value = "fake-model-date"
 
     def recommend_many_async(self, seed_sets):
@@ -826,12 +826,18 @@ class _InlineStallEngine:
 
 
 class TestInlinePathBlindSpotClosed:
-    def test_inline_kernel_stall_escalates_ladder_no_5xx(self, tmp_path):
-        """Acceptance: the PR 8 repro — a 200 ms injected kernel delay on
-        the inline native CPU path — now escalates the admission ladder
-        through the loop-lag term instead of answering everything late:
-        follow-up requests degrade/shed (200+header / 429), and nothing
-        is a 5xx."""
+    """The PR 8 blind spot was a kernel computed ON the event loop: a
+    stall there hid the backlog from the queue projection. Every batch
+    now takes the executor hop, so a stalled ``finish()`` leaves the
+    loop free and the in-flight batch's age IS the projection."""
+
+    STALL_S = 0.6
+
+    def test_kernel_stall_escalates_ladder_no_5xx(self, tmp_path):
+        """Acceptance: the PR 8 repro — an injected kernel delay —
+        escalates the admission ladder instead of answering everything
+        late: requests that arrive during the stall degrade/shed
+        (200+header / 429), and nothing is a 5xx."""
         import asyncio
 
         cfg = ServingConfig(
@@ -844,34 +850,41 @@ class TestInlinePathBlindSpotClosed:
         app.loop_lag = LoopLagMonitor(half_life_s=0.4)
         app.cache = None
         app.metrics = ServingMetrics()
-        app.engine = _InlineStallEngine()  # the fallback the degrade rung answers from
-        faults.inject("replica.kernel", replica=0, delay_s=0.2, times=1)
+        app.engine = _StallEngine()  # the fallback the degrade rung answers from
+        faults.inject(
+            "replica.kernel", replica=0, delay_s=self.STALL_S, times=1
+        )
 
         async def scenario():
             app.batcher = AsyncMicroBatcher(
-                _InlineStallEngine(), max_size=4, window_ms=1.0,
+                _StallEngine(), max_size=4, window_ms=1.0,
                 shed_queue_budget_ms=50.0, lag_monitor=app.loop_lag,
             )
             body = json.dumps({"songs": ["warm"]}).encode()
-            response, future, t0, trace = app.submit_recommend(body)
+            response, warm, t0_warm, trace_warm = app.submit_recommend(body)
             assert response is None
-            await future  # the inline finish() stalls the loop 200 ms
-            app.finish_recommend(future, t0, trace=trace)
-            # the direct stall note landed the instant the loop unblocked
-            assert app.loop_lag.lag_s() > 0.1
-            statuses = []
+            # finish() stalls on the executor; the loop keeps running,
+            # and the in-flight batch ages past the 50 ms budget
+            await asyncio.sleep(0.15)
+            assert not warm.done()
+            statuses, waiting = [], []
             for i in range(6):
                 body = json.dumps({"songs": [f"s{i}"]}).encode()
                 response, future, t0, trace = app.submit_recommend(body)
                 if future is not None:
-                    await future
-                    response = app.finish_recommend(future, t0, trace=trace)
-                statuses.append(
-                    (response[0], response[1].get("X-KMLS-Degraded"))
-                )
-            return statuses
+                    waiting.append((future, t0, trace))
+                else:
+                    statuses.append(response)
+            for future, t0, trace in [(warm, t0_warm, trace_warm)] + waiting:
+                await future
+                statuses.append(app.finish_recommend(future, t0, trace=trace))
+            return [
+                (response[0], response[1].get("X-KMLS-Degraded"))
+                for response in statuses
+            ]
 
         statuses = asyncio.run(scenario())
+        assert len(statuses) == 7
         assert all(code < 500 for code, _ in statuses), statuses
         escalated = [
             (code, why) for code, why in statuses
@@ -886,26 +899,44 @@ class TestInlinePathBlindSpotClosed:
         assert ("shed", "shed") in retained or (
             "degraded", "degrade") in retained
 
-    def test_without_lag_monitor_the_blind_spot_is_blind(self):
-        """The control arm: the identical stall with no lag monitor never
-        escalates — proving the new term is what closes the gap."""
+    def test_escalation_needs_no_lag_monitor_and_ends_with_the_stall(self):
+        """The executor hop is what closes the gap: with no lag monitor
+        at all, requests arriving during the stall are refused by the
+        queue projection alone, and once the stalled batch has landed
+        everything is admitted again."""
         import asyncio
 
-        faults.inject("replica.kernel", replica=0, delay_s=0.2, times=1)
+        from kmlserver_tpu.serving.batcher import Overloaded, OverloadDegraded
+
+        faults.inject(
+            "replica.kernel", replica=0, delay_s=self.STALL_S, times=1
+        )
 
         async def scenario():
             batcher = AsyncMicroBatcher(
-                _InlineStallEngine(), max_size=4, window_ms=1.0,
+                _StallEngine(), max_size=4, window_ms=1.0,
                 shed_queue_budget_ms=50.0,
             )
-            await batcher.submit(["warm"])
+            warm = batcher.submit(["warm"])
+            await asyncio.sleep(0.15)
+            assert not warm.done()
+            refused = 0
+            for i in range(4):
+                try:
+                    await batcher.submit([f"during-{i}"])
+                except (Overloaded, OverloadDegraded):
+                    refused += 1
+            await warm
+            # past the admission controller's memory of the stall
+            await asyncio.sleep(1.0)
             results = []
             for i in range(4):
                 results.append(await batcher.submit([f"s{i}"]))
-            return results
+            return refused, results
 
-        results = asyncio.run(scenario())
-        assert len(results) == 4  # everything admitted — answered late
+        refused, results = asyncio.run(scenario())
+        assert refused > 0
+        assert len(results) == 4  # everything admitted again
 
 
 # ---------------------------------------------------------------------------
@@ -1253,7 +1284,7 @@ class TestCostAttributionLive:
     def _app(self, cfg, **over):
         app = RecommendApp(
             dataclasses.replace(
-                cfg, cache_enabled=False, native_serve=False, **over
+                cfg, cache_enabled=False, **over
             )
         )
         assert app.engine.load()
@@ -1338,7 +1369,7 @@ class TestCostAttributionLive:
         run_mining_job(mcfg)
         cfg = dataclasses.replace(
             ServingConfig.from_env(None), base_dir=str(tmp_path),
-            cache_enabled=False, native_serve=False,
+            cache_enabled=False,
         )
         app = RecommendApp(cfg)
         assert app.engine.load()
@@ -1695,8 +1726,7 @@ class TestJobPhaseCostTelemetry:
 REQUEST_SPANS = {
     "request", "parse", "cache", "admit", "queue", "batch", "respond",
 }
-# what the engine's native host variant records (the CPU default); the
-# jitted variant records the same names (pinned below)
+# what a rules-only batch records (a hybrid one adds fetch_embed)
 BATCH_SPANS = {
     "batch", "stage", "dispatch", "fetch_rules", "compose", "resolve",
 }
@@ -1870,10 +1900,9 @@ class TestSpanTree:
         self, mined_pvc
     ):
         cfg, _, _ = mined_pvc
-        engine_cfg = dataclasses.replace(cfg, native_serve=False)
         from kmlserver_tpu.serving.engine import RecommendEngine
 
-        engine = RecommendEngine(engine_cfg)
+        engine = RecommendEngine(cfg)
         assert engine.load()
         rec = SpanRecorder(sample=1.0, rng=random.Random(12))
         bt = rec.begin_batch(time.perf_counter(), requests=3, replica=0)
@@ -1905,7 +1934,7 @@ class TestSpanTree:
         )
 
         run_mining_job(_make_pvc(str(tmp_path)))
-        engine = _serving_app(str(tmp_path), native_serve=False).engine
+        engine = _serving_app(str(tmp_path)).engine
         assert engine.embedding_active
         cold, hot = _cold_and_hot_seeds(engine)
         rec = SpanRecorder(sample=1.0, rng=random.Random(32))
@@ -1945,8 +1974,8 @@ class TestSpanTree:
         self, mined_pvc
     ):
         """A span name that ``benchmark/spans.py`` does not know leaves
-        its idle time unclaimed (PR 31 read 5.7% there): whatever any
-        variant of ``recommend_many_async`` records is a key of
+        its idle time unclaimed (PR 31 read 5.7% there): whatever
+        ``recommend_many_async`` records is a key of
         ``BUCKET_OF``, and stands in ``PRECEDENCE``."""
         from benchmark import spans as bench_spans
         from kmlserver_tpu.serving.engine import RecommendEngine
@@ -1957,19 +1986,16 @@ class TestSpanTree:
                 "compose"} <= laps
         assert laps <= set(bench_spans.BUCKET_OF)
         assert laps <= set(bench_spans.PRECEDENCE)
-        # and what a live batch records is among them, on both variants
+        # and what a live batch records is among them
         cfg, _, _ = mined_pvc
-        for native in (True, False):
-            engine = RecommendEngine(
-                dataclasses.replace(cfg, native_serve=native)
-            )
-            assert engine.load()
-            rec = SpanRecorder(sample=1.0, rng=random.Random(5))
-            bt = rec.begin_batch(time.perf_counter(), requests=1, replica=0)
-            engine.recommend_many_async([_rule_seeds(cfg)[:2]], trace=bt)()
-            rec.finish_batch(bt)
-            (doc,) = rec.debug_payload()["batches"]
-            assert {s["name"] for s in doc["spans"][1:]} <= laps
+        engine = RecommendEngine(cfg)
+        assert engine.load()
+        rec = SpanRecorder(sample=1.0, rng=random.Random(5))
+        bt = rec.begin_batch(time.perf_counter(), requests=1, replica=0)
+        engine.recommend_many_async([_rule_seeds(cfg)[:2]], trace=bt)()
+        rec.finish_batch(bt)
+        (doc,) = rec.debug_payload()["batches"]
+        assert {s["name"] for s in doc["spans"][1:]} <= laps
 
     def test_three_requests_one_batch_trace_three_batch_spans(self):
         """Satellite: requests that share a dispatch share one batch
@@ -2232,12 +2258,12 @@ class TestCaptureMode:
 class TestDispatchCounters:
     def test_seed_slots_count_exactly_rows_times_length(self, mined_pvc):
         """``kmls_seed_slots_total``: real + padded is the staged array's
-        size, dispatch by dispatch, on a hand-built batch (jitted path:
-        the shape is a bucket)."""
+        size, dispatch by dispatch, on a hand-built batch (the shape
+        is a bucket)."""
         from kmlserver_tpu.serving.engine import RecommendEngine
 
         cfg, _, _ = mined_pvc
-        engine = RecommendEngine(dataclasses.replace(cfg, native_serve=False))
+        engine = RecommendEngine(cfg)
         assert engine.load()
         seeds = _rule_seeds(cfg)
         real0, padded0 = engine.seed_slots_real, engine.seed_slots_padded
@@ -2265,9 +2291,10 @@ class TestDispatchCounters:
         ):
             assert types[name] == mtype
             assert METRIC_REGISTRY[name] == f"{mtype}:serving"
-        # native path: the array is exact-sized, one known seed a request
+        # two batches of one request: two seeds (one known) in the
+        # (1, 8) bucket each
         assert 'kmls_seed_slots_total{kind="real"} 2' in text
-        assert 'kmls_seed_slots_total{kind="padded"} 2' in text
+        assert 'kmls_seed_slots_total{kind="padded"} 14' in text
         assert "kmls_batch_size_count 2" in text
         assert "kmls_batch_size_sum 2.000000" in text
         assert "kmls_unwarmed_dispatches_total 0" in text
@@ -2299,7 +2326,7 @@ class TestDispatchCounters:
         self, mined_pvc
     ):
         cfg, _, _ = mined_pvc
-        app = RecommendApp(dataclasses.replace(cfg, native_serve=False))
+        app = RecommendApp(cfg)
         assert app.engine.load()
         seeds = _rule_seeds(cfg)
         app.engine.recommend_many_async([seeds[:1]])()

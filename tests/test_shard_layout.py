@@ -7,8 +7,8 @@ Layout-equivalence coverage, on the virtual 8-device CPU mesh:
   ties and padding included;
 - serving: a sharded engine answers bit-identically to a replicated one
   across publications (epochs), presents as one replica, never compiles
-  after publish on ANY warmed bucket, bypasses the native host kernel,
-  and exposes per-shard dispatch counters;
+  after publish on ANY warmed bucket, and exposes per-shard dispatch
+  counters;
 - layout resolution: ``auto`` shards exactly when the measured tensor
   bytes exceed the per-device budget (and never on one device);
 - mining: the vocab-sharded count→emit path produces rule tensors (and
@@ -142,7 +142,7 @@ def _sharded_cfg(cfg, **kw):
 
 def _replicated_cfg(cfg, **kw):
     return dataclasses.replace(
-        cfg, native_serve=False, serve_devices=1,
+        cfg, serve_devices=1,
         batch_max_size=4, max_seed_tracks=8, **kw,
     )
 
@@ -200,16 +200,6 @@ class TestShardedServing:
         if counter:
             assert counter() == n0, "a sharded dispatch compiled a kernel"
 
-    def test_sharded_bypasses_native_host_kernel(self, mined_pvc):
-        cfg, _, _ = mined_pvc
-        engine = RecommendEngine(
-            _sharded_cfg(cfg, native_serve=True)
-        )
-        assert engine.load()
-        assert engine.bundle.host_rule_ids is None
-        assert not engine.host_kernel_active
-        assert engine.bundle.layout == "sharded"
-
     def test_auto_layout_shards_only_past_the_budget(self, mined_pvc):
         cfg, _, _ = mined_pvc
         # tiny budget: the ds tensors measure over it → sharded
@@ -223,7 +213,7 @@ class TestShardedServing:
         # roomy budget: replicated, exactly the legacy layout
         roomy = RecommendEngine(dataclasses.replace(
             cfg, model_layout="auto", device_budget_bytes=1 << 40,
-            serve_devices=4, native_serve=False,
+            serve_devices=4,
             batch_max_size=4, max_seed_tracks=8,
         ))
         assert roomy.load()
