@@ -25,17 +25,25 @@ diverging only where the geometry demands it:
   this is belt-and-braces, not the primary path);
 - equal scores come out lowest item id first (``lax.top_k``'s order).
 
-Memory shape: ONE pass over the factors per batch. The seeds are the
-rows of the matrix product and the catalog its blocked axis: the batch's
-``B·L`` seed vectors are gathered once, then the ``(R, V)`` factor table
+Memory shape: ONE pass over the factors per batch, and no product in
+HBM at any batch size. The batch's ``B·L`` seed vectors are gathered once
+and kept as ``(B, L, R)``; then the ``(R, V)`` factor table
 (:func:`factor_table` — laid out once, where the model is placed, so the
 catalog is the minor axis and the program re-lays nothing) is walked in
-column tiles; each tile is one ``(B·L, R) × (R, tile)`` product at the
+column tiles. A tile step is one ``(B, L, R) × (R, tile)`` product at the
 backend's default precision (bfloat16 products, float32 sums on the
-TPU, at every batch size), max-reduced over each request's ``L`` rows
-while the tile is at hand. Only the ``(B, V)`` maxima are kept; the tile
-width follows the shapes (:func:`_tile_plan`) so the live product stays
-under ``_TILE_ELEMS`` elements however large ``B·L`` is.
+TPU, at every batch size) whose maximum over ``L`` is taken where the
+product is produced: the reduction runs over the WHOLE of the product's
+``L`` axis, which is the pattern the TPU compiler fuses into the matrix
+unit's output (one ``convolution_reduce_fusion`` writing ``(B, tile)``).
+Flattening the seeds to ``(B·L, R)`` and reducing ``L``-row groups of
+the product is the same arithmetic and is NOT fused when ``B > 1``: the
+``(B·L, tile)`` float32 product is then written out and read back by a
+stand-alone ``reduce_max`` (a ``(2, 128)`` batch's program took 5.64 ms
+on the chip that way and takes 1.94 ms this way; PERF.md §6, PR 36).
+Only the ``(B, V)`` maxima are kept; the tile width follows the seed
+length alone (:func:`_tile_plan`), so a request meets the same tiles
+alone and in a batch.
 """
 
 from __future__ import annotations
@@ -50,17 +58,26 @@ import numpy as np
 # max without breeding NaNs through 0·inf corners
 _NEG = jnp.float32(-3.0e38)
 
-# the most elements one tile's (B·L, tile) float32 product may hold:
-# 32 MiB, what the v5e keeps beside the table in on-chip memory — at four
-# times that a full (32, 128) batch spills the product to HBM and runs 3×
-# slower, at half of it the loop's trip count starts to show (PERF.md §6)
+# the most elements one request's (L, tile) float32 product may hold per
+# tile step: 32 MiB. No product is materialised at any batch size (the
+# maximum over L is fused into it), so this no longer bounds a buffer:
+# it fixes the tile width per seed length, so that a (B, L) batch walks
+# the tiles a lone (1, L) request walks (PR 27's plan for the lone
+# buckets, unmoved). Planning on B·L rows instead, as when the product
+# was live, is nowhere faster on the chip and up to 53% slower (narrow
+# tiles, many trips: PERF.md §6, PR 36)
 _TILE_ELEMS = 1 << 23
 
-# products of fewer rows than one sublane tile are lowered to a float32
+# a product of fewer rows than one sublane tile is lowered to a float32
 # vector-unit reduction, not the matrix unit's bfloat16 pass: a lone
 # one-seed request would be scored at another precision than the same
-# request inside a batch. The seed axis of so small a batch is repeated
-# up to this many rows (a max over duplicates changes nothing).
+# request inside a batch. This counts ALL the rows of a step's product,
+# B·L: the compiler lays the (B, L) rows out together in the sublanes,
+# so a one-seed row in a batch of eight already rides the matrix unit
+# (bitwise equal to the row alone on the chip; counting L instead costs
+# 29% at (32, 1): PERF.md §6, PR 36). The seed axis of so small a batch
+# is repeated up to this many rows (a max over duplicates changes
+# nothing).
 _MIN_ROWS = 8
 
 
@@ -76,9 +93,10 @@ def factor_table(item_factors) -> jax.Array:
 
 def _tile_plan(rows: int, v: int) -> tuple[int, int]:
     """→ ``(n_tiles, tile)``: the fewest equal column tiles, each a whole
-    number of 128-lane groups, whose ``(rows, tile)`` product stays under
-    ``_TILE_ELEMS``. The last tile is clamped to end at ``v`` (it overlaps
-    its neighbor by < 128·n_tiles columns), so ``v`` needs no padding."""
+    number of 128-lane groups, at which one request's ``(rows, tile)``
+    product stays under ``_TILE_ELEMS``. The last tile is clamped to end
+    at ``v`` (it overlaps its neighbor by < 128·n_tiles columns), so ``v``
+    needs no padding."""
     widest = max(128, _TILE_ELEMS // rows // 128 * 128)
     n_tiles = -(-v // widest)
     tile = min(v, -(-v // (n_tiles * 128)) * 128)
@@ -103,9 +121,10 @@ def _embed_topk_impl(
     # top-k, on (B, k) instead of (B, V)
     stand_in = jnp.max(seed_ids, axis=1, keepdims=True)
     safe_seeds = jnp.maximum(jnp.where(valid, seed_ids, stand_in), 0)
-    vecs = jnp.take(item_factors, safe_seeds.reshape(-1), axis=1).T  # (B·L, R)
+    vecs = jnp.take(item_factors, safe_seeds.reshape(-1), axis=1).T
+    vecs = vecs.reshape(b, length, r)
 
-    n_tiles, tile = _tile_plan(b * length, v)
+    n_tiles, tile = _tile_plan(max(length, _MIN_ROWS), v)
 
     def score_tile(scores, i):
         start = jnp.minimum(i * tile, v - tile)
@@ -115,8 +134,11 @@ def _embed_topk_impl(
         # converts the whole table ahead of the loop on every call — a
         # second pass over the factors and a 145 MB temporary
         block = jax.lax.reduce_precision(block, exponent_bits=8, mantissa_bits=23)
+        # (B, L, R) × (R, tile) reduced over the whole L axis: fused into
+        # the product for every B. A (B·L, R) product reshaped to
+        # (B, L, tile) before the max is not (module docstring).
         sims = jnp.dot(vecs, block, preferred_element_type=jnp.float32)
-        best = sims.reshape(b, length, tile).max(axis=1)
+        best = sims.max(axis=1)
         return jax.lax.dynamic_update_slice(scores, best, (0, start)), None
 
     scores, _ = jax.lax.scan(

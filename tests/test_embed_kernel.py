@@ -79,12 +79,15 @@ def _reference(factors: np.ndarray, seeds: np.ndarray, k_best: int):
 SMALL_TILE_ELEMS = 1 << 13
 
 
-@pytest.mark.parametrize("batch,length", [(1, 1), (1, 8), (1, 128), (4, 32), (32, 8)])
+@pytest.mark.parametrize(
+    "batch,length", [(1, 1), (1, 8), (1, 128), (4, 32), (32, 8), (2, 128), (4, 128)]
+)
 @pytest.mark.parametrize("rank", [8, 32])
 @pytest.mark.parametrize("v", [24, 3000, 3072, 4100])
 def test_blocked_lookup_matches_float64_reference(monkeypatch, v, rank, batch, length):
     monkeypatch.setattr(embed_ops, "_TILE_ELEMS", SMALL_TILE_ELEMS)
-    n_tiles, tile = embed_ops._tile_plan(max(batch * length, embed_ops._MIN_ROWS), v)
+    # the plan follows the seed length alone, whatever the batch
+    n_tiles, tile = embed_ops._tile_plan(max(length, embed_ops._MIN_ROWS), v)
     assert (n_tiles > 1) == (v > 24)
     assert (v % tile == 0) == (v in (24, 3072))
     factors = _factors(v, rank, seed=v + rank)
@@ -113,25 +116,39 @@ def test_lone_all_padding_row_returns_nothing():
     assert (np.asarray(ids) == -1).all() and (np.asarray(sims) == 0).all()
 
 
-@pytest.mark.parametrize("length", [1, 8, 32])
-def test_answer_does_not_depend_on_the_batch(length):
-    """PERF.md §6 finding 4, mended: the same seed row alone and as row 3
-    of a batch of 8 is scored by the same product at the same precision.
-    (The CPU's float32 sums may associate differently with the row
-    count: the last bits, far below any rank gap.)"""
+@pytest.mark.parametrize("others", ["mixed", "padding"])
+@pytest.mark.parametrize("batch", [2, 4, 8, 32])
+@pytest.mark.parametrize("length", [1, 8, 32, 128])
+def test_answer_does_not_depend_on_the_batch(length, batch, others):
+    """PERF.md §6 finding 4, mended, and kept by PR 36's fused maximum:
+    the same seed row alone and inside a batch of 2, 4, 8 or 32
+    is scored by the same product at the same precision, whether the
+    batch's other rows are live or padding alone. Row 1 holds ONE seed at
+    every length, and at length 1 every row does (``_MIN_ROWS``: alone it
+    is repeated to a sublane tile, in a batch of eight it is not; on the
+    chip the two are bitwise equal, PERF.md §6 PR 36). (The CPU's float32
+    sums may associate differently with the row count: the last bits, far
+    below any rank gap.)"""
     v = 5000
     table = factor_table(_factors(v, 32, seed=7, ties=False))
-    rng = np.random.default_rng(length)
-    seeds = np.full((8, length), -1, dtype=np.int32)
-    for row in range(8):
-        n = max(1, length - row % 3)
+    rng = np.random.default_rng(length * 100 + batch)
+    seeds = np.full((batch, length), -1, dtype=np.int32)
+    probes = sorted({0, 1, batch - 1})
+    for row in range(batch):
+        if others == "padding" and row != batch - 1:
+            continue
+        n = 1 if row == 1 else max(1, length - row % 3)
         seeds[row, :n] = rng.choice(v, size=n, replace=False)
-    alone_ids, alone_sims = embed_topk(table, jnp.asarray(seeds[3:4]), k_best=10)
     batch_ids, batch_sims = embed_topk(table, jnp.asarray(seeds), k_best=10)
-    np.testing.assert_array_equal(np.asarray(alone_ids)[0], np.asarray(batch_ids)[3])
-    np.testing.assert_allclose(
-        np.asarray(alone_sims)[0], np.asarray(batch_sims)[3], rtol=0, atol=2.0 ** -20
-    )
+    batch_ids, batch_sims = np.asarray(batch_ids), np.asarray(batch_sims)
+    for row in probes:
+        ids, sims = embed_topk(table, jnp.asarray(seeds[row : row + 1]), k_best=10)
+        np.testing.assert_array_equal(np.asarray(ids)[0], batch_ids[row])
+        np.testing.assert_allclose(
+            np.asarray(sims)[0], batch_sims[row], rtol=0, atol=2.0 ** -20
+        )
+        live = (seeds[row] >= 0).any()
+        assert (batch_ids[row] >= 0).all() == live
 
 
 @pytest.mark.parametrize(

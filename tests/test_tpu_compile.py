@@ -1,0 +1,101 @@
+"""The serve path's kernels compiled at the benchmark's real widths for a
+DESCRIBED TPU v5e (no chip attached, nothing runs): what the chip's
+compiler makes of a program is checked here at no chip time.
+
+All such compiles live in this one file (one pytest-xdist worker loads the
+TPU's library; a second file could land on another worker and skip). The
+topology is described inside a fixture, never at import.
+"""
+
+import re
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from kmlserver_tpu.ops import embed as embed_ops
+
+# benchmark/configs/mpd-hybrid.json: the Million Playlist Dataset's catalog
+V, RANK, K_BEST = 2262292, 32, 10
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described device is written to the persistent cache
+    and cannot be read back without a chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _computations(hlo: str) -> dict[str, list[str]]:
+    """Compiled HLO text → {computation name: its instruction lines}."""
+    out: dict[str, list[str]] = {}
+    name = None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            name = head.group(1)
+            out[name] = []
+        elif name is not None and " = " in line:
+            out[name].append(line)
+    return out
+
+
+@pytest.mark.parametrize("batch,length", [(1, 128), (2, 128), (4, 32), (32, 128)])
+def test_embedding_tile_step_fuses_the_max_into_the_product(
+    one_chip, no_compile_cache, batch, length
+):
+    """ISSUE 36's rule: no operation of the tile loop reads or writes a
+    ``(B·L, tile)`` or ``(B, L, tile)`` float32 product outside a fusion;
+    the loop's step is ONE fusion around the convolution whose root is the
+    maximum over ``L``, writing ``(B, tile)``."""
+    table = jax.ShapeDtypeStruct((RANK, V), jnp.float32, sharding=one_chip)
+    seeds = jax.ShapeDtypeStruct((batch, length), jnp.int32, sharding=one_chip)
+    kernel = jax.jit(partial(embed_ops._embed_topk_impl, k_best=K_BEST))
+    comps = _computations(kernel.lower(table, seeds).compile().as_text())
+    fused = {
+        m.group(1)
+        for lines in comps.values()
+        for line in lines
+        for m in [re.search(r"kind=\w+, calls=%([\w.\-]+)", line)]
+        if m
+    }
+    _, tile = embed_ops._tile_plan(max(length, embed_ops._MIN_ROWS), V)
+    product = re.compile(rf"= f32\[({batch * length}|{batch},{length}),{tile}\]")
+    steps = []
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            assert not product.search(line), f"a product outside a fusion: {line[:160]}"
+            if "while/body" not in line:
+                continue
+            assert not re.search(r" (reduce|convolution|dot)\(", line), (
+                f"a stand-alone op in the tile loop: {line[:160]}"
+            )
+            if " fusion(" in line and "kind=kOutput" in line:
+                steps.append(re.search(r"calls=%([\w.\-]+)", line).group(1))
+    assert len(steps) == 1, steps
+    body = comps[steps[0]]
+    assert any(" convolution(" in line for line in body)
+    root = next(line for line in body if "ROOT" in line)
+    assert " reduce(" in root and f"{tile}]" in root, root[:160]
