@@ -71,6 +71,9 @@ def emit_rule_tensors(pair_count_matrix: jax.Array, min_count: jax.Array, *, k_m
     return rule_ids, rule_counts, row_valid_counts
 
 
+_CONF_BLOCK_ROWS = 4096  # x K_max 256 x 8 B: an 8 MB quotient a block
+
+
 def derive_confs(
     rule_counts: np.ndarray,
     item_counts: np.ndarray,
@@ -78,11 +81,21 @@ def derive_confs(
     mode: str,
 ) -> np.ndarray:
     """THE count→confidence arithmetic, shared by the miner and every npz
-    consumer (float64 division, then float32 for the serving tensors)."""
+    consumer (float64 division, then float32 for the serving tensors).
+    Computed a block of rows at a time: the float64 quotient of a whole
+    (V, K) table is twice the table (19 GB at 9.39M x 256) paged in for
+    one pass; a block's stays in cache. Element for element the same."""
+    rule_counts = np.asarray(rule_counts)
+    rows = len(rule_counts)
     if mode == "support":
-        return (rule_counts.astype(np.float64) / n_playlists).astype(np.float32)
-    denom = np.maximum(item_counts, 1)[:, None].astype(np.float64)
-    return (rule_counts / denom).astype(np.float32)
+        denom = np.broadcast_to(np.float64(n_playlists), (rows, 1))
+    else:
+        denom = np.maximum(item_counts, 1)[:, None].astype(np.float64)
+    out = np.empty(rule_counts.shape, dtype=np.float32)
+    for lo in range(0, rows, _CONF_BLOCK_ROWS):
+        hi = lo + _CONF_BLOCK_ROWS
+        out[lo:hi] = rule_counts[lo:hi].astype(np.float64) / denom[lo:hi]
+    return out
 
 
 def expand_rules_dict(

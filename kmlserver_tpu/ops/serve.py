@@ -229,13 +229,19 @@ def sharded_recommend_fn(mesh, k_best: int, axis: str = "shard"):
         _sharded_recommend_local,
         k_best=k_best, axis=axis, n_shards=n_shards,
     )
-    return jax.jit(
-        jax.shard_map(
-            local, mesh=mesh,
-            in_specs=(P(axis, None), P(axis, None), P(None, None)),
-            out_specs=(P(None, None), P(None, None)),
-            # the all_gather makes both outputs mesh-invariant; the scatter
-            # updates carry no vma annotation the checker could follow
-            check_vma=False,
-        )
+    sharded = jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P(axis, None), P(axis, None), P(None, None)),
+        out_specs=(P(None, None), P(None, None)),
+        # the all_gather makes both outputs mesh-invariant; the scatter
+        # updates carry no vma annotation the checker could follow
+        check_vma=False,
     )
+
+    def _recommend_batch_sharded(rule_ids, rule_confs, seed_ids):
+        # the XLA module takes this function's name
+        # (``jit__recommend_batch_sharded``): whoever reads a device trace
+        # for the rule lookup by ``recommend_batch`` finds both layouts
+        return sharded(rule_ids, rule_confs, seed_ids)
+
+    return jax.jit(_recommend_batch_sharded)

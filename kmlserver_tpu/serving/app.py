@@ -434,6 +434,7 @@ class RecommendApp:
                     artifact_stale=self._artifact_stale_flags(ages),
                     mesh_shards=self._mesh_shard_states(),
                     io=iohealth.MONITOR.snapshot(),
+                    shard_placement=self.engine.shard_placement(),
                 )
                 return 200, {"Content-Type": "text/plain; version=0.0.4"}, text.encode()
             if path.startswith("/static/"):

@@ -75,6 +75,7 @@ import os
 import random
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 
 import jax
@@ -192,6 +193,12 @@ class RuleBundle:
     # replicated NamedSharding over `mesh` — the placement target for
     # staged seed batches (replicated layout uses `device` instead)
     seed_sharding: object = None
+    # what placing the shards took at publication (the two sharded
+    # ``device_put``s until both were resident) and the rule bytes each
+    # device holds, in shard order — kmls_shard_place_seconds and
+    # kmls_shard_resident_bytes{shard=} in /metrics
+    place_seconds: float = 0.0
+    shard_resident_bytes: tuple = ()
     # ---- pod-spanning serve mesh (ISSUE 16) ----
     # "mesh" layout: rule_ids/rule_confs hold ONLY this gang member's
     # vocab slab (global rows [gang_rank·shard_size, +shard_size)) on the
@@ -908,15 +915,27 @@ class RecommendEngine:
         mesh = JaxMesh(np.asarray(devs), ("shard",))
         v, k = rule_ids.shape
         v_pad = ((v + n - 1) // n) * n
-        ids = np.full((v_pad, k), -1, dtype=np.int32)
-        confs = np.zeros((v_pad, k), dtype=np.float32)
-        ids[:v] = rule_ids
-        confs[:v] = rule_confs
+        if v_pad == v:
+            # nothing to pad: no second copy of the tables on the host
+            ids = np.asarray(rule_ids, dtype=np.int32)
+            confs = np.asarray(rule_confs, dtype=np.float32)
+        else:
+            ids = np.full((v_pad, k), -1, dtype=np.int32)
+            confs = np.zeros((v_pad, k), dtype=np.float32)
+            ids[:v] = rule_ids
+            confs[:v] = rule_confs
         row_spec = NamedSharding(mesh, PartitionSpec("shard", None))
+        t_place = time.perf_counter()
+        ids_dev = jax.device_put(ids, row_spec)
+        confs_dev = jax.device_put(confs, row_spec)
+        jax.block_until_ready((ids_dev, confs_dev))
+        place_seconds = time.perf_counter() - t_place
+        resident = {d: 0 for d in devs}
+        for shard in (*ids_dev.addressable_shards, *confs_dev.addressable_shards):
+            resident[shard.device] += int(shard.data.nbytes)
         bundle = RuleBundle(
             vocab=vocab, index=index,
-            rule_ids=jax.device_put(ids, row_spec),
-            rule_confs=jax.device_put(confs, row_spec),
+            rule_ids=ids_dev, rule_confs=confs_dev,
             known_mask=known_mask, model_token=token,
             device=None, layout="sharded", mesh=mesh, n_shards=n,
             shard_size=v_pad // n,
@@ -924,12 +943,15 @@ class RecommendEngine:
                 mesh, self.cfg.k_best_tracks
             ),
             seed_sharding=NamedSharding(mesh, PartitionSpec(None, None)),
+            place_seconds=place_seconds,
+            shard_resident_bytes=tuple(resident[d] for d in devs),
         )
         logger.info(
             "sharded layout: %d rule rows (+%d pad) across %d shards "
-            "(%d rows, ~%.1f MiB of rule tensors per device)",
+            "(%d rows, ~%.1f MiB of rule tensors per device), placed in "
+            "%.2fs",
             v, v_pad - v, n, v_pad // n,
-            (ids.nbytes + confs.nbytes) / n / (1 << 20),
+            (ids.nbytes + confs.nbytes) / n / (1 << 20), place_seconds,
         )
         return bundle
 
@@ -1107,6 +1129,16 @@ class RecommendEngine:
         bundle = self.bundle
         return bundle.n_shards if bundle is not None else 1
 
+    def shard_placement(self) -> tuple[float, tuple] | None:
+        """``(seconds the shards took to place, rule bytes each device
+        holds in shard order)`` of the published bundle under the sharded
+        layout, None under any other (one bundle read, so both describe
+        the same publication)."""
+        bundle = self.bundle
+        if bundle is None or bundle.layout != "sharded":
+            return None
+        return bundle.place_seconds, bundle.shard_resident_bytes
+
     def _warmup(self, bundle: RuleBundle) -> None:
         """Compile EVERY (batch-bucket, length-bucket) shape before the
         bundle publishes: no request — whatever its batch size — ever pays
@@ -1125,6 +1157,8 @@ class RecommendEngine:
         kernel = bundle.shard_kernel or self._kernel
         if warm_mesh:
             from ..ops.serve import merge_partial_topk, shard_partial_topk
+        if bundle.shard_kernel is not None:
+            self._precompile_sharded(bundle)
         for length in self._len_buckets():
             for batch in self._batch_buckets():
                 seeds = jnp.full((batch, length), -1, dtype=jnp.int32)
@@ -1175,6 +1209,33 @@ class RecommendEngine:
                         )
                     )
                     bundle.emb_warmed_shapes.add((batch, length))
+
+    def _precompile_sharded(self, bundle: RuleBundle) -> None:
+        """Compile the sharded lookup's bucket grid side by side; the
+        warm-up loop then runs each shape in turn and finds its program
+        compiled (JAX keeps a lowered program's executable in memory, and
+        in the persistent cache where there is one). A program over a
+        four-device mesh at a 9.39M vocabulary compiles in 4.3 s, 24 of
+        them one after the other in 104 s (PR 38, on the chip), and
+        compiling releases the interpreter lock. Nothing runs here: two
+        collective programs launched from two threads may reach the
+        devices in different orders."""
+        shapes = [
+            (batch, length)
+            for length in self._len_buckets() for batch in self._batch_buckets()
+        ]
+
+        def compile_one(shape) -> None:
+            seeds = jax.device_put(
+                np.full(shape, -1, dtype=np.int32), bundle.seed_sharding
+            )
+            bundle.shard_kernel.lower(
+                bundle.rule_ids, bundle.rule_confs, seeds
+            ).compile()
+
+        workers = min(len(shapes), os.cpu_count() or 1)
+        with ThreadPoolExecutor(max_workers=workers) as pool:
+            list(pool.map(compile_one, shapes))
 
     def prewarm_touch(self) -> int:
         """Predictive shape pre-touch (ISSUE 17, actuator a): re-dispatch

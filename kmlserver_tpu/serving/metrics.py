@@ -91,6 +91,11 @@ METRIC_REGISTRY: dict[str, str] = {
     "kmls_unwarmed_dispatches_total": "counter:serving",
     "kmls_shard_dispatch_total": "counter:serving",
     "kmls_model_shards": "gauge:serving",
+    # sharded layout only: what placing the rule shards took at the last
+    # publication (the sharded device_puts until resident), and the rule
+    # bytes each device holds
+    "kmls_shard_place_seconds": "gauge:serving",
+    "kmls_shard_resident_bytes": "gauge:serving",
     # pod-spanning serve mesh (ISSUE 16): gang shard health by state
     # (serving/missing) — rendered only on gang members, so the series
     # existing at all says "this pod is a mesh member", and
@@ -507,6 +512,7 @@ class ServingMetrics:
         cache=None, dispatch_counts=None, robustness=None,
         shard_counts=None, cost=None, slo=None, artifact_ages=None,
         artifact_stale=None, mesh_shards=None, io=None, seed_slots=None,
+        shard_placement=None,
     ) -> str:
         """Prometheus text. ``cache`` (a serving.cache.RecommendCache),
         ``dispatch_counts`` (the engine's per-replica dispatch counters),
@@ -517,9 +523,11 @@ class ServingMetrics:
         sharded model layout), ``cost`` (an observability.costmodel
         .CostModel — per-kernel MFU/roofline + memory/compile
         telemetry), ``slo`` (an observability.slo.SloTracker) and
-        ``artifact_ages`` (artifact name → seconds since publication)
-        and ``seed_slots`` (the engine's ``(real, padded)`` staged-slot
-        counters) are optional — deployments without them render exactly
+        ``artifact_ages`` (artifact name → seconds since publication),
+        ``seed_slots`` (the engine's ``(real, padded)`` staged-slot
+        counters) and ``shard_placement`` (the engine's
+        ``shard_placement()``, None outside the sharded layout) are
+        optional — deployments without them render exactly
         the old exposition."""
         p50, p95, p99 = self.latency.percentiles(0.50, 0.95, 0.99)
         uptime = time.time() - self.started_at
@@ -611,6 +619,19 @@ class ServingMetrics:
             lines += [
                 f'kmls_shard_dispatch_total{{shard="{i}"}} {count}'
                 for i, count in enumerate(shard_counts)
+            ]
+        if shard_placement is not None:
+            # sharded model layout: the publication's placement time and
+            # what each device holds of the rule tensors
+            place_seconds, resident = shard_placement
+            lines += [
+                "# TYPE kmls_shard_place_seconds gauge",
+                f"kmls_shard_place_seconds {place_seconds:.6f}",
+                "# TYPE kmls_shard_resident_bytes gauge",
+            ]
+            lines += [
+                f'kmls_shard_resident_bytes{{shard="{i}"}} {nbytes}'
+                for i, nbytes in enumerate(resident)
             ]
         if mesh_shards:
             # pod-spanning serve mesh (ISSUE 16): shard health by state —

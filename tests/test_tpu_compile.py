@@ -16,19 +16,26 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from kmlserver_tpu.ops import embed as embed_ops
+from kmlserver_tpu.ops.serve import sharded_recommend_fn
 
 # benchmark/configs/mpd-hybrid.json: the Million Playlist Dataset's catalog
 V, RANK, K_BEST = 2262292, 32, 10
+# benchmark/configs/yambda-rules-sharded.json: Yambda-5B's catalog, four ways
+V_SHARDED, K_MAX, CHIP_LIMIT = 9390000, 256, 16909336064
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -99,3 +106,34 @@ def test_embedding_tile_step_fuses_the_max_into_the_product(
     assert any(" convolution(" in line for line in body)
     root = next(line for line in body if "ROOT" in line)
     assert " reduce(" in root and f"{tile}]" in root, root[:160]
+
+
+@pytest.mark.parametrize("batch,length", [(1, 1), (32, 128)])
+def test_sharded_rule_lookup_fits_four_chips_under_the_traced_name(
+    topo, no_compile_cache, batch, length
+):
+    """The vocabulary-sharded lookup at the four-chip cell's catalog: the
+    chip's compiler accepts it, each device is handed a quarter of the
+    19.23 GB of rule rows that no chip holds whole, arguments and
+    temporaries fit a chip, the partials cross the mesh, and the module
+    carries the name the benchmark's trace readers look for."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    mesh = Mesh(np.asarray(topo.devices), ("shard",))
+    rows = NamedSharding(mesh, P("shard", None))
+    ids = jax.ShapeDtypeStruct((V_SHARDED, K_MAX), jnp.int32, sharding=rows)
+    confs = jax.ShapeDtypeStruct((V_SHARDED, K_MAX), jnp.float32, sharding=rows)
+    seeds = jax.ShapeDtypeStruct(
+        (batch, length), jnp.int32, sharding=NamedSharding(mesh, P(None, None))
+    )
+    compiled = sharded_recommend_fn(mesh, K_BEST).lower(ids, confs, seeds).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit__recommend_batch_sharded")
+    assert "all-gather" in hlo
+    memory = compiled.memory_analysis()
+    resident = V_SHARDED // 4 * K_MAX * 8
+    assert V_SHARDED * K_MAX * 8 > CHIP_LIMIT  # no chip holds the rows whole
+    assert resident <= memory.argument_size_in_bytes < resident + (1 << 20)
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < CHIP_LIMIT // 2
