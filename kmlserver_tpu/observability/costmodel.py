@@ -172,25 +172,26 @@ def _serve_bytes(dims: dict) -> float:
     )
 
 
+def _merge_flops(dims: dict) -> float:
+    # the cross-shard merge ranks the shards·k_best gathered lanes of a
+    # row pairwise (ops/serve.py _merge_partial_topk_impl): ≈2 compares
+    # per (lane, lane) pair, nothing of width v
+    b, shards, k_best = _d(dims, "b"), _d(dims, "shards"), _d(dims, "k_best", 10)
+    return b * 2.0 * (shards * k_best) ** 2
+
+
 def _sharded_serve_flops(dims: dict) -> float:
     # per-shard work is the replicated kernel partitioned (same total),
-    # plus the cross-shard merge: shards·k_best candidate lanes per row
-    # rescattered + one more global top-k
-    b, v = _d(dims, "b"), _d(dims, "v")
-    shards, k_best = _d(dims, "shards"), _d(dims, "k_best", 10)
-    return _serve_flops(dims) + b * (
-        2.0 * shards * k_best + v * _log2k(k_best)
-    )
+    # plus the cross-shard merge in candidate space
+    return _serve_flops(dims) + _merge_flops(dims)
 
 
 def _sharded_serve_bytes(dims: dict) -> float:
     # adds the all_gather of (shards, b, k_best) partials (both tensors,
-    # send+receive) and the merge pass's second (b, v+1) score vector
-    b, v = _d(dims, "b"), _d(dims, "v")
+    # send+receive); the merge reads only those lanes
+    b = _d(dims, "b")
     shards, k_best = _d(dims, "shards"), _d(dims, "k_best", 10)
-    return _serve_bytes(dims) + 2.0 * shards * b * k_best * 8.0 + b * (
-        v + 1.0
-    ) * 8.0
+    return _serve_bytes(dims) + 2.0 * shards * b * k_best * 8.0
 
 
 def _mesh_serve_flops(dims: dict) -> float:
@@ -202,22 +203,20 @@ def _mesh_serve_flops(dims: dict) -> float:
     b, length, k_max = _d(dims, "b"), _d(dims, "l"), _d(dims, "k_max")
     v, shards, k_best = _d(dims, "v"), _d(dims, "shards"), _d(dims, "k_best", 10)
     return b * (
-        2.0 * length * k_max / max(shards, 1.0)
-        + 2.0 * v * _log2k(k_best)
-        + 2.0 * shards * k_best
-    )
+        2.0 * length * k_max / max(shards, 1.0) + v * _log2k(k_best)
+    ) + _merge_flops(dims)
 
 
 def _mesh_serve_bytes(dims: dict) -> float:
-    # slab gather (1/shards of the rule lanes) + the partial and merge
-    # passes' (b, v+1) score vectors + the gang exchange: the seed batch
-    # sent to every peer and (shards-1) stacked (b, k_best) partials
-    # received over DCN (or the simulation transport's sockets)
+    # slab gather (1/shards of the rule lanes) + the partial pass's
+    # (b, v+1) score vector + the gang exchange: the seed batch sent to
+    # every peer and (shards-1) stacked (b, k_best) partials received
+    # over DCN (or the simulation transport's sockets)
     b, length, k_max = _d(dims, "b"), _d(dims, "l"), _d(dims, "k_max")
     v, shards, k_best = _d(dims, "v"), _d(dims, "shards"), _d(dims, "k_best", 10)
     return (
         b * length * (k_max * 8.0 / max(shards, 1.0) + 4.0)
-        + 2.0 * b * (v + 1.0) * 8.0
+        + b * (v + 1.0) * 8.0
         + (shards - 1.0) * b * (k_best * 8.0 + length * 4.0)
         + b * k_best * 8.0
     )

@@ -13,8 +13,11 @@ Semantics parity notes:
   exclude seeds — only each row's own antecedent is absent from its row);
 - merge is max over per-seed confidences (defaultdict max-merge at :240-247),
   then descending sort, then top ``K_BEST_TRACKS`` (:250-253). ``top_k``'s
-  tie order (by index) stands in for Python's stable sort order on ties; the
-  set of returned confidences is identical.
+  tie order (by index, on the CPU) stands in for Python's stable sort order
+  on ties; the set of returned confidences is identical. On the chip
+  ``top_k`` over V columns keeps no index order among equal confidences
+  (PERF.md §6, PR 39): which of equally confident ids come back, and in
+  what order, is the compiler's.
 """
 
 from __future__ import annotations
@@ -33,15 +36,17 @@ def _masked_topk_from_candidates(
     v: int,
     k_best: int,
 ):
-    """THE kernel epilogue, shared by every lookup variant: max-merge
-    (id, conf) candidate lanes into a (B, V) score vector (dead lanes —
-    id < 0 or conf ≤ 0 — dump into a spill slot V, sliced off), then the
-    canonical masked top-k: ids with conf ≤ 0 become -1, columns
-    statically padded up to ``k_best``. One copy on purpose — the
-    replicated kernel, the per-shard partials, and the cross-shard merge
-    all route through it, which is what makes the layout bit-identity
-    contract (tests/test_shard_layout.py) a structural property instead
-    of three hand-kept copies."""
+    """The gather kernels' epilogue: max-merge (id, conf) candidate lanes
+    into a (B, V) score vector (dead lanes — id < 0 or conf ≤ 0 — dump
+    into a spill slot V, sliced off), then the canonical masked top-k:
+    ids with conf ≤ 0 become -1, columns statically padded up to
+    ``k_best``. The replicated kernel and the per-shard partials route
+    through it (up to L·K_max lanes, where a sort of the lanes costs the
+    chip's compiler seconds per shape); the cross-shard merge, which
+    always has S·k_best lanes, ranks them in candidate space instead
+    (:func:`_merge_partial_topk_impl`), to the same answer: bit for bit
+    on the CPU (tests/test_shard_layout.py), up to the order of equal
+    confidences on the chip, whose ``top_k`` keeps no index order."""
     b = cand_ids.shape[0]
     live = (cand_ids >= 0) & (cand_confs > 0)
     targets = jnp.where(live, cand_ids, v)
@@ -105,15 +110,21 @@ recommend_batch = partial(jax.jit, static_argnames=("k_best",))(
 #      ~K_max× smaller than the resident rule rows, so full width per shard
 #      is the cheap axis), and takes a per-shard top-k partial,
 #   3. all_gather of the (B, k) partials over the shard axis, then a
-#      max-merge rescatter + final top-k — replicated on every shard.
+#      merge that ranks the S·k gathered lanes among themselves (equal ids
+#      max-merged, order by conf desc then id asc) — nothing of width V,
+#      replicated on every shard.
 #
 # Exactness, including lax.top_k's index tie order: for any consequent in
 # the true global top-k, the shard where it attains its max partial score
 # must rank it inside ITS top-k (fewer than k competitors beat it there, or
 # they would beat it globally too), so the gathered candidate set contains
-# every true winner at its exact global score, and the merge's scatter-max
-# + top_k reproduces the replicated kernel's output bit for bit (pinned by
-# tests/test_shard_layout.py).
+# every true winner at its exact global score, and ranking those lanes by
+# (conf desc, id asc) — the order top_k over global ids gives on the CPU —
+# reproduces the replicated kernel's output bit for bit there (pinned by
+# tests/test_shard_layout.py against it and a numpy reference). On the chip
+# the partials' top_k keeps no index order among equal confidences, so the
+# layouts agree on every confidence and may differ in which equally
+# confident ids they return.
 # ---------------------------------------------------------------------------
 
 
@@ -154,23 +165,43 @@ def _merge_partial_topk_impl(
     all_ids: jax.Array,  # int32 (S, B, k_best) partials, SHARD order
     all_confs: jax.Array,  # float32 (S, B, k_best)
     *,
-    v: int,
     k_best: int,
 ):
     """Cross-shard max-merge of per-shard partials → final (B, k_best).
 
-    Every shard's masked partial lanes become candidates for one more
-    pass through the shared epilogue. The leading axis must be in shard
-    order (all_gather's axis order inside shard_map; ascending gang rank
-    on the serve mesh) — the epilogue's scatter-max is order-invariant
-    in value, and top_k's index tie order sees only GLOBAL ids, so the
-    merge is bit-identical either way."""
+    Ranks the S·k_best gathered lanes of each row among themselves, by
+    pairwise comparison: no sort, nothing of vocabulary width. A lane is
+    live when id ≥ 0 and conf > 0. Of the live lanes that share an id
+    (one per shard at most, but any number is handled) the one with the
+    highest conf, the lowest lane on a tie, represents it — the max-merge.
+    A representative's rank is the count of representatives ahead of it
+    by (conf desc, id asc), the dense epilogue's order on the CPU;
+    ranks below ``k_best`` fill their slot, the rest stay -1 / 0. The
+    answer is the dense scatter-max + top-k's whatever the shard order;
+    inside shard_map it is all_gather's, on the serve mesh ascending gang
+    rank."""
     s, b, k = all_ids.shape
-    return _masked_topk_from_candidates(
-        jnp.swapaxes(all_ids, 0, 1).reshape(b, s * k),
-        jnp.swapaxes(all_confs, 0, 1).reshape(b, s * k),
-        v=v, k_best=k_best,
-    )
+    n = s * k
+    ids = jnp.swapaxes(all_ids, 0, 1).reshape(b, n)
+    confs = jnp.swapaxes(all_confs, 0, 1).reshape(b, n)
+    live = (ids >= 0) & (confs > 0)
+    confs = jnp.where(live, confs, 0.0)
+    # [b, i, j]: lane j against lane i
+    c_i, c_j = confs[:, :, None], confs[:, None, :]
+    id_i, id_j = ids[:, :, None], ids[:, None, :]
+    lane = jnp.arange(n, dtype=jnp.int32)
+    first = (lane[None, :] < lane[:, None])[None]
+    shadowed = (live[:, None, :] & (id_j == id_i)
+                & ((c_j > c_i) | ((c_j == c_i) & first)))
+    rep = live & ~shadowed.any(axis=2)
+    ahead = rep[:, None, :] & ((c_j > c_i) | ((c_j == c_i) & (id_j < id_i)))
+    rank = ahead.sum(axis=2, dtype=jnp.int32)
+    slot = rep[:, :, None] & (
+        rank[:, :, None] == jnp.arange(k_best, dtype=jnp.int32)
+    )  # (B, n, k_best): at most one lane per slot
+    top_ids = jnp.where(slot, ids[:, :, None], -1).max(axis=1)
+    top_confs = jnp.where(slot, confs[:, :, None], 0.0).max(axis=1)
+    return top_ids, top_confs
 
 
 # Jitted module-level twins for the multi-process serve mesh
@@ -183,7 +214,7 @@ def _merge_partial_topk_impl(
 shard_partial_topk = partial(jax.jit, static_argnames=("v", "k_best"))(
     _shard_partial_topk_impl
 )
-merge_partial_topk = partial(jax.jit, static_argnames=("v", "k_best"))(
+merge_partial_topk = partial(jax.jit, static_argnames=("k_best",))(
     _merge_partial_topk_impl
 )
 
@@ -205,9 +236,7 @@ def _sharded_recommend_local(
     )
     all_ids = jax.lax.all_gather(part_ids, axis)  # (S, B, k_best)
     all_confs = jax.lax.all_gather(part_confs, axis)
-    return _merge_partial_topk_impl(
-        all_ids, all_confs, v=v, k_best=k_best,
-    )
+    return _merge_partial_topk_impl(all_ids, all_confs, k_best=k_best)
 
 
 @functools.lru_cache(maxsize=8)
@@ -221,7 +250,7 @@ def sharded_recommend_fn(mesh, k_best: int, axis: str = "shard"):
     ``NamedSharding(mesh, P(axis, None))`` with the padded vocab length a
     multiple of the shard count; ``seed_ids`` replicated. Output
     (replicated) is bit-identical to :func:`recommend_batch` on the same
-    (unpadded) tensors."""
+    (unpadded) tensors, on the chip up to equal confidences."""
     from jax.sharding import PartitionSpec as P
 
     n_shards = mesh.shape[axis]

@@ -1182,10 +1182,7 @@ class RecommendEngine:
                         part_confs, (bundle.n_shards,) + part_confs.shape
                     )
                     jax.block_until_ready(
-                        merge_partial_topk(
-                            stack_ids, stack_confs,
-                            v=bundle.mesh_v, k_best=kb,
-                        )
+                        merge_partial_topk(stack_ids, stack_confs, k_best=kb)
                     )
                 else:
                     jax.block_until_ready(
@@ -1802,7 +1799,7 @@ class RecommendEngine:
                 stack_ids[rank] = 0
                 stack_confs[rank] = np.float32(-np.inf)
             merged_ids, merged_confs = merge_partial_topk(
-                stack_ids, stack_confs, v=bundle.mesh_v, k_best=kb
+                stack_ids, stack_confs, k_best=kb
             )
             return np.asarray(merged_ids), np.asarray(merged_confs)
 

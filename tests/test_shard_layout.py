@@ -34,7 +34,11 @@ from kmlserver_tpu.io import registry
 from kmlserver_tpu.mining import checkpoint as ckpt_mod
 from kmlserver_tpu.mining.miner import mine
 from kmlserver_tpu.mining.pipeline import run_mining_job
-from kmlserver_tpu.ops.serve import recommend_batch, sharded_recommend_fn
+from kmlserver_tpu.ops.serve import (
+    merge_partial_topk,
+    recommend_batch,
+    sharded_recommend_fn,
+)
 from kmlserver_tpu.parallel.layout import resolve_layout, validate_layout
 from kmlserver_tpu.parallel.mesh import make_mesh
 from kmlserver_tpu.serving.engine import RecommendEngine
@@ -113,6 +117,110 @@ class TestShardedKernel:
         got = sharded_recommend_fn(mesh, k_best)(ids_sh, confs_sh, seeds)
         np.testing.assert_array_equal(np.asarray(ref[0]), np.asarray(got[0]))
         np.testing.assert_array_equal(np.asarray(ref[1]), np.asarray(got[1]))
+
+
+def _dense_reference(cand_ids, cand_confs, v, k_best):
+    """The dense lookup's semantics in numpy, independent of the kernels:
+    scatter-max the live lanes (id ≥ 0, conf > 0) into V+1 columns, dead
+    ones into the spill column V; a stable sort of the V columns by
+    (-conf, id); the first ``k_best``, -1 where conf ≤ 0, padded to
+    ``k_best`` columns when V < k_best."""
+    b = cand_ids.shape[0]
+    top_ids = np.full((b, k_best), -1, np.int32)
+    top_confs = np.zeros((b, k_best), np.float32)
+    for row in range(b):
+        live = (cand_ids[row] >= 0) & (cand_confs[row] > 0)
+        scores = np.zeros(v + 1, np.float32)
+        np.maximum.at(
+            scores, np.where(live, cand_ids[row], v),
+            np.where(live, cand_confs[row], np.float32(0)),
+        )
+        scores = scores[:v]
+        order = np.argsort(-scores, kind="stable")[:k_best]
+        top_ids[row, : len(order)] = np.where(scores[order] > 0, order, -1)
+        top_confs[row, : len(order)] = scores[order]
+    return top_ids, top_confs
+
+
+_LEVELS = np.array([0.25, 0.5, 0.75, 1.0], np.float32)
+
+
+def _shard_partials(rng, n_shards, batch, v, k_best):
+    """(S, B, k_best) partials as the shards hand them to the merge: each
+    a deduplicated top-k of a shard, ids drawn from a pool of 2·k_best so
+    that ids repeat across shards, at quantized confs so that they repeat
+    at equal and at different confs and different ids tie, dead lanes
+    -1 / 0. Row 0 is written by hand (5 distinct ids, fewer than k_best,
+    an id repeated at an equal and one at a different conf); the last of
+    three or more rows is all dead; row 1 carries a rank the serve mesh
+    dropped (ids 0, conf -inf)."""
+    ids = np.full((n_shards, batch, k_best), -1, np.int32)
+    confs = np.zeros((n_shards, batch, k_best), np.float32)
+    pool = min(v, 2 * k_best)
+    for shard in range(n_shards):
+        for row in range(batch):
+            n = int(rng.integers(0, min(pool, k_best) + 1))
+            lane_ids = rng.choice(pool, size=n, replace=False)
+            lane_confs = rng.choice(_LEVELS, size=n)
+            order = np.lexsort((lane_ids, -lane_confs))
+            ids[shard, row, :n] = lane_ids[order]
+            confs[shard, row, :n] = lane_confs[order]
+    ids[:, 0], confs[:, 0] = -1, 0.0
+    ids[0, 0, :4], confs[0, 0, :4] = [4, 2, 6, 1], [0.75, 0.5, 0.5, 0.25]
+    ids[1, 0, :3], confs[1, 0, :3] = [2, 0, 4], [0.5, 0.5, 0.25]
+    if batch >= 2:
+        ids[-1, 1], confs[-1, 1] = 0, -np.inf
+    if batch >= 3:
+        ids[:, -1], confs[:, -1] = -1, 0.0
+    return ids, confs
+
+
+class TestMergeAgainstDenseReference:
+    """ISSUE 39: the cross-shard merge ranks its S·k_best lanes in
+    candidate space; both the merge alone and the whole sharded lookup
+    are held bit for bit to a numpy reference of the dense semantics
+    (not only to the replicated kernel, which is the other jitted path)."""
+
+    @pytest.mark.parametrize("v", [7, 300])
+    @pytest.mark.parametrize("batch", [1, 3, 32])
+    @pytest.mark.parametrize("n_shards", [2, 4, 8])
+    @pytest.mark.parametrize("kernel", ["merge", "sharded"])
+    def test_bit_identical_to_dense_reference(
+        self, kernel, n_shards, batch, v
+    ):
+        from jax.sharding import Mesh
+
+        k_best = 10
+        rng = np.random.default_rng([n_shards, batch, v])
+        if kernel == "merge":
+            ids, confs = _shard_partials(rng, n_shards, batch, v, k_best)
+            got = merge_partial_topk(ids, confs, k_best=k_best)
+            cand_ids = np.swapaxes(ids, 0, 1).reshape(batch, -1)
+            cand_confs = np.swapaxes(confs, 0, 1).reshape(batch, -1)
+        else:
+            rule_ids, rule_confs = _random_rule_tensors(rng, v, min(v, 6))
+            seeds = rng.integers(-1, v, size=(batch, 5)).astype(np.int32)
+            if batch >= 3:
+                seeds[-1] = -1
+            mesh = Mesh(np.asarray(jax.devices()[:n_shards]), ("shard",))
+            ids_sh, confs_sh = _shard_tensors(mesh, rule_ids, rule_confs)
+            got = sharded_recommend_fn(mesh, k_best)(ids_sh, confs_sh, seeds)
+            valid = (seeds >= 0)[..., None] & (rule_ids[seeds] >= 0)
+            cand_ids = np.where(valid, rule_ids[seeds], -1).reshape(batch, -1)
+            cand_confs = np.where(valid, rule_confs[seeds], 0).reshape(batch, -1)
+        ref = _dense_reference(cand_ids, cand_confs, v, k_best)
+        np.testing.assert_array_equal(np.asarray(got[0]), ref[0])
+        np.testing.assert_array_equal(np.asarray(got[1]), ref[1])
+        if kernel == "merge":
+            # the hand-written row: max-merged repeats, then (conf, id)
+            np.testing.assert_array_equal(ref[0][0, :6], [4, 0, 2, 6, 1, -1])
+        if batch == 32 and v == 300:
+            # somewhere in the batch the k-th and the next id tie
+            wider = _dense_reference(cand_ids, cand_confs, v, k_best + 1)[1]
+            cut = wider[:, k_best]
+            assert ((cut > 0) & (cut == wider[:, k_best - 1])).any()
+        if batch >= 3:
+            assert (ref[0][-1] == -1).all()
 
 
 class TestLayoutResolution:

@@ -16,7 +16,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from kmlserver_tpu.ops import embed as embed_ops
-from kmlserver_tpu.ops.serve import sharded_recommend_fn
+from kmlserver_tpu.ops.serve import (
+    merge_partial_topk,
+    shard_partial_topk,
+    sharded_recommend_fn,
+)
 
 # benchmark/configs/mpd-hybrid.json: the Million Playlist Dataset's catalog
 V, RANK, K_BEST = 2262292, 32, 10
@@ -51,6 +55,24 @@ def no_compile_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", before)
     compilation_cache.reset_cache()
+
+
+def _widest_dims(hlo: str) -> list[int]:
+    """Compiled HLO text → for each instruction line, the largest dimension
+    of any array shape it names (result or operand)."""
+    out = []
+    for line in hlo.splitlines():
+        if " = " not in line:
+            continue
+        dims = [
+            int(d)
+            for m in re.finditer(r"\b[a-z]+\d*\[([\d,]+)\]", line)
+            for d in m.group(1).split(",")
+            if d
+        ]
+        if dims:
+            out.append(max(dims))
+    return out
 
 
 def _computations(hlo: str) -> dict[str, list[str]]:
@@ -137,3 +159,50 @@ def test_sharded_rule_lookup_fits_four_chips_under_the_traced_name(
     assert V_SHARDED * K_MAX * 8 > CHIP_LIMIT  # no chip holds the rows whole
     assert resident <= memory.argument_size_in_bytes < resident + (1 << 20)
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < CHIP_LIMIT // 2
+
+
+@pytest.mark.parametrize("batch,length", [(1, 1), (32, 128)])
+def test_cross_shard_merge_ranks_candidates_not_the_vocabulary(
+    topo, one_chip, no_compile_cache, batch, length
+):
+    """ISSUE 39: the merge of the four shards' partials works on their
+    S·k_best = 40 lanes alone: no instruction names a dimension of the
+    vocabulary's width, its temporaries are under 1 MB, and the whole
+    sharded program has no more V-wide instructions than one shard's
+    partial alone (a dense merge doubles them: 15 → 30 at (1, 1))."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    n_shards = 4
+    gathered = (n_shards, batch, K_BEST)
+    merge = merge_partial_topk.lower(
+        jax.ShapeDtypeStruct(gathered, jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct(gathered, jnp.float32, sharding=one_chip),
+        k_best=K_BEST,
+    ).compile()
+    assert max(_widest_dims(merge.as_text())) <= max(batch, n_shards * K_BEST)
+    assert merge.memory_analysis().temp_size_in_bytes < 1 << 20
+
+    mesh = Mesh(np.asarray(topo.devices), ("shard",))
+    rows = NamedSharding(mesh, P("shard", None))
+    whole = sharded_recommend_fn(mesh, K_BEST).lower(
+        jax.ShapeDtypeStruct((V_SHARDED, K_MAX), jnp.int32, sharding=rows),
+        jax.ShapeDtypeStruct((V_SHARDED, K_MAX), jnp.float32, sharding=rows),
+        jax.ShapeDtypeStruct(
+            (batch, length), jnp.int32, sharding=NamedSharding(mesh, P(None, None))
+        ),
+    ).compile()
+    v_loc = V_SHARDED // n_shards
+    part = shard_partial_topk.lower(
+        jax.ShapeDtypeStruct((v_loc, K_MAX), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((v_loc, K_MAX), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((batch, length), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        v=V_SHARDED, k_best=K_BEST,
+    ).compile()
+
+    def v_wide(compiled):
+        return sum(d >= V_SHARDED for d in _widest_dims(compiled.as_text()))
+
+    assert 0 < v_wide(whole) <= v_wide(part)
