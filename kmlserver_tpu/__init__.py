@@ -11,7 +11,7 @@ This package re-implements every component TPU-first:
 - ``ops/``      — the compute kernels (JAX/XLA, Pallas): one-hot / bit-packed
                   basket encoding, MXU pair-support counting (``XᵀX``),
                   itemset extension, rule-tensor emission, and the serve-time
-                  gather → scatter-max → top-k recommendation kernel.
+                  gather → max-merge → top-k recommendation kernel.
 - ``parallel/`` — device-mesh sharding of the mining compute: data-parallel
                   ``psum`` over the transaction axis, tensor-parallel sharding
                   of the item axis with all-gather and ring (``ppermute``)

@@ -11,7 +11,7 @@ production — the invariant that until now lived only in tests.
 Three pieces:
 
 - **Analytic cost specs** (:data:`KERNEL_COST_SPECS`): for every jitted
-  kernel the project dispatches — the rule scatter-max serve kernel
+  kernel the project dispatches — the rule lookup serve kernel
   (``ops/serve.py recommend_batch``), its vocab-sharded twin
   (``sharded_recommend_fn``), the embedding cosine top-k
   (``ops/embed.py embed_topk``), the ALS half-sweeps (``mining/als.py``), the pair-support count
@@ -152,22 +152,34 @@ def _d(dims: dict, key: str, default: float = 1.0) -> float:
     return float(dims.get(key, default))
 
 
+def _rank_lanes_flops(lanes: float, k_best: float) -> float:
+    # the epilogue's k_best rounds over a row's candidate lanes
+    # (ops/serve.py _masked_topk_from_candidates): max, compare, min and
+    # mask, ≈4 ops per lane a round; nothing of width v
+    return 4.0 * k_best * lanes
+
+
+def _rank_lanes_bytes(lanes: float, k_best: float) -> float:
+    # each round reads a lane's id and conf and writes its conf back
+    return 12.0 * k_best * lanes
+
+
 def _serve_flops(dims: dict) -> float:
-    # gather + scatter-max over b·l·k_max candidate lanes (≈2 ops per
-    # lane: compare + select), then top-k over the (b, v) score vector
+    # mask the b·l·k_max gathered lanes (≈2 ops per lane: compare +
+    # select), then rank them in k_best rounds
     b, length, k_max = _d(dims, "b"), _d(dims, "l"), _d(dims, "k_max")
-    v, k_best = _d(dims, "v"), _d(dims, "k_best", 10)
-    return b * (2.0 * length * k_max + v * _log2k(k_best))
+    lanes, k_best = b * length * k_max, _d(dims, "k_best", 10)
+    return 2.0 * lanes + _rank_lanes_flops(lanes, k_best)
 
 
 def _serve_bytes(dims: dict) -> float:
-    # rule-row gather (ids+confs, 8 B/lane), the transient (b, v+1)
-    # score vector written+read, seeds in, top-k out
+    # rule-row gather (ids+confs, 8 B/lane), the rounds over those lanes,
+    # seeds in, top-k out
     b, length, k_max = _d(dims, "b"), _d(dims, "l"), _d(dims, "k_max")
-    v, k_best = _d(dims, "v"), _d(dims, "k_best", 10)
+    lanes, k_best = b * length * k_max, _d(dims, "k_best", 10)
     return (
         b * length * (k_max * 8.0 + 4.0)
-        + b * (v + 1.0) * 8.0
+        + _rank_lanes_bytes(lanes, k_best)
         + b * k_best * 8.0
     )
 
@@ -181,42 +193,52 @@ def _merge_flops(dims: dict) -> float:
 
 
 def _sharded_serve_flops(dims: dict) -> float:
-    # per-shard work is the replicated kernel partitioned (same total),
-    # plus the cross-shard merge in candidate space
-    return _serve_flops(dims) + _merge_flops(dims)
+    # the replicated kernel's work, but every shard's partial ranks all
+    # the lanes (the other shards' seeds masked dead), plus the
+    # cross-shard merge in candidate space
+    b, length, k_max = _d(dims, "b"), _d(dims, "l"), _d(dims, "k_max")
+    shards, k_best = _d(dims, "shards"), _d(dims, "k_best", 10)
+    extra = (shards - 1.0) * _rank_lanes_flops(b * length * k_max, k_best)
+    return _serve_flops(dims) + extra + _merge_flops(dims)
 
 
 def _sharded_serve_bytes(dims: dict) -> float:
-    # adds the all_gather of (shards, b, k_best) partials (both tensors,
-    # send+receive); the merge reads only those lanes
-    b = _d(dims, "b")
+    # the other shards' rounds, and the all_gather of (shards, b, k_best)
+    # partials (both tensors, send+receive); the merge reads only those
+    # lanes
+    b, length, k_max = _d(dims, "b"), _d(dims, "l"), _d(dims, "k_max")
     shards, k_best = _d(dims, "shards"), _d(dims, "k_best", 10)
-    return _serve_bytes(dims) + 2.0 * shards * b * k_best * 8.0
+    extra = (shards - 1.0) * _rank_lanes_bytes(b * length * k_max, k_best)
+    return _serve_bytes(dims) + extra + 2.0 * shards * b * k_best * 8.0
 
 
 def _mesh_serve_flops(dims: dict) -> float:
     # ONE gang member's share of the pod-spanning lookup: the sharded
-    # kernel's per-shard half (1/shards of the candidate-lane gather,
-    # one slab partial top-k at GLOBAL width) plus the coordinator-side
-    # merge over the rank-stacked partials — peers' slab work runs on
-    # peer processes and is attributed there
+    # kernel's per-shard half (1/shards of the candidate-lane gather; the
+    # slab partial's rounds pass over every lane, the other shards' seeds
+    # masked dead) plus the coordinator-side merge over the rank-stacked
+    # partials — peers' slab work runs on peer processes and is
+    # attributed there
     b, length, k_max = _d(dims, "b"), _d(dims, "l"), _d(dims, "k_max")
-    v, shards, k_best = _d(dims, "v"), _d(dims, "shards"), _d(dims, "k_best", 10)
-    return b * (
-        2.0 * length * k_max / max(shards, 1.0) + v * _log2k(k_best)
-    ) + _merge_flops(dims)
+    shards, k_best = _d(dims, "shards"), _d(dims, "k_best", 10)
+    lanes = b * length * k_max
+    return (
+        2.0 * lanes / max(shards, 1.0)
+        + _rank_lanes_flops(lanes, k_best)
+        + _merge_flops(dims)
+    )
 
 
 def _mesh_serve_bytes(dims: dict) -> float:
-    # slab gather (1/shards of the rule lanes) + the partial pass's
-    # (b, v+1) score vector + the gang exchange: the seed batch sent to
-    # every peer and (shards-1) stacked (b, k_best) partials received
-    # over DCN (or the simulation transport's sockets)
+    # slab gather (1/shards of the rule lanes) + the partial's rounds +
+    # the gang exchange: the seed batch sent to every peer and
+    # (shards-1) stacked (b, k_best) partials received over DCN (or the
+    # simulation transport's sockets)
     b, length, k_max = _d(dims, "b"), _d(dims, "l"), _d(dims, "k_max")
-    v, shards, k_best = _d(dims, "v"), _d(dims, "shards"), _d(dims, "k_best", 10)
+    shards, k_best = _d(dims, "shards"), _d(dims, "k_best", 10)
     return (
         b * length * (k_max * 8.0 / max(shards, 1.0) + 4.0)
-        + b * (v + 1.0) * 8.0
+        + _rank_lanes_bytes(b * length * k_max, k_best)
         + (shards - 1.0) * b * (k_best * 8.0 + length * 4.0)
         + b * k_best * 8.0
     )
@@ -327,8 +349,8 @@ def _sparse_als_bytes(dims: dict) -> float:
 KERNEL_COST_SPECS: dict[str, CostSpec] = {
     "serve_rules": CostSpec(
         "serve_rules", _serve_flops, _serve_bytes,
-        "replicated rule scatter-max + top-k (ops/serve.py "
-        "recommend_batch; dims b, l, k_max, v, k_best)",
+        "replicated rule gather + candidate-lane top-k (ops/serve.py "
+        "recommend_batch; dims b, l, k_max, k_best)",
     ),
     "serve_sharded": CostSpec(
         "serve_sharded", _sharded_serve_flops, _sharded_serve_bytes,

@@ -2,9 +2,10 @@
 
 Replaces the reference's per-request pure-Python dict max-merge + sort
 (reference: rest_api/app/main.py:224-254): seed songs' rule rows are gathered
-from the HBM-resident rule tensors, max-merged by scatter-max into a dense
-per-request score vector, and the top-K names extracted — batched over B
-concurrent requests so 1k QPS rides a handful of device calls.
+from the HBM-resident rule tensors, and their (id, conf) lanes max-merged
+and ranked among themselves, round by round, into the top-K names —
+batched over B concurrent requests so 1k QPS rides a handful of device
+calls.
 
 Semantics parity notes:
 - seeds absent from the rule tensors contribute nothing (the reference
@@ -12,12 +13,10 @@ Semantics parity notes:
 - a recommendation may be another seed song (the reference's merge does not
   exclude seeds — only each row's own antecedent is absent from its row);
 - merge is max over per-seed confidences (defaultdict max-merge at :240-247),
-  then descending sort, then top ``K_BEST_TRACKS`` (:250-253). ``top_k``'s
-  tie order (by index, on the CPU) stands in for Python's stable sort order
-  on ties; the set of returned confidences is identical. On the chip
-  ``top_k`` over V columns keeps no index order among equal confidences
-  (PERF.md §6, PR 39): which of equally confident ids come back, and in
-  what order, is the compiler's.
+  then descending sort, then top ``K_BEST_TRACKS`` (:250-253). Equal
+  confidences come back in ascending id order, on every backend: that
+  stands in for Python's stable sort order on ties; the set of returned
+  confidences is identical.
 """
 
 from __future__ import annotations
@@ -33,34 +32,49 @@ def _masked_topk_from_candidates(
     cand_ids: jax.Array,  # int32 (B, N) GLOBAL ids, -1 = dead lane
     cand_confs: jax.Array,  # float32 (B, N), 0 = dead lane
     *,
-    v: int,
     k_best: int,
 ):
-    """The gather kernels' epilogue: max-merge (id, conf) candidate lanes
-    into a (B, V) score vector (dead lanes — id < 0 or conf ≤ 0 — dump
-    into a spill slot V, sliced off), then the canonical masked top-k:
-    ids with conf ≤ 0 become -1, columns statically padded up to
-    ``k_best``. The replicated kernel and the per-shard partials route
-    through it (up to L·K_max lanes, where a sort of the lanes costs the
-    chip's compiler seconds per shape); the cross-shard merge, which
-    always has S·k_best lanes, ranks them in candidate space instead
-    (:func:`_merge_partial_topk_impl`), to the same answer: bit for bit
-    on the CPU (tests/test_shard_layout.py), up to the order of equal
-    confidences on the chip, whose ``top_k`` keeps no index order."""
+    """The gather kernels' epilogue: the max-merge of (id, conf) candidate
+    lanes and their masked top-k, ranked among the N lanes themselves.
+
+    A lane is live when id ≥ 0 and conf > 0. Each of ``k_best`` rounds
+    takes a row's highest live conf ``m``, emits the lowest id among the
+    lanes at ``m``, and kills every lane carrying that id: an id comes
+    out once, at its highest conf (the max-merge), in (conf desc, id asc)
+    order. Once a row's live lanes run out its slots read -1 / 0. No
+    sort and nothing of vocabulary width: the work is k_best passes over
+    the N = L·K_max lanes, whatever V is. The answer is the dense
+    scatter-max into (B, V) + ``top_k``'s, on every backend, with equal
+    confidences in ascending id order (tests/test_shard_layout.py holds
+    it to a numpy reference)."""
     b = cand_ids.shape[0]
     live = (cand_ids >= 0) & (cand_confs > 0)
-    targets = jnp.where(live, cand_ids, v)
     confs = jnp.where(live, cand_confs, 0.0)
-    scores = jnp.zeros((b, v + 1), dtype=cand_confs.dtype)
-    batch_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
-    scores = scores.at[batch_idx, targets].max(confs)[:, :v]
-    k = min(k_best, v)
-    top_confs, top_ids = jax.lax.top_k(scores, k)
-    top_ids = jnp.where(top_confs > 0, top_ids, -1)
-    if k < k_best:  # static pad so callers always see k_best columns
-        pad = ((0, 0), (0, k_best - k))
-        top_ids = jnp.pad(top_ids, pad, constant_values=-1)
-        top_confs = jnp.pad(top_confs, pad)
+    no_id = jnp.iinfo(jnp.int32).max
+
+    def one_round(j, carry):
+        confs, top_ids, top_confs = carry
+        m = confs.max(axis=1, keepdims=True)  # (B, 1)
+        hit = m > 0
+        pick = jnp.where(hit & (confs == m), cand_ids, no_id).min(
+            axis=1, keepdims=True
+        )
+        pick = jnp.where(hit, pick, -1)
+        confs = jnp.where(cand_ids == pick, 0.0, confs)
+        top_ids = jax.lax.dynamic_update_slice(top_ids, pick, (0, j))
+        top_confs = jax.lax.dynamic_update_slice(
+            top_confs, jnp.where(hit, m, 0.0), (0, j)
+        )
+        return confs, top_ids, top_confs
+
+    _, top_ids, top_confs = jax.lax.fori_loop(
+        0, k_best, one_round,
+        (
+            confs,
+            jnp.full((b, k_best), -1, jnp.int32),
+            jnp.zeros((b, k_best), cand_confs.dtype),
+        ),
+    )
     return top_ids, top_confs
 
 
@@ -72,7 +86,6 @@ def _recommend_batch_impl(
     k_best: int,
 ):
     """→ ``(top_ids int32 (B, k_best) with -1 padding, top_confs f32)``."""
-    v = rule_ids.shape[0]
     b = seed_ids.shape[0]
     safe_seeds = jnp.where(seed_ids >= 0, seed_ids, 0)
     gathered_ids = rule_ids[safe_seeds]  # (B, L, K)
@@ -81,7 +94,7 @@ def _recommend_batch_impl(
     return _masked_topk_from_candidates(
         jnp.where(valid, gathered_ids, -1).reshape(b, -1),
         jnp.where(valid, gathered_confs, 0.0).reshape(b, -1),
-        v=v, k_best=k_best,
+        k_best=k_best,
     )
 
 
@@ -105,26 +118,22 @@ recommend_batch = partial(jax.jit, static_argnames=("k_best",))(
 #   1. each shard maps the replicated seed batch onto its own row range
 #      (seeds outside the range contribute nothing — exactly the replicated
 #      kernel's membership semantics, partitioned),
-#   2. gathers + scatter-maxes its rows into a GLOBAL-width score vector
-#      (consequent ids span the full vocab; the transient (B, V) scores are
-#      ~K_max× smaller than the resident rule rows, so full width per shard
-#      is the cheap axis), and takes a per-shard top-k partial,
+#   2. gathers its rows and ranks their (GLOBAL id, conf) lanes among
+#      themselves into a per-shard top-k partial (the shared epilogue:
+#      nothing of width V, though consequent ids span the full vocab),
 #   3. all_gather of the (B, k) partials over the shard axis, then a
 #      merge that ranks the S·k gathered lanes among themselves (equal ids
 #      max-merged, order by conf desc then id asc) — nothing of width V,
 #      replicated on every shard.
 #
-# Exactness, including lax.top_k's index tie order: for any consequent in
-# the true global top-k, the shard where it attains its max partial score
-# must rank it inside ITS top-k (fewer than k competitors beat it there, or
-# they would beat it globally too), so the gathered candidate set contains
-# every true winner at its exact global score, and ranking those lanes by
-# (conf desc, id asc) — the order top_k over global ids gives on the CPU —
-# reproduces the replicated kernel's output bit for bit there (pinned by
-# tests/test_shard_layout.py against it and a numpy reference). On the chip
-# the partials' top_k keeps no index order among equal confidences, so the
-# layouts agree on every confidence and may differ in which equally
-# confident ids they return.
+# Exactness, tie order included: for any consequent in the true global
+# top-k, the shard where it attains its max partial score must rank it
+# inside ITS top-k (fewer than k competitors beat it there, or they would
+# beat it globally too), so the gathered candidate set contains every true
+# winner at its exact global score, and ranking those lanes by (conf desc,
+# id asc) — the order of every partial — reproduces the replicated
+# kernel's output bit for bit, on every backend (pinned by
+# tests/test_shard_layout.py against it and a numpy reference).
 # ---------------------------------------------------------------------------
 
 
@@ -137,15 +146,16 @@ def _shard_partial_topk_impl(
     v: int,
     k_best: int,
 ):
-    """One shard's (B, k_best) top-k partial at GLOBAL ids and width.
+    """One shard's (B, k_best) top-k partial at GLOBAL ids.
 
     The seed batch is mapped onto this shard's row range [lo, lo+V_loc)
     (seeds outside contribute nothing — the replicated kernel's
     membership semantics, partitioned), its rows gathered, and the
-    candidates pushed through THE shared epilogue at the full vocab
-    width. ``lo`` is a traced scalar so one compiled program serves
-    every shard — inside shard_map it is ``axis_index * v_loc``; on a
-    serve-mesh gang member it is ``rank * v_loc``."""
+    candidates pushed through THE shared epilogue. ``lo`` is a traced
+    scalar so one compiled program serves every shard — inside
+    shard_map it is ``axis_index * v_loc``; on a serve-mesh gang member
+    it is ``rank * v_loc``. ``v``, the global vocab width, sizes
+    nothing: the epilogue's work is the candidates'."""
     v_loc = rule_ids_loc.shape[0]
     b = seed_ids.shape[0]
     in_shard = (seed_ids >= lo) & (seed_ids < lo + v_loc)
@@ -157,7 +167,7 @@ def _shard_partial_topk_impl(
     return _masked_topk_from_candidates(
         jnp.where(valid, gathered_ids, -1).reshape(b, -1),
         jnp.where(valid, gathered_confs, 0.0).reshape(b, -1),
-        v=v, k_best=k_best,
+        k_best=k_best,
     )
 
 
@@ -175,7 +185,7 @@ def _merge_partial_topk_impl(
     (one per shard at most, but any number is handled) the one with the
     highest conf, the lowest lane on a tie, represents it — the max-merge.
     A representative's rank is the count of representatives ahead of it
-    by (conf desc, id asc), the dense epilogue's order on the CPU;
+    by (conf desc, id asc), the order of every partial;
     ranks below ``k_best`` fill their slot, the rest stay -1 / 0. The
     answer is the dense scatter-max + top-k's whatever the shard order;
     inside shard_map it is all_gather's, on the serve mesh ascending gang
@@ -250,7 +260,7 @@ def sharded_recommend_fn(mesh, k_best: int, axis: str = "shard"):
     ``NamedSharding(mesh, P(axis, None))`` with the padded vocab length a
     multiple of the shard count; ``seed_ids`` replicated. Output
     (replicated) is bit-identical to :func:`recommend_batch` on the same
-    (unpadded) tensors, on the chip up to equal confidences."""
+    (unpadded) tensors."""
     from jax.sharding import PartitionSpec as P
 
     n_shards = mesh.shape[axis]
@@ -262,8 +272,9 @@ def sharded_recommend_fn(mesh, k_best: int, axis: str = "shard"):
         local, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(None, None)),
         out_specs=(P(None, None), P(None, None)),
-        # the all_gather makes both outputs mesh-invariant; the scatter
-        # updates carry no vma annotation the checker could follow
+        # the all_gather makes both outputs mesh-invariant; the epilogue's
+        # loop carry starts invariant and turns varying, which the
+        # checker refuses
         check_vma=False,
     )
 

@@ -1985,7 +1985,7 @@ class RecommendEngine:
         # varying batch dimension would compile a fresh kernel per distinct
         # size, and padding every batch to the 32-wide cap (the old scheme)
         # made a batch of 3 pay a 32-row kernel — ~8x the work on the
-        # scatter/top-k. Every bucket is pre-warmed at bundle publish.
+        # lane ranking. Every bucket is pre-warmed at bundle publish.
         n_rows = self._bucket_batch(max(len(seed_sets), 1))
         arr, seeds_dev, known_rows = self._stage_seeds(
             bundle, seed_sets, n_rows, length, trace

@@ -4,7 +4,8 @@ Layout-equivalence coverage, on the virtual 8-device CPU mesh:
 
 - kernel: the sharded lookup (per-shard gather/top-k + cross-device
   max-merge of the partials) is BIT-identical to the replicated kernel,
-  ties and padding included;
+  ties and padding included, and every rule program to a numpy
+  reference of the dense semantics;
 - serving: a sharded engine answers bit-identically to a replicated one
   across publications (epochs), presents as one replica, never compiles
   after publish on ANY warmed bucket, and exposes per-shard dispatch
@@ -37,6 +38,7 @@ from kmlserver_tpu.mining.pipeline import run_mining_job
 from kmlserver_tpu.ops.serve import (
     merge_partial_topk,
     recommend_batch,
+    shard_partial_topk,
     sharded_recommend_fn,
 )
 from kmlserver_tpu.parallel.layout import resolve_layout, validate_layout
@@ -53,13 +55,14 @@ def _clean_faults():
     faults.clear()
 
 
-def _random_rule_tensors(rng, v, k):
+def _random_rule_tensors(rng, v, k, levels=None):
     """Random padded rule tensors with deliberate confidence TIES (the
     tie order is half the bit-identity contract)."""
     rule_ids = np.full((v, k), -1, np.int32)
     rule_confs = np.zeros((v, k), np.float32)
     # quantized confidences: collisions guaranteed
-    levels = np.linspace(0.1, 1.0, 7).astype(np.float32)
+    if levels is None:
+        levels = np.linspace(0.1, 1.0, 7).astype(np.float32)
     for i in range(v):
         n = int(rng.integers(0, k + 1))
         ids = rng.choice(v, size=n, replace=False).astype(np.int32)
@@ -175,16 +178,62 @@ def _shard_partials(rng, n_shards, batch, v, k_best):
     return ids, confs
 
 
-class TestMergeAgainstDenseReference:
-    """ISSUE 39: the cross-shard merge ranks its S·k_best lanes in
-    candidate space; both the merge alone and the whole sharded lookup
-    are held bit for bit to a numpy reference of the dense semantics
-    (not only to the replicated kernel, which is the other jitted path)."""
+def _lookup_case(rng, batch, v):
+    """Rule tensors and a seed batch for the lookup programs. V = 7 is
+    under ``k_best``; V = 300 draws 5 seeds a request over rows of 6
+    lanes at 7 conf levels; V = 3000 draws 128 seeds over rows of 256,
+    N = 32,768 lanes a request, at 4,096 levels. Rule rows 0-2 are
+    written by hand and request 0 asks for them: id 4 from three seeds
+    at 0.75, 0.25 and 0.75, id 2 from two at 0.5, ids 0, 2 and 6 tied at
+    0.5, five distinct live ids in all. The last of three or more
+    requests has every lane dead."""
+    k_max, length, levels = 6, 5, None
+    if v == 3000:
+        k_max, length = 256, 128
+        levels = (np.arange(1, 4097) / 4096).astype(np.float32)
+    rule_ids, rule_confs = _random_rule_tensors(rng, v, min(v, k_max), levels)
+    for row, (ids, confs) in enumerate([
+        ([4, 2, 6, 1], [0.75, 0.5, 0.5, 0.25]),
+        ([2, 0, 4], [0.5, 0.5, 0.25]),
+        ([4], [0.75]),
+    ]):
+        rule_ids[row], rule_confs[row] = -1, 0.0
+        rule_ids[row, : len(ids)], rule_confs[row, : len(ids)] = ids, confs
+    seeds = rng.integers(-1, v, size=(batch, length)).astype(np.int32)
+    seeds[0] = -1
+    seeds[0, :4] = [0, 1, 2, 0]
+    if batch >= 3:
+        seeds[-1] = -1
+    return rule_ids, rule_confs, seeds
 
-    @pytest.mark.parametrize("v", [7, 300])
+
+def _candidates(rule_ids, rule_confs, seeds, lo=0, hi=None):
+    """The (B, L·K_max) lanes the lookup ranks, from seeds in [lo, hi)."""
+    hi = rule_ids.shape[0] if hi is None else hi
+    in_range = (seeds >= lo) & (seeds < hi)
+    rows = np.where(in_range, seeds, 0)
+    valid = in_range[..., None] & (rule_ids[rows] >= 0)
+    batch = seeds.shape[0]
+    return (
+        np.where(valid, rule_ids[rows], -1).reshape(batch, -1),
+        np.where(valid, rule_confs[rows], 0).reshape(batch, -1),
+    )
+
+
+class TestMergeAgainstDenseReference:
+    """Every rule program is held bit for bit to a numpy reference of the
+    dense semantics: the cross-shard merge alone (its S·k_best partial
+    lanes), the replicated kernel, one shard's partial at every ``lo``,
+    and the whole sharded lookup. Each ranks its candidate lanes among
+    themselves, so the reference is the one independent judge. The
+    replicated kernel has no shards: ``n_shards`` draws its data."""
+
+    @pytest.mark.parametrize("v", [7, 300, 3000])
     @pytest.mark.parametrize("batch", [1, 3, 32])
     @pytest.mark.parametrize("n_shards", [2, 4, 8])
-    @pytest.mark.parametrize("kernel", ["merge", "sharded"])
+    @pytest.mark.parametrize(
+        "kernel", ["merge", "sharded", "replicated", "partial"]
+    )
     def test_bit_identical_to_dense_reference(
         self, kernel, n_shards, batch, v
     ):
@@ -198,22 +247,39 @@ class TestMergeAgainstDenseReference:
             cand_ids = np.swapaxes(ids, 0, 1).reshape(batch, -1)
             cand_confs = np.swapaxes(confs, 0, 1).reshape(batch, -1)
         else:
-            rule_ids, rule_confs = _random_rule_tensors(rng, v, min(v, 6))
-            seeds = rng.integers(-1, v, size=(batch, 5)).astype(np.int32)
-            if batch >= 3:
-                seeds[-1] = -1
+            rule_ids, rule_confs, seeds = _lookup_case(rng, batch, v)
+            cand_ids, cand_confs = _candidates(rule_ids, rule_confs, seeds)
+        if kernel == "sharded":
             mesh = Mesh(np.asarray(jax.devices()[:n_shards]), ("shard",))
             ids_sh, confs_sh = _shard_tensors(mesh, rule_ids, rule_confs)
             got = sharded_recommend_fn(mesh, k_best)(ids_sh, confs_sh, seeds)
-            valid = (seeds >= 0)[..., None] & (rule_ids[seeds] >= 0)
-            cand_ids = np.where(valid, rule_ids[seeds], -1).reshape(batch, -1)
-            cand_confs = np.where(valid, rule_confs[seeds], 0).reshape(batch, -1)
+        elif kernel == "replicated":
+            got = recommend_batch(rule_ids, rule_confs, seeds, k_best=k_best)
+        elif kernel == "partial":
+            v_loc = -(-v // n_shards)
+            pad = ((0, v_loc * n_shards - v), (0, 0))
+            ids_pad = np.pad(rule_ids, pad, constant_values=-1)
+            confs_pad = np.pad(rule_confs, pad)
+            for lo in range(0, v, v_loc):
+                part = shard_partial_topk(
+                    ids_pad[lo : lo + v_loc], confs_pad[lo : lo + v_loc],
+                    seeds, np.int32(lo), v=v_loc * n_shards, k_best=k_best,
+                )
+                ref = _dense_reference(
+                    *_candidates(rule_ids, rule_confs, seeds, lo, lo + v_loc),
+                    v, k_best,
+                )
+                np.testing.assert_array_equal(np.asarray(part[0]), ref[0])
+                np.testing.assert_array_equal(np.asarray(part[1]), ref[1])
+                assert part[1].dtype == np.float32
+            got = None
         ref = _dense_reference(cand_ids, cand_confs, v, k_best)
-        np.testing.assert_array_equal(np.asarray(got[0]), ref[0])
-        np.testing.assert_array_equal(np.asarray(got[1]), ref[1])
-        if kernel == "merge":
-            # the hand-written row: max-merged repeats, then (conf, id)
-            np.testing.assert_array_equal(ref[0][0, :6], [4, 0, 2, 6, 1, -1])
+        if got is not None:
+            np.testing.assert_array_equal(np.asarray(got[0]), ref[0])
+            np.testing.assert_array_equal(np.asarray(got[1]), ref[1])
+            assert got[1].dtype == np.float32
+        # the hand-written request: max-merged repeats, then (conf, id)
+        np.testing.assert_array_equal(ref[0][0, :6], [4, 0, 2, 6, 1, -1])
         if batch == 32 and v == 300:
             # somewhere in the batch the k-th and the next id tie
             wider = _dense_reference(cand_ids, cand_confs, v, k_best + 1)[1]

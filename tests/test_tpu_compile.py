@@ -18,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 from kmlserver_tpu.ops import embed as embed_ops
 from kmlserver_tpu.ops.serve import (
     merge_partial_topk,
+    recommend_batch,
     shard_partial_topk,
     sharded_recommend_fn,
 )
@@ -26,6 +27,9 @@ from kmlserver_tpu.ops.serve import (
 V, RANK, K_BEST = 2262292, 32, 10
 # benchmark/configs/yambda-rules-sharded.json: Yambda-5B's catalog, four ways
 V_SHARDED, K_MAX, CHIP_LIMIT = 9390000, 256, 16909336064
+# the rule epilogue's temporaries at any batch: it ranks candidate lanes,
+# not a (B, V+1) score array (2.4 GB at (32, 128) on the sharded catalog)
+EPILOGUE_TEMP_LIMIT = 16 << 20
 
 
 @pytest.fixture(scope="module")
@@ -72,6 +76,23 @@ def _widest_dims(hlo: str) -> list[int]:
         ]
         if dims:
             out.append(max(dims))
+    return out
+
+
+def _results(hlo: str) -> list[tuple[str, list[int]]]:
+    """Compiled HLO text → (opcode, dimensions of its result shape) for
+    each instruction line."""
+    out = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\([^)]*\)|\S+) ([\w\-]+)\(", line)
+        if m:
+            dims = [
+                int(d)
+                for shape in re.finditer(r"\b[a-z]+\d*\[([\d,]+)\]", m.group(1))
+                for d in shape.group(1).split(",")
+                if d
+            ]
+            out.append((m.group(2), dims))
     return out
 
 
@@ -165,11 +186,14 @@ def test_sharded_rule_lookup_fits_four_chips_under_the_traced_name(
 def test_cross_shard_merge_ranks_candidates_not_the_vocabulary(
     topo, one_chip, no_compile_cache, batch, length
 ):
-    """ISSUE 39: the merge of the four shards' partials works on their
-    S·k_best = 40 lanes alone: no instruction names a dimension of the
-    vocabulary's width, its temporaries are under 1 MB, and the whole
-    sharded program has no more V-wide instructions than one shard's
-    partial alone (a dense merge doubles them: 15 → 30 at (1, 1))."""
+    """The merge of the four shards' partials works on their S·k_best = 40
+    lanes alone: no instruction names a dimension of the vocabulary's
+    width, and its temporaries are under 1 MB. Neither one shard's
+    partial nor the whole sharded program names one either: each shard
+    ranks its own candidate lanes (a dense epilogue names V in 15
+    instructions of the partial at (1, 1), and a dense merge doubles
+    them in the whole program), and the partial's temporaries stay
+    small at every batch."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding
     from jax.sharding import PartitionSpec as P
@@ -205,4 +229,26 @@ def test_cross_shard_merge_ranks_candidates_not_the_vocabulary(
     def v_wide(compiled):
         return sum(d >= V_SHARDED for d in _widest_dims(compiled.as_text()))
 
-    assert 0 < v_wide(whole) <= v_wide(part)
+    assert v_wide(whole) == v_wide(part) == 0
+    assert part.memory_analysis().temp_size_in_bytes < EPILOGUE_TEMP_LIMIT
+
+
+@pytest.mark.parametrize("batch,length", [(1, 1), (32, 128)])
+def test_replicated_rule_lookup_writes_nothing_of_vocabulary_width(
+    one_chip, no_compile_cache, batch, length
+):
+    """The replicated kernel at the Million Playlist Dataset's catalog:
+    only the rule tables' own parameters are V rows long. No instruction
+    writes a result with a dimension of the vocabulary's width (a dense
+    epilogue writes a (B, V+1) score array and ranks it), and the
+    temporaries are a few MB at a full batch of the longest bucket
+    (579 MB for a dense epilogue)."""
+    compiled = recommend_batch.lower(
+        jax.ShapeDtypeStruct((V, K_MAX), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((V, K_MAX), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((batch, length), jnp.int32, sharding=one_chip),
+        k_best=K_BEST,
+    ).compile()
+    wide = [op for op, dims in _results(compiled.as_text()) if dims and max(dims) >= V]
+    assert wide and set(wide) == {"parameter"}, wide
+    assert compiled.memory_analysis().temp_size_in_bytes < EPILOGUE_TEMP_LIMIT
