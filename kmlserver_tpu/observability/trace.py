@@ -35,14 +35,18 @@ The trace id travels in the ``X-KMLS-Trace`` header (request:
 id), so a replay/bench client can join its client-side timing to the
 server-side span breakdown for the same request.
 
-Spans form a TREE (ISSUE 26): every span has a small integer id local
-to its trace and a parent id; the root (id 0) is the trace itself,
-``request`` or ``batch``. A dispatched batch has a trace of its own —
-``batch -> {stage, dispatch, fetch_rules, fetch_embed, compose,
-resolve}`` — and each member request's ``batch`` span names it by
-``batch_id``, so a request reads ``request -> {parse, cache, admit,
-queue, batch, respond, write}`` and the batch's inside is looked up
-once, not copied into every member.
+Spans form a TREE: every span has a small integer id local to its
+trace and a parent id; the root (id 0) is the trace itself, ``request``
+or ``batch``. A dispatched batch has a trace of its own —
+``batch -> {stage -> {fill_rules, put_rules}, dispatch ->
+{enqueue_rules, fill_embed, put_embed, enqueue_embed}, handoff,
+fetch_rules, fetch_embed, compose, resolve}`` — and each member
+request's ``batch`` span names it by ``batch_id``, so a request reads
+``request -> {parse, cache, admit, queue, batch, respond, write}`` and
+the batch's inside is looked up once, not copied into every member.
+``stage`` and ``dispatch`` take their ids when they begin
+(:meth:`TraceContext.reserve`), so their children, recorded while they
+are open, can name them; each keeps the endpoints it has without them.
 
 CAPTURE MODE puts the spans on the device trace's clock. While a
 ``/debug/profile`` capture is open (``utils/profiling.start_capture``
@@ -50,11 +54,14 @@ switches it; there is no knob) every request and every batch is traced,
 whatever the sample rate, and kept in a list of its own; the capture
 thread emits clock anchors into the profiler session
 (:func:`emit_clock_anchor`: a ``TraceAnnotation`` whose name carries
-``perf_counter_ns``), and when the capture closes the spans are written
-beside the ``.xplane.pb`` as ``kmls_spans.jsonl`` with the anchors in a
-header line. A reader maps ``perf_counter`` onto the capture's time
-base by the anchors alone. Every ``TraceAnnotation`` of the serving
-path lives in this module.
+``perf_counter_ns``) and, after each, runs :class:`ClockProbe` (a
+one-element program on every local device, between two
+``perf_counter_ns`` readings); when the capture closes the spans are
+written beside the ``.xplane.pb`` as ``kmls_spans.jsonl`` with the
+anchors and the probes in a header line. A reader maps ``perf_counter``
+onto the capture's host plane by the anchors, and each device plane
+onto it by its probes. Every ``TraceAnnotation`` of the serving path
+lives in this module.
 """
 
 from __future__ import annotations
@@ -99,6 +106,49 @@ def emit_clock_anchor() -> tuple[int, int]:
     with jax.profiler.TraceAnnotation(f"{CLOCK_ANCHOR_PREFIX}{named}"):
         opened = time.perf_counter_ns()
     return named, opened
+
+
+def kmls_clock_probe(x):
+    """The probe's program (its XLA module is ``jit_kmls_clock_probe``)."""
+    return x + 1
+
+
+class ClockProbe:
+    """One tiny program per local device, for the device planes' clock.
+
+    Built before a capture opens: it places its one-element inputs and
+    compiles the program on every device then, so that no compile lands
+    inside the capture. Each call runs the program on each device in
+    turn, ``rounds`` times over → ``[[device id, perf_counter_ns before
+    the call, perf_counter_ns after block_until_ready], ...]``. The
+    program's execution on that device's plane lies between the two
+    readings, so each probe bounds the plane's offset from the host
+    clock from both sides. On a TPU v5e the two readings lie 1.2-2.1 ms
+    apart around a program of 0.6 µs (the launch and the completion's
+    way back to the host), so a reader intersects several probes."""
+
+    rounds = 4
+
+    def __init__(self):
+        import jax
+        import numpy as np
+
+        self._run = jax.jit(kmls_clock_probe)
+        self._inputs = [
+            (d.id, jax.device_put(np.zeros((1,), np.int32), d))
+            for d in jax.local_devices()
+        ]
+        for _, x in self._inputs:
+            self._run(x).block_until_ready()
+
+    def __call__(self) -> list[list[int]]:
+        out = []
+        for _ in range(self.rounds):
+            for device_id, x in self._inputs:
+                before = time.perf_counter_ns()
+                self._run(x).block_until_ready()
+                out.append([device_id, before, time.perf_counter_ns()])
+        return out
 
 
 class TraceContext:
@@ -152,25 +202,46 @@ class TraceContext:
     def span(
         self, name: str, t_start: float, t_end: float,
         attrs: dict | None = None, parent: int = ROOT,
+        span_id: int | None = None,
     ) -> int:
         """Record a named span (perf_counter endpoints) under ``parent``
-        → its id. No-op (→ -1) once the trace is finished: a
-        deadline-expired request is retained at resolve time, and the
-        kernel's eventual completion must not rewrite what
-        ``/debug/traces`` already served."""
+        → its id (``span_id``, where :meth:`reserve` gave one). No-op
+        (→ -1) once the trace is finished: a deadline-expired request is
+        retained at resolve time, and the kernel's eventual completion
+        must not rewrite what ``/debug/traces`` already served."""
         if self.finished:
             return -1
-        span_id = next(self._ids)
+        if span_id is None:
+            span_id = next(self._ids)
         self.spans.append((span_id, parent, name, t_start, t_end, attrs))
         return span_id
 
-    def lap(self, name: str, attrs: dict | None = None) -> int:
+    def reserve(self) -> int:
+        """An id for a span that is still open, so that the spans inside
+        it can name it as their parent before it closes (it closes with
+        ``lap(..., span_id=)``). Ids follow the order spans begin in."""
+        return next(self._ids)
+
+    def lap(
+        self, name: str, attrs: dict | None = None,
+        span_id: int | None = None,
+    ) -> int:
         """Close a span that began where the last lap ended (or at the
         last :meth:`skip`) and ends now."""
         now = time.perf_counter()
-        span_id = self.span(name, self.cursor, now, attrs)
+        span_id = self.span(name, self.cursor, now, attrs, span_id=span_id)
         self.cursor = now
         return span_id
+
+    def child(
+        self, name: str, parent: int, t_start: float,
+        attrs: dict | None = None,
+    ) -> int:
+        """Record a span under the open span ``parent`` (an id from
+        :meth:`reserve`) from ``t_start`` to now. The lap cursor
+        does not move: the parent's own lap still starts and ends where
+        it would without its children."""
+        return self.span(name, t_start, time.perf_counter(), attrs, parent)
 
     def skip(self) -> None:
         """Move the lap cursor to now: what lies between the last lap
@@ -186,7 +257,9 @@ class TraceContext:
         file's form: the clock the anchors are on)."""
         t0 = self.t0
         rows = [(ROOT, None, self.kind, t0, t0 + self.duration_s, None)]
-        rows += list(self.spans)
+        # by id, which is the order the spans began in: a reserved parent
+        # is recorded after the children that name it
+        rows += sorted(self.spans)
         spans = []
         for span_id, parent, name, t_start, t_end, attrs in rows:
             span = {

@@ -1663,15 +1663,18 @@ class RecommendEngine:
         filled = arr[: len(seed_sets)] >= 0
         return filled.any(axis=1), int(filled.sum())
 
-    def _note_staged(self, arr: np.ndarray, n_real: int, trace) -> None:
+    def _note_staged(
+        self, arr: np.ndarray, n_real: int, trace, stage: int | None,
+    ) -> None:
         """The staged array is filled and on its way: count its slots
         (always on: two integer adds a batch) and, on a traced batch,
-        close the ``stage`` span and say what was staged."""
+        close the ``stage`` span (its id ``stage`` was reserved when it
+        began) and say what was staged."""
         with self._dispatch_lock:
             self.seed_slots_real += n_real
             self.seed_slots_padded += arr.size - n_real
         if trace is not None:
-            trace.lap("stage")
+            trace.lap("stage", span_id=stage)
             trace.attrs.update(
                 rows=arr.shape[0], length=arr.shape[1], seeds_real=n_real
             )
@@ -1689,12 +1692,18 @@ class RecommendEngine:
         device; the mesh-replicated sharding in the sharded layout), so a
         replica's dispatch runs on the replica's chip. ``trace`` is the
         batch's trace (None = untraced): its ``stage`` span ends where
-        the transfer has been issued."""
+        the transfer has been issued, and holds ``fill_rules`` (the array
+        filled) and ``put_rules`` (the transfer issued)."""
         shape = (rows, length)
+        stage = None
+        if trace is not None:
+            stage, t_fill = trace.reserve(), time.perf_counter()
         arr = np.full(shape, -1, dtype=np.int32)
         known_rows, n_real = self._fill_seed_rows(
             bundle, seed_sets, arr, length
         )
+        if trace is not None:
+            trace.child("fill_rules", stage, t_fill)
         if bundle.n_shards > 1 and bundle.shard_size > 0:
             # per-shard dispatch accounting: which vocab shard's rows
             # this batch's seed ids actually hit (host integer math on
@@ -1704,10 +1713,19 @@ class RecommendEngine:
                 self._note_shard_dispatch(np.bincount(
                     hit // bundle.shard_size, minlength=bundle.n_shards
                 ))
+        if trace is not None:
+            t_put = time.perf_counter()
         seeds_dev = jax.device_put(
             arr, bundle.seed_sharding or bundle.device
         )
-        self._note_staged(arr, n_real, trace)
+        if trace is not None:
+            # the mesh-replicated placement copies the array to each device
+            trace.child("put_rules", stage, t_put, {
+                "bytes": arr.nbytes,
+                "devices": len(bundle.seed_sharding.device_set)
+                if bundle.seed_sharding is not None else 1,
+            })
+        self._note_staged(arr, n_real, trace, stage)
         if shape not in bundle.warmed_shapes:
             # a compile is landing on the serving path — count it loudly
             self.unwarmed_dispatches += 1
@@ -1809,7 +1827,7 @@ class RecommendEngine:
 
     def _dispatch_embed(
         self, bundle: RuleBundle, seed_sets: list[list[str]],
-        n_rows: int, length: int,
+        n_rows: int, length: int, trace=None, parent: int | None = None,
     ):
         """Dispatch the embedding cosine top-k for a batch → ``(device
         top_ids, device top_sims, host known-row mask)``, or None when the
@@ -1819,9 +1837,14 @@ class RecommendEngine:
         span; the caller starts the device results' copies and its
         ``finish()`` picks them up. The (n_rows, length) shape must come
         from the warmed bucket grid — an unwarmed shape is counted and
-        logged exactly like the rule kernel's."""
+        logged exactly like the rule kernel's. On a traced batch
+        (``trace``; ``parent`` is the open ``dispatch`` span's id) the
+        fill, the transfer and the enqueue are ``fill_embed``,
+        ``put_embed`` and ``enqueue_embed``."""
         if bundle.emb_factors is None or self.cfg.hybrid_mode == "rules":
             return None
+        if trace is not None:
+            t_fill = time.perf_counter()
         arr = np.full((n_rows, length), -1, dtype=np.int32)
         known = np.zeros(len(seed_sets), dtype=bool)
         index = bundle.emb_index or {}
@@ -1829,11 +1852,20 @@ class RecommendEngine:
             ids = [index[s] for s in seeds if s in index][:length]
             arr[r, : len(ids)] = ids
             known[r] = len(ids) > 0
+        if trace is not None:
+            trace.child("fill_embed", parent, t_fill)
         if not known.any():
             # no row has an embed-known seed: the kernel's output would be
             # ignored wholesale — skip the transfer + full-vocab matmul
             return None
+        if trace is not None:
+            t_put = time.perf_counter()
         seeds_dev = jax.device_put(arr, bundle.device)
+        if trace is not None:
+            trace.child(
+                "put_embed", parent, t_put,
+                {"bytes": arr.nbytes, "devices": 1},
+            )
         shape = (n_rows, length)
         if shape not in bundle.emb_warmed_shapes:
             self.unwarmed_dispatches += 1
@@ -1842,9 +1874,13 @@ class RecommendEngine:
                 "the serving path); warmed buckets: batches %s x lengths %s",
                 shape, self._batch_buckets(), self._len_buckets(),
             )
+        if trace is not None:
+            t_enqueue = time.perf_counter()
         top_ids, top_sims = embed_topk(
             bundle.emb_factors, seeds_dev, k_best=self.cfg.k_best_tracks
         )
+        if trace is not None:
+            trace.child("enqueue_embed", parent, t_enqueue)
         return top_ids, top_sims, known
 
     def _compose_answer(
@@ -1948,12 +1984,19 @@ class RecommendEngine:
         (:meth:`_note_staged`: the rule seeds' fill and transfer) and
         ``dispatch`` (the rule enqueue — in the mesh layout the peer
         fan-out too — the embedding seeds' fill, transfer and enqueue,
-        and the starting of the results' copies) here; ``fetch_rules``
-        (the rule pair's pick-up), ``fetch_embed`` (the embedding
-        pair's) and ``compose`` in ``finish()``. The fallback (nothing
-        published yet) records ``compose`` alone.
-        What lies between ``dispatch`` and ``finish()`` starting is the
-        batcher's hop to its completion thread, and belongs to no span."""
+        and the starting of the results' copies) here; ``handoff`` (from
+        the end of ``dispatch`` to ``finish()`` starting: the batcher's
+        bookkeeping and its hop to the thread that runs ``finish()``),
+        ``fetch_rules`` (the rule pair's pick-up), ``fetch_embed`` (the
+        embedding pair's) and ``compose`` in ``finish()``. Inside
+        ``stage`` lie ``fill_rules`` and ``put_rules``
+        (:meth:`_stage_seeds`); inside ``dispatch`` lie ``enqueue_rules``
+        (the whole :meth:`_dispatch_rules` call) and ``fill_embed``,
+        ``put_embed``, ``enqueue_embed`` (:meth:`_dispatch_embed`), each
+        recorded with ``TraceContext.child`` under its parent's reserved
+        id. What a parent does outside its children (the shard count, the
+        copies' start) is its own time. The fallback (nothing published
+        yet) records ``handoff`` and ``compose`` alone."""
         if trace is not None:
             trace.skip()  # the engine's part of the batch starts here
         replicas = self.replicas
@@ -1967,7 +2010,7 @@ class RecommendEngine:
 
             def finish_fallback() -> list[tuple[list[str], str]]:
                 if trace is not None:
-                    trace.skip()
+                    trace.lap("handoff")
                 out = [
                     (self.static_recommendation(s), "fallback")
                     for s in seed_sets
@@ -1992,23 +2035,30 @@ class RecommendEngine:
         )
         cm = self.cost_model
         t_kernel = time.perf_counter() if cm is not None else 0.0
+        dispatch = None
+        if trace is not None:
+            dispatch, t_enqueue = trace.reserve(), time.perf_counter()
         rule_results, pick_up_rules, remote = self._dispatch_rules(
             bundle, arr, seeds_dev, deadline
         )
+        if trace is not None:
+            trace.child("enqueue_rules", dispatch, t_enqueue)
         # second model family: the embedding lookup dispatches alongside
         # the rule kernel onto the same replica device — both async, both
         # consumed together in finish()
-        emb = self._dispatch_embed(bundle, seed_sets, n_rows, length)
+        emb = self._dispatch_embed(
+            bundle, seed_sets, n_rows, length, trace, dispatch
+        )
         # every result starts for the host now, behind its program, so
         # that finish() does not start four copies one after the other
         _start_host_copies(*rule_results, *(emb[:2] if emb else ()))
         self._note_dispatch(idx)
         if trace is not None:
-            trace.lap("dispatch")
+            trace.lap("dispatch", span_id=dispatch)
 
         def finish() -> list[tuple[list[str], str]]:
             if trace is not None:
-                trace.skip()
+                trace.lap("handoff")
             # chaos hook ON the completion path — where a real kernel
             # failure or stall surfaces (delay faults sleep here, fail
             # faults raise into the batcher's circuit breaker)

@@ -69,7 +69,12 @@ def start_capture(label: str, seconds: float, recorder=None) -> "object":
     starts until after it stops, so every batch whose programs the
     session sees has a trace; inside the session the thread emits a
     clock anchor right after the start, once a second, and right before
-    the stop; and the spans land beside the ``.xplane.pb`` as
+    the stop, and after each anchor runs the clock probe
+    (``observability.trace.ClockProbe``: a one-element program named
+    ``kmls_clock_probe`` on every local device, timed on the host before
+    the call and after ``block_until_ready``; compiled on every device
+    before the recorder and the session open, so nothing compiles in the
+    capture); and the spans land beside the ``.xplane.pb`` as
     ``kmls_spans.jsonl``. Two log lines bracket it: ``profile capture
     open: dir=<session directory>`` before anything starts, ``profile
     capture closed: dir=<the span file's directory> ...`` once the file
@@ -90,20 +95,26 @@ def start_capture(label: str, seconds: float, recorder=None) -> "object":
             os.path.join(profile_dir() or "", label), seconds,
         )
         anchors: list[tuple[int, int]] = []
+        probes: list[list[int]] = []
+        # compiled on every device before the session opens: no compile
+        # lands inside the capture
+        probe = spantrace.ClockProbe()
         recorder.capture_begin()
         try:
             with trace_session(label):
                 deadline = time.monotonic() + max(seconds, 0.0)
                 anchors.append(spantrace.emit_clock_anchor())
+                probes += probe()
                 while True:
                     left = deadline - time.monotonic()
                     if left <= 0:
                         break
                     time.sleep(min(1.0, left))
                     anchors.append(spantrace.emit_clock_anchor())
+                    probes += probe()
         finally:
             traces = recorder.capture_end()
-        _write_capture_spans(label, seconds, anchors, traces)
+        _write_capture_spans(label, seconds, anchors, traces, probes)
 
     thread = threading.Thread(
         target=run, daemon=True, name="kmls-profile-capture"
@@ -113,12 +124,14 @@ def start_capture(label: str, seconds: float, recorder=None) -> "object":
 
 
 def _write_capture_spans(
-    label: str, seconds: float, anchors: list, traces: list[dict]
+    label: str, seconds: float, anchors: list, traces: list[dict],
+    probes: list,
 ) -> None:
     """``kmls_spans.jsonl`` beside the capture's ``.xplane.pb`` (the
     session's own directory where the profiler wrote none): a header
-    line with the anchors' ``perf_counter_ns`` pairs, then one line per
-    request trace and per batch trace, spans with absolute
+    line with the anchors' ``perf_counter_ns`` pairs and the clock
+    probes' ``[device id, before, after]`` (``device_probes``), then one
+    line per request trace and per batch trace, spans with absolute
     ``perf_counter`` nanoseconds. Then the one log line a reader finds
     the capture by."""
     import glob
@@ -142,6 +155,7 @@ def _write_capture_spans(
         "kind": "header", "version": 1, "label": label,
         "seconds": seconds, "clock": "perf_counter_ns",
         "anchors": [list(pair) for pair in anchors],
+        "device_probes": probes,
         "requests": requests, "batches": len(traces) - requests,
         "spans": spans,
     }

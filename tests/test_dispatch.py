@@ -210,10 +210,25 @@ class TestBatchPathAgainstOracle:
 
 
 def _span_shape(trace) -> list[tuple[str, tuple]]:
+    """(name, attribute keys) of each span, in the order they began (by
+    id: a reserved parent is recorded after its children)."""
     return [
         (name, tuple(sorted(attrs or ())))
-        for _id, _parent, name, _t0, _t1, attrs in trace.spans
+        for _id, _parent, name, _t0, _t1, attrs in sorted(trace.spans)
     ]
+
+
+# a rules-only batch's spans in the order they begin; a hybrid one adds
+# the embedding family's three under dispatch and its pick-up
+RULE_BATCH = [
+    "stage", "fill_rules", "put_rules", "dispatch", "enqueue_rules",
+    "handoff", "fetch_rules", "compose",
+]
+HYBRID_BATCH = [
+    "stage", "fill_rules", "put_rules", "dispatch", "enqueue_rules",
+    "fill_embed", "put_embed", "enqueue_embed", "handoff", "fetch_rules",
+    "fetch_embed", "compose",
+]
 
 
 def _traced_batch(engine, seed_sets, monkeypatch):
@@ -245,9 +260,7 @@ class TestOneSkeletonAcrossLayouts:
             reference, seed_sets, monkeypatch
         )
         assert reference.model_layout == "replicated"
-        assert [n for n, _ in _span_shape(local_trace)] == [
-            "stage", "dispatch", "fetch_rules", "compose",
-        ]
+        assert [n for n, _ in _span_shape(local_trace)] == RULE_BATCH
         assert local_fired == 1
         for member in members:
             assert member.model_layout == "mesh"
@@ -266,18 +279,31 @@ class TestOneSkeletonAcrossLayouts:
         engine = hybrid_engines(layout, "blend")
         sets = [_seed_set(engine.bundle, k) for k in SEED_SETS]
         _, trace, fired = _traced_batch(engine, sets, monkeypatch)
-        assert [n for n, _ in _span_shape(trace)] == [
-            "stage", "dispatch", "fetch_rules", "fetch_embed", "compose",
-        ]
+        assert [n for n, _ in _span_shape(trace)] == HYBRID_BATCH
         assert trace.attrs["rows"] == 4 and trace.attrs["length"] == MAX_SEEDS
         assert fired == 1
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_put_spans_count_the_bytes_and_the_devices_they_go_to(
+        self, hybrid_engines, layout, monkeypatch
+    ):
+        """The vocabulary-sharded layout places the rule seeds on every
+        device of its mesh; the embedding seeds go to one device."""
+        engine = hybrid_engines(layout, "blend")
+        sets = [_seed_set(engine.bundle, k) for k in SEED_SETS]
+        _, trace, _ = _traced_batch(engine, sets, monkeypatch)
+        attrs = {name: a for _id, _parent, name, _t0, _t1, a in trace.spans}
+        size = trace.attrs["rows"] * trace.attrs["length"] * 4  # int32 ids
+        devices = 4 if layout == "sharded" else 1
+        assert attrs["put_rules"] == {"bytes": size, "devices": devices}
+        assert attrs["put_embed"] == {"bytes": size, "devices": 1}
 
     def test_fallback_before_first_load_composes_only(self, tmp_path, monkeypatch):
         engine = RecommendEngine(ServingConfig(base_dir=str(tmp_path)))
         monkeypatch.setattr(engine, "reload_if_required", lambda: None)
         out, trace, fired = _traced_batch(engine, [["a"], ["b"]], monkeypatch)
         assert [src for _, src in out] == ["fallback", "fallback"]
-        assert [n for n, _ in _span_shape(trace)] == ["compose"]
+        assert [n for n, _ in _span_shape(trace)] == ["handoff", "compose"]
         assert fired == 0
         assert engine.recommend(["a"]) == out[0]
 

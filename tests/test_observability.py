@@ -1726,9 +1726,18 @@ class TestJobPhaseCostTelemetry:
 REQUEST_SPANS = {
     "request", "parse", "cache", "admit", "queue", "batch", "respond",
 }
-# what a rules-only batch records (a hybrid one adds fetch_embed)
+# what a rules-only batch records (a hybrid one adds fetch_embed and
+# fill_embed, put_embed, enqueue_embed under dispatch)
 BATCH_SPANS = {
-    "batch", "stage", "dispatch", "fetch_rules", "compose", "resolve",
+    "batch", "stage", "fill_rules", "put_rules", "dispatch",
+    "enqueue_rules", "handoff", "fetch_rules", "compose", "resolve",
+}
+# the children of stage and dispatch: recorded with TraceContext.child
+# under their parent's reserved id, never laps
+CHILD_SPANS = {
+    "fill_rules": "stage", "put_rules": "stage", "enqueue_rules": "dispatch",
+    "fill_embed": "dispatch", "put_embed": "dispatch",
+    "enqueue_embed": "dispatch",
 }
 
 
@@ -1806,12 +1815,17 @@ class _GatedEngine:
         self.batches.append(len(seed_sets))
         if trace is not None:
             trace.skip()
-            trace.lap("stage")
-            trace.lap("dispatch")
+            stage, t = trace.reserve(), time.perf_counter()
+            trace.child("fill_rules", stage, t)
+            trace.child("put_rules", stage, time.perf_counter())
+            trace.lap("stage", span_id=stage)
+            dispatch = trace.reserve()
+            trace.child("enqueue_rules", dispatch, time.perf_counter())
+            trace.lap("dispatch", span_id=dispatch)
 
         def finish():
             if trace is not None:
-                trace.skip()
+                trace.lap("handoff")
             assert self.gate.wait(timeout=10)
             if trace is not None:
                 trace.lap("fetch_rules")
@@ -1914,7 +1928,8 @@ class TestSpanTree:
         rec.finish_batch(bt)
         (doc,) = rec.debug_payload()["batches"]
         assert [s["name"] for s in doc["spans"]] == [
-            "batch", "stage", "dispatch", "fetch_rules", "compose",
+            "batch", "stage", "fill_rules", "put_rules", "dispatch",
+            "enqueue_rules", "handoff", "fetch_rules", "compose",
         ]
         _assert_tree(doc)
         # three requests of 1, 2 and 3 seeds land in the (4, 8) bucket
@@ -1949,8 +1964,9 @@ class TestSpanTree:
         (doc,) = rec.debug_payload()["batches"]
         names = [s["name"] for s in doc["spans"]]
         assert names == [
-            "batch", "stage", "dispatch", "fetch_rules", "fetch_embed",
-            "compose",
+            "batch", "stage", "fill_rules", "put_rules", "dispatch",
+            "enqueue_rules", "fill_embed", "put_embed", "enqueue_embed",
+            "handoff", "fetch_rules", "fetch_embed", "compose",
         ]
         _assert_tree(doc)
         span = {s["name"]: s for s in doc["spans"]}
@@ -1960,7 +1976,8 @@ class TestSpanTree:
             return span[name]["start_ms"] + span[name]["duration_ms"]
 
         for before, after in (
-            ("stage", "dispatch"), ("fetch_rules", "fetch_embed"),
+            ("stage", "dispatch"), ("dispatch", "handoff"),
+            ("handoff", "fetch_rules"), ("fetch_rules", "fetch_embed"),
             ("fetch_embed", "compose"),
         ):
             assert abs(span[after]["start_ms"] - end(before)) <= slack
@@ -1982,10 +1999,19 @@ class TestSpanTree:
 
         source = inspect.getsource(RecommendEngine)
         laps = set(re.findall(r'trace\.lap\("(\w+)"', source))
-        assert {"stage", "dispatch", "fetch_rules", "fetch_embed",
-                "compose"} <= laps
+        assert {"stage", "dispatch", "handoff", "fetch_rules",
+                "fetch_embed", "compose"} <= laps
+        # handoff is the one lap the readers do not claim: idle time under
+        # it stays the batch's ("unclaimed")
+        assert "handoff" not in bench_spans.PRECEDENCE
+        assert "handoff" not in bench_spans.BUCKET_OF
+        laps.discard("handoff")
         assert laps <= set(bench_spans.BUCKET_OF)
         assert laps <= set(bench_spans.PRECEDENCE)
+        # a child lies inside its parent, which claims its idle time
+        children = set(re.findall(r'trace\.child\(\s*"(\w+)"', source))
+        assert children == set(CHILD_SPANS)
+        assert set(CHILD_SPANS.values()) <= laps
         # and what a live batch records is among them
         cfg, _, _ = mined_pvc
         engine = RecommendEngine(cfg)
@@ -1995,7 +2021,9 @@ class TestSpanTree:
         engine.recommend_many_async([_rule_seeds(cfg)[:2]], trace=bt)()
         rec.finish_batch(bt)
         (doc,) = rec.debug_payload()["batches"]
-        assert {s["name"] for s in doc["spans"][1:]} <= laps
+        assert {s["name"] for s in doc["spans"][1:]} <= (
+            laps | children | {"handoff"}
+        )
 
     def test_three_requests_one_batch_trace_three_batch_spans(self):
         """Satellite: requests that share a dispatch share one batch
@@ -2230,6 +2258,15 @@ class TestCaptureMode:
         assert named == sorted(named)
         assert all(0 <= opened - ns < 1e9 for ns, opened in header["anchors"])
         assert 1.2e9 <= named[-1] - named[0] < 10e9  # the capture's length
+        # after each anchor, the clock probe on every local device
+        import jax
+
+        from kmlserver_tpu.observability.trace import ClockProbe
+
+        ids = [d.id for d in jax.local_devices()]
+        assert [p[0] for p in header["device_probes"]] == (
+            ids * ClockProbe.rounds * len(named)
+        )
         assert [t["kind"] for t in traces].count("request") == 3
         for t in traces:
             _assert_tree(t)
@@ -2253,6 +2290,248 @@ class TestCaptureMode:
             if e.name.startswith("kmls/clock:")
         ]
         assert sorted(found) == [f"kmls/clock:{ns}" for ns in named]
+
+
+def _hybrid_engine(tmp_path):
+    """A published hybrid generation's engine and a batch of three that
+    reaches both families (one seed the rules do not know)."""
+    from .test_embedding import _cold_and_hot_seeds, _make_pvc, _serving_app
+
+    run_mining_job(_make_pvc(str(tmp_path)))
+    engine = _serving_app(str(tmp_path)).engine
+    assert engine.embedding_active
+    cold, hot = _cold_and_hot_seeds(engine)
+    return engine, [[hot], [cold], [hot, cold, hot]]
+
+
+def _raw_spans(trace) -> dict[str, tuple]:
+    """name → (id, parent, t_start, t_end, attrs) of a live trace, on
+    perf_counter's clock, unrounded."""
+    return {
+        name: (span_id, parent, t0, t1, attrs)
+        for span_id, parent, name, t0, t1, attrs in trace.spans
+    }
+
+
+@pytest.fixture(scope="module")
+def hybrid_batch(tmp_path_factory):
+    """One traced hybrid batch through the real engine, with the moments
+    ``_note_staged`` was entered and left → (its raw spans, those two
+    moments, the trace)."""
+    engine, sets = _hybrid_engine(tmp_path_factory.mktemp("hybrid-spans"))
+    noted = []
+    real = engine._note_staged
+
+    def note(*args):
+        noted.append(time.perf_counter())
+        real(*args)
+        noted.append(time.perf_counter())
+
+    engine._note_staged = note
+    rec = SpanRecorder(sample=1.0, rng=random.Random(44))
+    bt = rec.begin_batch(time.perf_counter(), requests=3, replica=0)
+    engine.recommend_many_async(sets, trace=bt)()
+    rec.finish_batch(bt)
+    return _raw_spans(bt), noted, bt
+
+
+class TestHostPathSpans:
+    """The children of ``stage`` and ``dispatch`` and the ``handoff`` lap:
+    each names its parent and lies inside it, and the laps around them
+    keep their endpoints."""
+
+    @pytest.mark.parametrize("child,parent", sorted(CHILD_SPANS.items()))
+    def test_each_child_names_its_parent_and_lies_inside_it(
+        self, hybrid_batch, child, parent
+    ):
+        spans, _, _ = hybrid_batch
+        cid, cparent, c0, c1, _ = spans[child]
+        pid, pparent, p0, p1, _ = spans[parent]
+        assert (cparent, pparent) == (pid, 0)
+        assert cid > pid  # ids follow the order spans begin in
+        assert p0 <= c0 <= c1 <= p1
+
+    def test_children_sum_to_no_more_than_their_parent(self, hybrid_batch):
+        spans, _, _ = hybrid_batch
+
+        def length(name):
+            return spans[name][3] - spans[name][2]
+
+        assert length("fill_rules") + length("put_rules") <= length("stage")
+        assert sum(length(n) for n in (
+            "enqueue_rules", "fill_embed", "put_embed", "enqueue_embed",
+        )) <= length("dispatch")
+        # in the order the work is done
+        for order in (
+            ["fill_rules", "put_rules"],
+            ["enqueue_rules", "fill_embed", "put_embed", "enqueue_embed"],
+        ):
+            for before, after in zip(order, order[1:]):
+                assert spans[before][3] <= spans[after][2], (before, after)
+
+    def test_stage_still_ends_in_note_staged_after_put_rules(self, hybrid_batch):
+        spans, (entered, left), _ = hybrid_batch
+        stage_end = spans["stage"][3]
+        assert entered <= stage_end <= left
+        assert spans["put_rules"][3] <= entered
+
+    def test_the_laps_still_tile_across_the_new_handoff(self, hybrid_batch):
+        """``dispatch`` ends where ``handoff`` starts and ``fetch_rules``
+        starts where ``handoff`` ends, to the nanosecond: the laps tile
+        the batch, and the hop to ``finish()`` is one of them."""
+        spans, _, bt = hybrid_batch
+        assert spans["handoff"][1] == 0  # a child of the batch
+        assert spans["handoff"][2] == spans["dispatch"][3]
+        assert spans["fetch_rules"][2] == spans["handoff"][3]
+        assert spans["dispatch"][2] == spans["stage"][3]
+        assert spans["fetch_embed"][2] == spans["fetch_rules"][3]
+        assert bt.cursor == spans["compose"][3]
+
+    @pytest.mark.parametrize("transport", ["threaded", "async"])
+    def test_handoff_in_both_batchers(self, mined_pvc, transport):
+        """Both batchers hand a batch's ``finish()`` to another thread;
+        the engine names that hop in either, from the end of ``dispatch``
+        to the start of ``fetch_rules``."""
+        cfg, _, _ = mined_pvc
+        cfg = dataclasses.replace(cfg, trace_sample=1.0)
+        seeds = _rule_seeds(cfg)[:2]
+        if transport == "threaded":
+            app = RecommendApp(cfg)
+            assert app.engine.load()
+            assert _post(app, seeds)[0] == 200
+        else:
+            app = RecommendApp(cfg, defer_batcher=True)
+            assert app.engine.load()
+            assert _http_post(_serve_async(app), seeds)[0] == 200
+        deadline = time.time() + 5
+        while time.time() < deadline and not app.recorder.debug_payload()["batches"]:
+            time.sleep(0.01)
+        (batch,) = app.recorder.debug_payload()["batches"]
+        _assert_tree(batch)
+        at = {s["name"]: s for s in batch["spans"]}
+        handoff = at["handoff"]
+        assert handoff["parent"] == 0 and handoff["duration_ms"] >= 0.0
+
+        def end(span):
+            return span["start_ms"] + span["duration_ms"]
+
+        assert handoff["start_ms"] == pytest.approx(end(at["dispatch"]), abs=2e-4)
+        assert at["fetch_rules"]["start_ms"] == pytest.approx(end(handoff), abs=2e-4)
+
+    def test_tracing_off_records_nothing_and_runs_no_probe(
+        self, mined_pvc, monkeypatch
+    ):
+        """Off means one is-None check at each new site: no context, no
+        reserved id, no child, no probe."""
+        from kmlserver_tpu.observability import trace as trace_mod
+
+        def refuse(*args, **kwargs):
+            raise AssertionError("a tracing call ran with tracing off")
+
+        for name in ("reserve", "child", "lap", "span"):
+            monkeypatch.setattr(trace_mod.TraceContext, name, refuse)
+        monkeypatch.setattr(trace_mod, "ClockProbe", refuse)
+        cfg, _, _ = mined_pvc
+        app = RecommendApp(cfg)
+        assert app.engine.load()
+        assert not app.recorder.active
+        for seed in _rule_seeds(cfg)[:3]:
+            assert _post(app, [seed])[0] == 200
+        assert app.recorder.began == 0
+        assert app.recorder.batches_began == 0
+        assert app.engine.seed_slots_real == 3
+
+
+_PROBE_CAPTURE = r"""
+import contextlib, json, sys, time
+from kmlserver_tpu.utils.virtualcpu import force_virtual_cpu
+force_virtual_cpu(4)
+import jax
+from jax._src.dispatch import BACKEND_COMPILE_EVENT
+from kmlserver_tpu.observability import SpanRecorder
+from kmlserver_tpu.utils import profiling
+
+compiles, session = [], []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda event, secs, **kw: compiles.append(time.perf_counter_ns())
+    if event == BACKEND_COMPILE_EVENT else None
+)
+real_session = profiling.trace_session
+
+@contextlib.contextmanager
+def watched(label):
+    with real_session(label):
+        session.append(time.perf_counter_ns())
+        yield
+        session.append(time.perf_counter_ns())
+
+profiling.trace_session = watched
+thread = profiling.start_capture("probe", float(sys.argv[1]), recorder=SpanRecorder())
+thread.join(timeout=120)
+assert not thread.is_alive()
+print(json.dumps({"compiles": compiles, "session": session,
+                  "devices": [d.id for d in jax.local_devices()]}))
+"""
+
+
+class TestClockProbe:
+    def test_probe_runs_on_every_device_and_counts_its_bounds(self):
+        from kmlserver_tpu.observability.trace import ClockProbe
+
+        probe = ClockProbe()
+        first, second = probe(), probe()
+        import jax
+
+        ids = [d.id for d in jax.local_devices()]
+        for run in (first, second):
+            # every device in turn, the rounds one after the other
+            assert [p[0] for p in run] == ids * ClockProbe.rounds
+            assert all(before < after for _, before, after in run)
+            assert all(a[2] <= b[1] for a, b in zip(run, run[1:]))
+        assert first[-1][2] <= second[0][1]
+        # one executable a device, compiled when the probe was built
+        assert probe._run._cache_size() == len(ids)
+
+    def test_header_carries_device_probes_on_four_devices_without_compiling(
+        self, tmp_path
+    ):
+        """A CPU capture with four virtual devices: ``device_probes`` holds
+        one probe a device at every anchor, each inside the session, and
+        no compile lands inside the session (the probe was compiled before
+        it opened)."""
+        import subprocess
+        import sys
+
+        from kmlserver_tpu.observability.trace import SPANS_FILENAME
+
+        env = dict(os.environ, KMLS_PROFILE_DIR=str(tmp_path))
+        proc = subprocess.run(
+            [sys.executable, "-c", _PROBE_CAPTURE, "1.5"],
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            env=env, capture_output=True, text=True, timeout=240,
+        )
+        assert proc.returncode == 0, proc.stderr[-3000:]
+        out = json.loads(proc.stdout.strip().splitlines()[-1])
+        assert out["devices"] == [0, 1, 2, 3]
+        opened, closed = out["session"]
+        assert not [t for t in out["compiles"] if opened <= t <= closed]
+        (path,) = [
+            os.path.join(d, SPANS_FILENAME) for d, _, files in os.walk(tmp_path)
+            if SPANS_FILENAME in files
+        ]
+        with open(path) as fh:
+            header = json.loads(fh.readline())
+        assert header["version"] == 1 and len(header["anchors"]) >= 3
+        from kmlserver_tpu.observability.trace import ClockProbe
+
+        probes = header["device_probes"]
+        per_anchor = 4 * ClockProbe.rounds
+        assert len(probes) == per_anchor * len(header["anchors"])
+        for i, (named, _) in enumerate(header["anchors"]):
+            mine = probes[per_anchor * i: per_anchor * (i + 1)]
+            assert [p[0] for p in mine] == [0, 1, 2, 3] * ClockProbe.rounds
+            assert all(named < before < after for _, before, after in mine)
+            assert all(opened <= p[1] and p[2] <= closed for p in mine)
 
 
 class TestDispatchCounters:
