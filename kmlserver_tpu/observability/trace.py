@@ -38,9 +38,11 @@ server-side span breakdown for the same request.
 Spans form a TREE: every span has a small integer id local to its
 trace and a parent id; the root (id 0) is the trace itself, ``request``
 or ``batch``. A dispatched batch has a trace of its own —
-``batch -> {stage -> {fill_rules, put_rules}, dispatch ->
-{enqueue_rules, fill_embed, put_embed, enqueue_embed}, handoff,
-fetch_rules, fetch_embed, compose, resolve}`` — and each member
+``batch -> {stage -> {fill_embed, put_embed, enqueue_embed,
+fill_rules, put_rules}, dispatch -> {enqueue_rules}, handoff,
+fetch_embed, fetch_rules, compose, resolve}`` in a hybrid batch (the
+``*_embed`` spans absent where the embedding family does not answer) —
+and each member
 request's ``batch`` span names it by ``batch_id``, so a request reads
 ``request -> {parse, cache, admit, queue, batch, respond, write}`` and
 the batch's inside is looked up once, not copied into every member.

@@ -1681,7 +1681,7 @@ class RecommendEngine:
 
     def _stage_seeds(
         self, bundle: RuleBundle, seed_sets: list[list[str]],
-        rows: int, length: int, trace=None,
+        rows: int, length: int, trace=None, stage: int | None = None,
     ) -> tuple[np.ndarray, jax.Array, np.ndarray]:
         """Fill the padded (rows, length) seed-index array and transfer it
         → (host seed array, device seed array, per-row any-known-seed
@@ -1691,13 +1691,13 @@ class RecommendEngine:
         seeds. The transfer targets the bundle's own placement (its
         device; the mesh-replicated sharding in the sharded layout), so a
         replica's dispatch runs on the replica's chip. ``trace`` is the
-        batch's trace (None = untraced): its ``stage`` span ends where
-        the transfer has been issued, and holds ``fill_rules`` (the array
-        filled) and ``put_rules`` (the transfer issued)."""
+        batch's trace (None = untraced) and ``stage`` its open ``stage``
+        span's id, reserved by the caller where staging began: the span
+        ends where the transfer has been issued, and holds ``fill_rules``
+        (the array filled) and ``put_rules`` (the transfer issued)."""
         shape = (rows, length)
-        stage = None
         if trace is not None:
-            stage, t_fill = trace.reserve(), time.perf_counter()
+            t_fill = time.perf_counter()
         arr = np.full(shape, -1, dtype=np.int32)
         known_rows, n_real = self._fill_seed_rows(
             bundle, seed_sets, arr, length
@@ -1825,6 +1825,19 @@ class RecommendEngine:
 
     # ---------- second model family: embedding dispatch + hybrid merge ----
 
+    def _serves_embed(self, bundle: RuleBundle) -> bool:
+        """Whether a batch on ``bundle`` dispatches the embedding lookup
+        at all: the bundle carries factors and the operator did not pin
+        rules-only."""
+        return bundle.emb_factors is not None and self.cfg.hybrid_mode != "rules"
+
+    def _embed_goes_first(self, bundle: RuleBundle) -> bool:
+        """Whether a batch on ``bundle`` launches the embedding lookup
+        before the rule lookup (:meth:`recommend_many_async`): wherever it
+        serves the embedding family, but in the mesh layout, whose peer
+        fan-out goes first."""
+        return self._serves_embed(bundle) and bundle.layout != "mesh"
+
     def _dispatch_embed(
         self, bundle: RuleBundle, seed_sets: list[list[str]],
         n_rows: int, length: int, trace=None, parent: int | None = None,
@@ -1832,16 +1845,16 @@ class RecommendEngine:
         """Dispatch the embedding cosine top-k for a batch → ``(device
         top_ids, device top_sims, host known-row mask)``, or None when the
         bundle carries no factors / the operator pinned rules-only. Runs
-        on the DISPATCH path (no host syncs — jax dispatch is async), so
-        its fill, transfer and enqueue lie inside the batch's ``dispatch``
-        span; the caller starts the device results' copies and its
-        ``finish()`` picks them up. The (n_rows, length) shape must come
-        from the warmed bucket grid — an unwarmed shape is counted and
-        logged exactly like the rule kernel's. On a traced batch
-        (``trace``; ``parent`` is the open ``dispatch`` span's id) the
-        fill, the transfer and the enqueue are ``fill_embed``,
-        ``put_embed`` and ``enqueue_embed``."""
-        if bundle.emb_factors is None or self.cfg.hybrid_mode == "rules":
+        on the DISPATCH path (no host syncs — jax dispatch is async) and
+        starts its results' copies to the host right after the enqueue;
+        the caller's ``finish()`` picks them up. The (n_rows, length)
+        shape must come from the warmed bucket grid — an unwarmed shape
+        is counted and logged exactly like the rule kernel's. On a traced
+        batch (``trace``; ``parent`` is the id of the open span it runs
+        in: ``stage`` where it goes first, ``dispatch`` in the mesh
+        layout) the fill, the transfer and the enqueue are
+        ``fill_embed``, ``put_embed`` and ``enqueue_embed``."""
+        if not self._serves_embed(bundle):
             return None
         if trace is not None:
             t_fill = time.perf_counter()
@@ -1881,6 +1894,7 @@ class RecommendEngine:
         )
         if trace is not None:
             trace.child("enqueue_embed", parent, t_enqueue)
+        _start_host_copies(top_ids, top_sims)
         return top_ids, top_sims, known
 
     def _compose_answer(
@@ -1951,12 +1965,21 @@ class RecommendEngine:
         host array, one transfer), enqueue the rule lookup
         (:meth:`_dispatch_rules` — the only step a layout varies), stage
         and enqueue the embedding lookup (:meth:`_dispatch_embed`), and
-        start every result's device → host copy
-        (:func:`_start_host_copies`), behind its program on the device's
-        stream. ``finish()`` picks the results up in program order, the
-        rule pair and then the embedding pair: a pick-up finds its copy
-        done or under way, and does not start one and sit it out before
-        the next can begin.
+        start each result's device → host copy
+        (:func:`_start_host_copies`) right after its program's enqueue,
+        behind it on the device's stream. Where the bundle serves the
+        embedding family beside local rule tensors, the embedding lookup
+        is launched FIRST: the two programs are independent, the rule
+        program is short (tens of microseconds against the embedding
+        scan's ~1.3 ms on a v5e), so launched first it left the chip
+        idle through the embedding family's fill, transfer and enqueue;
+        launched second, the rule family's host work runs while the chip
+        scans the catalog. The mesh layout keeps the rule lookup first:
+        its peer fan-out's socket round trip is the longest pole there.
+        ``finish()`` picks the results up in program order, the chip's
+        in-order stream finishing them in that order: a pick-up finds its
+        copy done or under way, and does not start one and sit it out
+        before the next can begin.
 
         The split lets the micro-batcher pipeline device calls: a
         dispatch-block-respond loop caps throughput at batch_size over
@@ -1981,22 +2004,24 @@ class RecommendEngine:
         None = untraced, and then each site below is one is-None check).
         Every layout records the same spans on it with
         ``TraceContext.lap``, each where the work happens: ``stage``
-        (:meth:`_note_staged`: the rule seeds' fill and transfer) and
-        ``dispatch`` (the rule enqueue — in the mesh layout the peer
-        fan-out too — the embedding seeds' fill, transfer and enqueue,
-        and the starting of the results' copies) here; ``handoff`` (from
-        the end of ``dispatch`` to ``finish()`` starting: the batcher's
-        bookkeeping and its hop to the thread that runs ``finish()``),
-        ``fetch_rules`` (the rule pair's pick-up), ``fetch_embed`` (the
-        embedding pair's) and ``compose`` in ``finish()``. Inside
-        ``stage`` lie ``fill_rules`` and ``put_rules``
-        (:meth:`_stage_seeds`); inside ``dispatch`` lie ``enqueue_rules``
-        (the whole :meth:`_dispatch_rules` call) and ``fill_embed``,
-        ``put_embed``, ``enqueue_embed`` (:meth:`_dispatch_embed`), each
-        recorded with ``TraceContext.child`` under its parent's reserved
-        id. What a parent does outside its children (the shard count, the
-        copies' start) is its own time. The fallback (nothing published
-        yet) records ``handoff`` and ``compose`` alone."""
+        (:meth:`_note_staged`: everything before the rule program is
+        enqueued) and ``dispatch`` (the rule enqueue — in the mesh layout
+        the peer fan-out too — and the starting of its results' copies)
+        here; ``handoff`` (from the end of ``dispatch`` to ``finish()``
+        starting: the batcher's bookkeeping and its hop to the thread
+        that runs ``finish()``), ``fetch_embed`` (the embedding pair's
+        pick-up), ``fetch_rules`` (the rule pair's), in program order,
+        and ``compose`` in ``finish()``. Inside ``stage`` lie
+        ``fill_rules`` and ``put_rules`` (:meth:`_stage_seeds`); inside
+        ``dispatch`` lies ``enqueue_rules`` (the whole
+        :meth:`_dispatch_rules` call); ``fill_embed``, ``put_embed`` and
+        ``enqueue_embed`` (:meth:`_dispatch_embed`) lie at the front of
+        ``stage`` where the embedding lookup goes first, and after
+        ``enqueue_rules`` inside ``dispatch`` in the mesh layout. Each
+        child is recorded with ``TraceContext.child`` under its parent's
+        reserved id. What a parent does outside its children (the shard
+        count, the copies' start) is its own time. The fallback (nothing
+        published yet) records ``handoff`` and ``compose`` alone."""
         if trace is not None:
             trace.skip()  # the engine's part of the batch starts here
         replicas = self.replicas
@@ -2030,10 +2055,20 @@ class RecommendEngine:
         # made a batch of 3 pay a 32-row kernel — ~8x the work on the
         # lane ranking. Every bucket is pre-warmed at bundle publish.
         n_rows = self._bucket_batch(max(len(seed_sets), 1))
-        arr, seeds_dev, known_rows = self._stage_seeds(
-            bundle, seed_sets, n_rows, length, trace
-        )
         cm = self.cost_model
+        # both families take the one (n_rows, length) bucket and the
+        # replica's device, both async, both picked up in finish()
+        embed_first = self._embed_goes_first(bundle)
+        stage = trace.reserve() if trace is not None else None
+        emb, t_embed = None, 0.0
+        if embed_first:
+            t_embed = time.perf_counter() if cm is not None else 0.0
+            emb = self._dispatch_embed(
+                bundle, seed_sets, n_rows, length, trace, stage
+            )
+        arr, seeds_dev, known_rows = self._stage_seeds(
+            bundle, seed_sets, n_rows, length, trace, stage
+        )
         t_kernel = time.perf_counter() if cm is not None else 0.0
         dispatch = None
         if trace is not None:
@@ -2043,18 +2078,34 @@ class RecommendEngine:
         )
         if trace is not None:
             trace.child("enqueue_rules", dispatch, t_enqueue)
-        # second model family: the embedding lookup dispatches alongside
-        # the rule kernel onto the same replica device — both async, both
-        # consumed together in finish()
-        emb = self._dispatch_embed(
-            bundle, seed_sets, n_rows, length, trace, dispatch
-        )
-        # every result starts for the host now, behind its program, so
-        # that finish() does not start four copies one after the other
-        _start_host_copies(*rule_results, *(emb[:2] if emb else ()))
+        _start_host_copies(*rule_results)
+        if not embed_first:
+            emb = self._dispatch_embed(
+                bundle, seed_sets, n_rows, length, trace, dispatch
+            )
         self._note_dispatch(idx)
         if trace is not None:
             trace.lap("dispatch", span_id=dispatch)
+
+        def pick_up_embed(t_from: float):
+            """Pick the embedding pair up → (host ids, sims, known mask),
+            and the moment it was picked up (0.0 without a cost model)."""
+            emb_host = (np.asarray(emb[0]), np.asarray(emb[1]), emb[2])
+            t_done = 0.0
+            if cm is not None:
+                # from ``t_from`` (its launch, or the pick-up of the
+                # program ahead of it on the in-order stream) to its own
+                # pick-up: the rest of the embedding lookup and its copy
+                t_done = time.perf_counter()
+                cm.observe_kernel(
+                    "embed_topk", t_done - t_from,
+                    b=n_rows, l=length, v=len(bundle.emb_vocab or ()),
+                    r=int(bundle.emb_factors.shape[0]),
+                    k_best=self.cfg.k_best_tracks,
+                )
+            if trace is not None:
+                trace.lap("fetch_embed")
+            return emb_host, t_done
 
         def finish() -> list[tuple[list[str], str]]:
             if trace is not None:
@@ -2063,17 +2114,22 @@ class RecommendEngine:
             # failure or stall surfaces (delay faults sleep here, fail
             # faults raise into the batcher's circuit breaker)
             faults.fire("replica.kernel", replica=idx)
-            # the rule pair first: its program ran first, and its pick-up
-            # is the fence between the two programs below
+            # pick-ups in program order: each is the fence between the
+            # program it waits for and the one after it
+            emb_host = None
+            t_from = t_kernel
+            if embed_first and emb is not None:
+                emb_host, t_from = pick_up_embed(t_embed)
             host_ids, host_confs = pick_up_rules()
             if trace is not None:
                 trace.lap("fetch_rules")
             if cm is not None:
-                # per-kernel attribution (ISSUE 12): dispatch → the rule
-                # pair's pick-up, an upper bound on the rule lookup's
-                # device time (the same semantics as the batcher's
-                # kmls_device_ms interval), so the derived MFU is a
-                # lower bound
+                # per-kernel attribution: its dispatch (or the embedding
+                # pair's pick-up, where that program ran ahead) → the
+                # rule pair's pick-up, an upper bound on the rule
+                # lookup's device time (the same semantics as the
+                # batcher's kmls_device_ms interval), so the derived MFU
+                # is a lower bound
                 t_rules = time.perf_counter()
                 dims = dict(
                     b=n_rows, l=length, k_max=bundle.rule_ids.shape[1],
@@ -2082,32 +2138,19 @@ class RecommendEngine:
                 )
                 if bundle.layout == "mesh":
                     cm.observe_kernel(
-                        "serve_mesh", t_rules - t_kernel, **dims
+                        "serve_mesh", t_rules - t_from, **dims
                     )
                 elif bundle.shard_kernel is not None:
                     cm.observe_kernel(
-                        "serve_sharded", t_rules - t_kernel, **dims
+                        "serve_sharded", t_rules - t_from, **dims
                     )
                 else:
                     cm.observe_kernel(
-                        "serve_rules", t_rules - t_kernel, **dims
+                        "serve_rules", t_rules - t_from, **dims
                     )
-            emb_host = None
-            if emb is not None:
-                emb_host = (np.asarray(emb[0]), np.asarray(emb[1]), emb[2])
-                if cm is not None:
-                    # the rule pair was picked up at t_rules, so this
-                    # interval bills what was left of the embedding
-                    # lookup and its copy (in-order device queue)
-                    cm.observe_kernel(
-                        "embed_topk",
-                        time.perf_counter() - t_rules,
-                        b=n_rows, l=length, v=len(bundle.emb_vocab or ()),
-                        r=int(bundle.emb_factors.shape[0]),
-                        k_best=self.cfg.k_best_tracks,
-                    )
-                if trace is not None:
-                    trace.lap("fetch_embed")
+                t_from = t_rules
+            if not embed_first and emb is not None:
+                emb_host, _ = pick_up_embed(t_from)
             out: list[tuple[list[str], str]] = []
             for r, seeds in enumerate(seed_sets):
                 emb_row = None if emb_host is None else (

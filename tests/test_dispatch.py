@@ -21,6 +21,7 @@ from kmlserver_tpu.observability.trace import SpanRecorder
 from kmlserver_tpu.serving.engine import RecommendEngine
 
 from .oracle import random_baskets, reference_recommend
+from .test_mesh import _gang_ports, _shutdown
 from .test_mesh import gang_pair, mesh_pvc  # noqa: F401  (fixture re-export)
 from .test_pipeline import table_with_metadata
 
@@ -56,15 +57,21 @@ def _mine(base_dir: str, rng, **kw) -> str:
 
 
 @pytest.fixture(scope="module")
-def hybrid_engines(tmp_path_factory):
-    """(layout, hybrid_mode) → a loaded engine over ONE mined PVC with
-    both model families published; built on first use, kept for the
-    module (a load warms every bucket of both kernels)."""
-    base = _mine(
+def hybrid_pvc(tmp_path_factory) -> str:
+    """ONE mined PVC with both model families published."""
+    return _mine(
         str(tmp_path_factory.mktemp("dispatch-hybrid")),
         np.random.default_rng(2),
         embed_enabled=True, als_rank=8, als_iters=3,
     )
+
+
+@pytest.fixture(scope="module")
+def hybrid_engines(hybrid_pvc):
+    """(layout, hybrid_mode) → a loaded engine over the hybrid PVC; built
+    on first use, kept for the module (a load warms every bucket of both
+    kernels)."""
+    base = hybrid_pvc
     engines: dict = {}
 
     def get(layout: str, mode: str) -> RecommendEngine:
@@ -218,16 +225,17 @@ def _span_shape(trace) -> list[tuple[str, tuple]]:
     ]
 
 
-# a rules-only batch's spans in the order they begin; a hybrid one adds
-# the embedding family's three under dispatch and its pick-up
+# a rules-only batch's spans in the order they begin; a hybrid one
+# launches the embedding family first, at the front of stage, and picks
+# its pair up first
 RULE_BATCH = [
     "stage", "fill_rules", "put_rules", "dispatch", "enqueue_rules",
     "handoff", "fetch_rules", "compose",
 ]
 HYBRID_BATCH = [
-    "stage", "fill_rules", "put_rules", "dispatch", "enqueue_rules",
-    "fill_embed", "put_embed", "enqueue_embed", "handoff", "fetch_rules",
-    "fetch_embed", "compose",
+    "stage", "fill_embed", "put_embed", "enqueue_embed", "fill_rules",
+    "put_rules", "dispatch", "enqueue_rules", "handoff", "fetch_embed",
+    "fetch_rules", "compose",
 ]
 
 
@@ -306,6 +314,209 @@ class TestOneSkeletonAcrossLayouts:
         assert [n for n, _ in _span_shape(trace)] == ["handoff", "compose"]
         assert fired == 0
         assert engine.recommend(["a"]) == out[0]
+
+
+def _launch_log(engine, seed_sets, monkeypatch) -> list[str]:
+    """Run one batch with the calls that launch its programs logged, in
+    the order this thread made them: ``put`` a seed transfer, ``rules``
+    the rule program's enqueue (``fanout`` the mesh layout's peer fan-out
+    before it), ``embed`` the embedding program's, ``copies:<n>`` n
+    results' copies to the host started, ``|`` the dispatch returned.
+    Other threads (a gang peer serving the fan-out) are left out."""
+    import threading
+
+    import jax
+
+    from kmlserver_tpu.ops import serve as serve_ops
+    from kmlserver_tpu.serving import engine as engine_mod
+
+    log: list[str] = []
+    me = threading.current_thread()
+
+    def logged(name, fn):
+        def call(*args, **kw):
+            if threading.current_thread() is me:
+                log.append(name(*args) if callable(name) else name)
+            return fn(*args, **kw)
+
+        return call
+
+    with monkeypatch.context() as patched:
+        patched.setattr(jax, "device_put", logged("put", jax.device_put))
+        patched.setattr(
+            engine_mod, "embed_topk", logged("embed", engine_mod.embed_topk)
+        )
+        patched.setattr(engine_mod, "_start_host_copies", logged(
+            lambda *arrays: f"copies:{len(arrays)}",
+            engine_mod._start_host_copies,
+        ))
+        patched.setattr(engine, "_kernel", logged("rules", engine._kernel))
+        for bundle in engine.replicas:
+            if bundle.shard_kernel is not None:
+                patched.setattr(
+                    bundle, "shard_kernel", logged("rules", bundle.shard_kernel)
+                )
+        patched.setattr(serve_ops, "shard_partial_topk", logged(
+            "rules", serve_ops.shard_partial_topk
+        ))
+        if engine.mesh_coordinator is not None:
+            coordinator = engine.mesh_coordinator
+            patched.setattr(coordinator, "fetch_partials", logged(
+                "fanout", coordinator.fetch_partials
+            ))
+        finish = engine.recommend_many_async(seed_sets)
+        log.append("|")
+        assert len(finish()) == len(seed_sets)
+    return log
+
+
+@pytest.fixture
+def hybrid_gang(hybrid_pvc):
+    """Two gang members (the mesh layout) over the hybrid PVC, both
+    families published."""
+    port = _gang_ports()
+    members = []
+    try:
+        for rank in range(2):
+            engine = RecommendEngine(ServingConfig(
+                base_dir=hybrid_pvc, k_best_tracks=5, hybrid_mode="blend",
+                batch_max_size=4, max_seed_tracks=MAX_SEEDS,
+                serve_gang_coordinator=f"127.0.0.1:{port}",
+                serve_gang_size=2, serve_gang_rank=rank,
+                serve_gang_port=port + rank,
+            ))
+            members.append(engine)
+            assert engine.load()
+        yield members
+    finally:
+        for engine in members:
+            _shutdown(engine)
+
+
+class _PowerClock:
+    """``time`` with ``perf_counter`` reading 2**k at its k-th call: the
+    difference of two readings names the two calls it spans."""
+
+    def __init__(self):
+        self.calls = 0
+
+    def perf_counter(self) -> float:
+        self.calls += 1
+        return float(2 ** self.calls)
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def _ticks(interval: float) -> tuple[int, int]:
+    """The calls ``(i, j)`` of a ``_PowerClock`` interval 2**j - 2**i."""
+    n = int(interval)
+    assert n == interval and n > 0, interval
+    i = (n & -n).bit_length() - 1
+    return i, i + (n >> i).bit_length()
+
+
+class TestLaunchOrder:
+    """A hybrid batch launches the embedding program first, so the rule
+    family's host work runs while the chip scores the catalog; a
+    rules-only batch, and the mesh layout's, launch as before."""
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_hybrid_batch_enqueues_the_embedding_before_the_rule_seeds_go(
+        self, hybrid_engines, layout, monkeypatch
+    ):
+        engine = hybrid_engines(layout, "blend")
+        sets = [_seed_set(engine.bundle, k) for k in SEED_SETS]
+        assert _launch_log(engine, sets, monkeypatch) == [
+            "put", "embed", "copies:2", "put", "rules", "copies:2", "|",
+        ]
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_rules_only_batch_launches_as_before(
+        self, hybrid_engines, layout, monkeypatch
+    ):
+        engine = hybrid_engines(layout, "rules")
+        sets = [_seed_set(engine.bundle, k) for k in SEED_SETS]
+        assert _launch_log(engine, sets, monkeypatch) == [
+            "put", "rules", "copies:2", "|",
+        ]
+
+    def test_mesh_batch_fans_out_to_its_peers_before_the_embedding(
+        self, hybrid_gang, monkeypatch
+    ):
+        member = hybrid_gang[0]
+        assert member.model_layout == "mesh" and member.embedding_active
+        sets = [_seed_set(member.bundle, k) for k in SEED_SETS]
+        assert _launch_log(member, sets, monkeypatch) == [
+            "put", "fanout", "rules", "copies:2", "put", "embed", "copies:2",
+            "|",
+        ]
+
+    @pytest.mark.parametrize("batch", [1, 3])
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_answers_equal_the_rules_first_order(
+        self, hybrid_engines, layout, batch, monkeypatch
+    ):
+        """Launched either way, a batch composes from the same rule ids
+        and confidences, the same embedding ids and similarities, into
+        the same answers and sources."""
+        engine = hybrid_engines(layout, "blend")
+        assert engine._embed_goes_first(engine.bundle)
+        kinds = ("all_known", "some_unknown", "over_cap")[:batch]
+        sets = [_seed_set(engine.bundle, k) for k in kinds]
+
+        def run():
+            rows = []
+            compose = engine._compose_answer
+
+            def recorded(bundle, seeds, rule_known, ids, confs, emb_row):
+                rows.append((rule_known, ids, confs, emb_row))
+                return compose(bundle, seeds, rule_known, ids, confs, emb_row)
+
+            with monkeypatch.context() as patched:
+                patched.setattr(engine, "_compose_answer", recorded)
+                return engine.recommend_many(sets), rows
+
+        answers, rows = run()
+        with monkeypatch.context() as patched:
+            patched.setattr(engine, "_embed_goes_first", lambda bundle: False)
+            want_answers, want_rows = run()
+        assert answers == want_answers
+        assert {src for _, src in answers} == {"hybrid"}
+        assert len(rows) == len(want_rows) == batch
+        for got, want in zip(rows, want_rows):
+            assert got[0] == want[0]
+            np.testing.assert_array_equal(got[1], want[1])
+            np.testing.assert_array_equal(got[2], want[2])
+            for g, w in zip(got[3], want[3], strict=True):
+                np.testing.assert_array_equal(g, w)
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_each_program_is_billed_its_own_interval(
+        self, hybrid_engines, layout, monkeypatch
+    ):
+        """The cost model bills the embedding lookup from its launch to
+        its pick-up and the rule lookup from there to its own: one
+        interval each, and they do not overlap."""
+        from kmlserver_tpu.serving import engine as engine_mod
+
+        engine = hybrid_engines(layout, "blend")
+        assert engine.cost_model is not None
+        sets = [_seed_set(engine.bundle, k) for k in SEED_SETS]
+        billed = []
+        with monkeypatch.context() as patched:
+            patched.setattr(engine_mod, "time", _PowerClock())
+            patched.setattr(
+                engine.cost_model, "observe_kernel",
+                lambda kernel, device_s, **dims: billed.append(
+                    (kernel, device_s)
+                ),
+            )
+            engine.recommend_many(sets)
+        rules = "serve_sharded" if layout == "sharded" else "serve_rules"
+        assert [kernel for kernel, _ in billed] == ["embed_topk", rules]
+        (e0, e1), (r0, r1) = (_ticks(s) for _, s in billed)
+        assert e0 < e1 <= r0 < r1
 
 
 class TestFreshStagingArray:

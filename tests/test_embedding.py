@@ -605,8 +605,9 @@ class _Watched:
 
 class TestCopiesStartAtDispatch:
     """The mechanism, on the CPU backend: every result's copy to the host
-    is started inside dispatch, and ``finish()`` picks the rule pair up
-    before the embedding pair."""
+    is started inside dispatch, right after its program's enqueue, and
+    ``finish()`` picks the pairs up in program order: the embedding pair,
+    launched first, then the rule pair."""
 
     @staticmethod
     def _watch(engine, monkeypatch):
@@ -645,14 +646,14 @@ class TestCopiesStartAtDispatch:
         # dispatch is over: every result is already on its way to the
         # host, and none has been waited for
         assert log == [
-            ("copy", "rule_ids"), ("copy", "rule_confs"),
             ("copy", "emb_ids"), ("copy", "emb_sims"),
+            ("copy", "rule_ids"), ("copy", "rule_confs"),
         ]
         del log[:]
         assert finish() == expected
         assert log == [
-            ("fetch", "rule_ids"), ("fetch", "rule_confs"),
             ("fetch", "emb_ids"), ("fetch", "emb_sims"),
+            ("fetch", "rule_ids"), ("fetch", "rule_confs"),
         ]
 
     @pytest.mark.parametrize("case", ["rules_mode", "no_embed_known_seed"])

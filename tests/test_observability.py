@@ -1727,7 +1727,7 @@ REQUEST_SPANS = {
     "request", "parse", "cache", "admit", "queue", "batch", "respond",
 }
 # what a rules-only batch records (a hybrid one adds fetch_embed and
-# fill_embed, put_embed, enqueue_embed under dispatch)
+# fill_embed, put_embed, enqueue_embed at the front of stage)
 BATCH_SPANS = {
     "batch", "stage", "fill_rules", "put_rules", "dispatch",
     "enqueue_rules", "handoff", "fetch_rules", "compose", "resolve",
@@ -1736,8 +1736,7 @@ BATCH_SPANS = {
 # under their parent's reserved id, never laps
 CHILD_SPANS = {
     "fill_rules": "stage", "put_rules": "stage", "enqueue_rules": "dispatch",
-    "fill_embed": "dispatch", "put_embed": "dispatch",
-    "enqueue_embed": "dispatch",
+    "fill_embed": "stage", "put_embed": "stage", "enqueue_embed": "stage",
 }
 
 
@@ -1964,9 +1963,9 @@ class TestSpanTree:
         (doc,) = rec.debug_payload()["batches"]
         names = [s["name"] for s in doc["spans"]]
         assert names == [
-            "batch", "stage", "fill_rules", "put_rules", "dispatch",
-            "enqueue_rules", "fill_embed", "put_embed", "enqueue_embed",
-            "handoff", "fetch_rules", "fetch_embed", "compose",
+            "batch", "stage", "fill_embed", "put_embed", "enqueue_embed",
+            "fill_rules", "put_rules", "dispatch", "enqueue_rules",
+            "handoff", "fetch_embed", "fetch_rules", "compose",
         ]
         _assert_tree(doc)
         span = {s["name"]: s for s in doc["spans"]}
@@ -1977,11 +1976,11 @@ class TestSpanTree:
 
         for before, after in (
             ("stage", "dispatch"), ("dispatch", "handoff"),
-            ("handoff", "fetch_rules"), ("fetch_rules", "fetch_embed"),
-            ("fetch_embed", "compose"),
+            ("handoff", "fetch_embed"), ("fetch_embed", "fetch_rules"),
+            ("fetch_rules", "compose"),
         ):
             assert abs(span[after]["start_ms"] - end(before)) <= slack
-        assert span["fetch_rules"]["start_ms"] >= end("dispatch") - slack
+        assert span["fetch_embed"]["start_ms"] >= end("dispatch") - slack
         assert doc["attrs"]["rows"] == 4 and doc["attrs"]["length"] == 8
         assert doc["attrs"]["seeds_real"] == 3
         assert engine.seed_slots_real - real0 == 3
@@ -2357,14 +2356,16 @@ class TestHostPathSpans:
         def length(name):
             return spans[name][3] - spans[name][2]
 
-        assert length("fill_rules") + length("put_rules") <= length("stage")
         assert sum(length(n) for n in (
-            "enqueue_rules", "fill_embed", "put_embed", "enqueue_embed",
-        )) <= length("dispatch")
-        # in the order the work is done
+            "fill_embed", "put_embed", "enqueue_embed", "fill_rules",
+            "put_rules",
+        )) <= length("stage")
+        assert length("enqueue_rules") <= length("dispatch")
+        # in the order the work is done: the embedding family first
         for order in (
-            ["fill_rules", "put_rules"],
-            ["enqueue_rules", "fill_embed", "put_embed", "enqueue_embed"],
+            ["fill_embed", "put_embed", "enqueue_embed", "fill_rules",
+             "put_rules"],
+            ["enqueue_rules"],
         ):
             for before, after in zip(order, order[1:]):
                 assert spans[before][3] <= spans[after][2], (before, after)
@@ -2376,15 +2377,17 @@ class TestHostPathSpans:
         assert spans["put_rules"][3] <= entered
 
     def test_the_laps_still_tile_across_the_new_handoff(self, hybrid_batch):
-        """``dispatch`` ends where ``handoff`` starts and ``fetch_rules``
-        starts where ``handoff`` ends, to the nanosecond: the laps tile
-        the batch, and the hop to ``finish()`` is one of them."""
+        """``dispatch`` ends where ``handoff`` starts and the first
+        pick-up, ``fetch_embed`` in a hybrid batch, starts where
+        ``handoff`` ends, to the nanosecond: the laps tile the batch, and
+        the hop to ``finish()`` is one of them."""
         spans, _, bt = hybrid_batch
         assert spans["handoff"][1] == 0  # a child of the batch
         assert spans["handoff"][2] == spans["dispatch"][3]
-        assert spans["fetch_rules"][2] == spans["handoff"][3]
+        assert spans["fetch_embed"][2] == spans["handoff"][3]
         assert spans["dispatch"][2] == spans["stage"][3]
-        assert spans["fetch_embed"][2] == spans["fetch_rules"][3]
+        assert spans["fetch_rules"][2] == spans["fetch_embed"][3]
+        assert spans["compose"][2] == spans["fetch_rules"][3]
         assert bt.cursor == spans["compose"][3]
 
     @pytest.mark.parametrize("transport", ["threaded", "async"])
